@@ -1172,42 +1172,8 @@ def _render_flight(fl: dict, tail: int = 32) -> list[str]:
 def cmd_cflight(args) -> int:
     """Stall forensics viewer: the flight recorder's recent cycle-phase
     timeline plus the last stall's all-thread stack capture — from a
-    live ctld, every shard of a federation, or a BENCH_*.json probe
-    diagnosis (``--file``)."""
+    live ctld or every shard of a federation."""
     import json as _json
-    if getattr(args, "file", ""):
-        with open(args.file, encoding="utf-8") as fh:
-            doc = _json.load(fh)
-        # accept the probe dict itself, a bench.py output doc, or the
-        # committed BENCH_rNN.json wrapper ({"parsed": <bench doc>})
-        acq = doc if isinstance(doc, dict) else {}
-        for path in (("device_acquisition",),
-                     ("detail", "device_acquisition"),
-                     ("parsed", "detail", "device_acquisition")):
-            node = doc
-            for key in path:
-                node = node.get(key) if isinstance(node, dict) else None
-            if node:
-                acq = node
-                break
-        phases = acq.get("phases") or []
-        print(f"probe acquired={acq.get('acquired', '?')} "
-              f"phases={'->'.join(str(p) for p in phases) or '(none)'}")
-        # the handshake's heartbeat stamps: where the wall-clock went
-        # inside acquisition (the gap after the LAST stamp is the
-        # wedged phase on a timeout)
-        stamps = acq.get("phase_stamps") or []
-        if stamps:
-            t0 = float(stamps[0].get("t") or 0.0)
-            for s in stamps:
-                print(f"  stamp {str(s.get('phase')):<14} "
-                      f"+{float(s.get('t') or 0.0) - t0:.3f}s")
-        if acq.get("diagnosis"):
-            print(f"diagnosis: {acq['diagnosis']}")
-        if acq.get("stacks"):
-            print("-- harvested probe stacks --")
-            print(acq["stacks"])
-        return 0 if acq.get("acquired") else 1
     if getattr(args, "federation", False):
         fed = _fed_connect(args)
         if fed is None:
@@ -1726,9 +1692,6 @@ def build_parser() -> argparse.ArgumentParser:
                             "timeline + the last stall's thread stacks")
     p.add_argument("--tail", type=int, default=32, metavar="N",
                    help="phase stamps to show (newest N)")
-    p.add_argument("--file", default="", metavar="PATH",
-                   help="render a BENCH_*.json probe diagnosis instead "
-                        "of querying a server")
     _fed_flags(p)
     p.set_defaults(func=cmd_cflight)
 

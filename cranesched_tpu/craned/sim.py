@@ -182,7 +182,7 @@ class SimCluster:
             return
         when = self.now if now is None else max(now, self.now)
         self._frozen.pop(job_id, None)
-        self._remove_step_everywhere(job_id)
+        self._free_steps(job)
         # stamp the incarnation we killed: ctld may requeue + re-place the
         # job before this report drains (e.g. on_craned_down terminates
         # the gang then requeues in the same call) and the stale Cancelled
@@ -212,7 +212,7 @@ class SimCluster:
                     ev.exit_code, ev.time, incarnation=ev.requeue_count)
                 sent += 1
                 continue
-            self._remove_step_everywhere(ev.job_id)
+            self._free_steps(job)
             self.scheduler.step_status_change(ev.job_id, ev.status,
                                               ev.exit_code, ev.time,
                                               incarnation=ev.requeue_count)
@@ -222,9 +222,12 @@ class SimCluster:
     def next_event_time(self) -> float | None:
         return self._events[0].time if self._events else None
 
-    def _remove_step_everywhere(self, job_id: int) -> None:
-        for craned in self.craneds.values():
-            craned.free_step(job_id)
+    def _free_steps(self, job: Job) -> None:
+        # only the job's own nodes hold its step: a walk over every
+        # craned made each completion O(cluster) — at 10k nodes the
+        # sim plane, not the scheduler, set the completion rate
+        for node_id in job.node_ids:
+            self.craneds[node_id].free_step(job.job_id)
 
     # -- convenience driver --
 

@@ -201,15 +201,21 @@ class SchedulerConfig:
     # bounded ring of structured per-cycle traces (obs/trace.py),
     # queryable via QueryStats / `cstats --cycles`
     cycle_trace_ring: int = 64
-    # solver backend for immediate-fit cycles: "auto" prefers the native
-    # C++ treap solver (bit-identical, ~fastest single-host) and falls
-    # back to the device scan; "device" forces the JAX scan; "native"
-    # requires the C++ library; "pallas" runs the single-kernel TPU
-    # solve (models/pallas_solver.py — interpret mode off-TPU, so only
-    # useful for tests there); "sharded" runs the node-axis-sharded
-    # multi-chip solve over every visible device
-    # (parallel/sharded.py).  Backfill and packed cycles always run on
-    # device.  All five are bit-identical on placements.
+    # solver backend for immediate-fit cycles: "auto" chooses from the
+    # platform JAX reports — on a TPU backend the single-kernel Pallas
+    # solve (models/pallas_solver.py: resident state, donated buffers),
+    # elsewhere the native C++ treap solver when its library builds,
+    # else the device scan; "device" forces the JAX scan; "native"
+    # requires the C++ library; "pallas" requires a backend that runs
+    # the Mosaic kernel (a TPU) and fails loudly elsewhere; "sharded"
+    # runs the node-axis-sharded multi-chip solve over every visible
+    # device (parallel/sharded.py).  Backfill and packed cycles always
+    # run on device.  On one backend all five place bit-identically
+    # (tests/ on the CPU; Pallas vs the scan on a v5e: chip_smoke.py).
+    # ACROSS backends they do not: the int32 ledgers are exact, but
+    # quantized_dcost's f32 divide is not the same function on a TPU
+    # as on a host (one ledger unit apart in ~0.1% of increments, which
+    # reorders cost ties and moves placements — PERF.md, PR 21).
     solver: str = "auto"
     # post-commit dispatch fan-out width (YAML ``DispatchWorkers``).
     # None sizes the dispatcher pool from the cluster:
@@ -551,6 +557,11 @@ class JobScheduler:
         # device across cycles; per-cycle H2D is job_class[J] only
         self._mask_table = _MaskTable()
         self._mesh = None  # lazy device mesh for solver == "sharded"
+        # test-harness hook: run the Pallas solve under the Pallas
+        # interpreter so CPU-only tests can drive _solve_pallas.  The
+        # program never sets it — production compiles the kernel for
+        # the backend it runs on or fails.
+        self.pallas_interpret = False
         self._dependents: dict[int, set[int]] = {}  # dep job -> waiters
         # job_id -> last kill-send time for unconfirmed cancel intents
         self._cancel_kill_sent: dict[int, float] = {}
@@ -2531,7 +2542,8 @@ class JobScheduler:
     def _immediate_solve(self, avail, total, alive, cost0, jobs_batch,
                          max_nodes, resident_ok=False):
         """Route one immediate-fit solve through the configured backend
-        (auto/native/device/pallas/sharded — all bit-identical).
+        (auto/native/device/pallas/sharded — bit-identical on one
+        backend, see SchedulerConfig.solver).
 
         When a topology is configured, the node axis is presented to the
         backend in block-major order (Topology.perm): the backends'
@@ -2559,19 +2571,24 @@ class JobScheduler:
             resident_ok = False
         placements = None
         solver_name = "immediate"
-        if self.config.solver in ("auto", "native"):
+        import jax as _jax
+        solver = self.config.solver
+        if solver == "auto" and _jax.default_backend() == "tpu":
+            # the chip is there: the immediate solve belongs on it
+            solver = "pallas"
+        if solver in ("auto", "native"):
             placements = self._solve_native(avail, total, alive, cost0,
                                             jobs_batch, max_nodes)
             if placements is not None:
                 solver_name = "native"
-            elif self.config.solver == "native":
+            elif solver == "native":
                 raise RuntimeError("native solver unavailable")
-        if placements is None and self.config.solver == "sharded":
+        if placements is None and solver == "sharded":
             placements = self._solve_sharded(avail, total, alive, cost0,
                                              jobs_batch, max_nodes,
                                              resident_ok=resident_ok)
             solver_name = "sharded"
-        if placements is None and self.config.solver == "pallas":
+        if placements is None and solver == "pallas":
             placements, solver_name = self._solve_pallas(
                 avail, total, alive, cost0, jobs_batch, max_nodes,
                 resident_ok=resident_ok)
@@ -2585,7 +2602,6 @@ class JobScheduler:
                     key=("device", int(np.asarray(avail).shape[0]),
                          int(np.asarray(avail).shape[1]),
                          self._mask_table.generation))
-                import jax as _jax
                 fn = (solve_greedy_donating
                       if _jax.default_backend() == "tpu" else solve_greedy)
                 placements, new_state = fn(state, dense,
@@ -3002,19 +3018,20 @@ class JobScheduler:
         ``pallas-stream`` with ``num_streams`` in the cycle trace —
         both derived from the plan the auto dispatch ACTUALLY ran with,
         including the planner's internal decision when no cached plan
-        exists.  On TPU the cluster-state buffers are donated; with
+        exists.  The cluster-state buffers are donated; with
         ``resident_ok`` they come from the cross-cycle resident state
         (dirty-row scatter patch) instead of a fresh host upload.
-        Non-TPU backends run in interpret mode (tests)."""
-        import jax as _jax
-
+        The kernel is compiled for the backend JAX runs on — a backend
+        that cannot run it raises (``pallas_interpret`` is the tests'
+        opt-out, never derived from the platform)."""
         from cranesched_tpu.models.pallas_solver import (
             plan_streams,
             solve_greedy_pallas_auto,
             solve_greedy_pallas_from_batch,
         )
 
-        on_tpu = _jax.default_backend() == "tpu"
+        interpret = self.pallas_interpret
+        donate = not interpret   # the interpreter's CPU ignores donation
         cfg = self.config
         if resident_ok and self._resident.enabled:
             state, _mode = self._resident.acquire(
@@ -3030,7 +3047,7 @@ class JobScheduler:
                     state, jobs_batch, max_nodes=max_nodes,
                     block_jobs=cfg.block_jobs,
                     max_streams=cfg.max_streams,
-                    interpret=not on_tpu, donate=on_tpu,
+                    interpret=interpret, donate=donate,
                     return_plan=True))
         else:
             plan = None
@@ -3048,8 +3065,8 @@ class JobScheduler:
                 jobs_batch.time_limit, jobs_batch.valid,
                 jobs_batch.job_class, jobs_batch.class_masks,
                 max_nodes=max_nodes, block_jobs=cfg.block_jobs,
-                max_streams=cfg.max_streams, interpret=not on_tpu,
-                donate=on_tpu, plan=plan, return_plan=True)
+                max_streams=cfg.max_streams, interpret=interpret,
+                donate=donate, plan=plan, return_plan=True)
         if resident_ok and self._resident.enabled:
             self._resident.adopt(new_state)
         num_streams = used_plan[1] if used_plan is not None else 1

@@ -2,7 +2,7 @@
 
 Why: the lax.scan solver (models/solver.py) is semantically exact but
 latency-bound on TPU — 100k scan steps of ~15 tiny kernels each measured
-2.75 s/cycle at the north-star shape (BENCH_r03/r04 greedy), entirely
+2.7 s/cycle at the north-star shape on a v5e (PERF.md, PR 21), entirely
 dispatch/latency overhead: the actual arithmetic is ~10 GOP.  The
 TPU-native fix is to run the WHOLE job loop inside a single kernel:
 
@@ -21,9 +21,11 @@ TPU-native fix is to run the WHOLE job loop inside a single kernel:
   node axis is folded to (8 sublanes, N/8 lanes) so every op fills the
   full 8x128 VPU instead of one sublane.
 
-Semantics are bit-identical to ``solver.solve_greedy`` (same fixed-point
-cost ledger, same (cost, lowest-index) tie order, same decide_job
-admission + pending reasons — asserted in tests/test_pallas_parity.py).
+Semantics are bit-identical to ``solver.solve_greedy`` on the same
+backend (same fixed-point cost ledger, same (cost, lowest-index) tie
+order, same decide_job admission + pending reasons — asserted in
+interpret mode by tests/test_pallas_parity.py and on the chip at
+100k x 10k by chip_smoke.py).
 The one interface difference: per-job node eligibility arrives as
 ``job_class[J]`` + ``class_masks[C, N]`` instead of a dense
 ``part_mask[J, N]`` — the [J, N] matrix at 100k x 10k is a 1 GB bool
@@ -218,11 +220,10 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
                     cost_s[0] = cost_s[0] + jnp.where(win, dcost, 0)
             return carry
 
-        # unroll=4: the loop is bound by per-job scalar work and
-        # reduce-to-scalar latency, not vector width (tools/kattr.py);
-        # unrolling lets Mosaic overlap job j+1's SMEM reads and
-        # broadcasts with job j's reductions
-        jax.lax.fori_loop(0, BJ, job_body, jnp.int32(0), unroll=4)
+        # no partial unroll: the Mosaic lowering of the installed JAX
+        # accepts only unroll=1 or a full unroll of the BJ steps
+        # (tests/test_pallas_lowering.py lowers every entry point)
+        jax.lax.fori_loop(0, BJ, job_body, jnp.int32(0))
 
         # per-job outputs live whole in VMEM (tiny); write this block's
         # row at a dynamic offset — blocked specs would need a

@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from cranesched_tpu.obs.introspect import instrument_jit
+
 FLOAT_MAX = 3.4e38  # plain float: keep module import backend-free
 
 
@@ -230,6 +232,10 @@ def multifactor_priority(
                 + weights.job_size * size_f + weights.fair_share * fshare_f
                 + weights.qos * qos_f)
     return jnp.where(p_ok, priority, -jnp.inf)
+
+
+multifactor_priority = instrument_jit("multifactor_priority",
+                                      multifactor_priority)
 
 
 def priority_order(priority: jax.Array) -> jax.Array:

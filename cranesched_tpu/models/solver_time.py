@@ -64,6 +64,7 @@ from cranesched_tpu.models.solver import (
     decide_job,
     normalize_cost_ledger,
 )
+from cranesched_tpu.obs.introspect import instrument_jit
 
 # start_bucket value for jobs that could not be scheduled in the window
 NO_START = 2**30  # plain int: keep module import backend-free
@@ -366,3 +367,8 @@ def solve_backfill(state: TimedClusterState, jobs: TimedJobBatch,
     new_state = state.replace(time_avail=ta, cost=cost)
     return (TimedPlacements(placed=placed, start_bucket=start, nodes=nodes,
                             reason=reason), new_state)
+
+
+# the default cycle's head solve: its compiles count toward the cycle
+# trace's ``recompiles`` like the immediate solvers' do
+solve_backfill = instrument_jit("solve_backfill", solve_backfill)

@@ -12,10 +12,9 @@ lifecycle tracing with SLOs, and the scheduler watchdog.
 - ``slo.py``       sliding-window p50/p99 targets over trace edges with
                    multi-window burn-rate gauges and a breach counter.
 - ``flight.py``    stall forensics: always-on flight recorder (phase
-                   ring + stall sentry with all-thread stack dumps),
-                   the fsync'd probe heartbeat protocol, and the
-                   persistent XLA compilation cache with hit/miss
-                   counters.
+                   ring + stall sentry with all-thread stack dumps)
+                   and the persistent XLA compilation cache with
+                   hit/miss counters.
 - ``fedobs.py``    federation-wide merge: scatter-gather metric
                    aggregation and the cluster-level SLO engine over
                    per-shard summaries (exact burn-rate merge).
@@ -32,11 +31,8 @@ from cranesched_tpu.obs.fedobs import (  # noqa: F401
 )
 from cranesched_tpu.obs.flight import (  # noqa: F401
     FlightRecorder,
-    Heartbeat,
-    PROBE_PHASES,
     dump_all_stacks,
     enable_xla_cache,
-    read_heartbeat,
     xla_cache_stats,
 )
 from cranesched_tpu.obs.jobtrace import (  # noqa: F401

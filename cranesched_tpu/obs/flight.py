@@ -1,12 +1,5 @@
-"""Stall forensics: the always-on flight recorder, the probe heartbeat
-protocol, and the persistent XLA compilation cache wiring.
-
-Four benches in a row (r06-r09) died the same way: the TPU probe timed
-out and left ZERO forensics — "jax.devices() did not return within the
-budget" names neither the phase that hung (import? backend init? first
-compile?) nor the stack it hung on.  This module makes every stall —
-probe-side or cycle-side — land with a phase attribution and an
-all-thread stack dump:
+"""Stall forensics: the always-on flight recorder and the persistent
+XLA compilation cache wiring.
 
 * :class:`FlightRecorder` — a bounded ring of recent phase stamps (the
   scheduler stamps cycle_begin/prelude/commit/dispatch/cycle_end per
@@ -18,36 +11,21 @@ all-thread stack dump:
   wedged daemon.  All bookkeeping self-time is accumulated so the bench
   can prove the recorder costs <= 1% of a cycle.
 
-* The heartbeat protocol — :class:`Heartbeat` writes one fsync'd JSON
-  line per named phase (``PROBE_PHASES``: env preflight -> jax import
-  -> backend init -> device enum -> first trace -> first compile ->
-  first execute -> steady state);
-  :func:`read_heartbeat` parses the file tolerantly (a probe killed
-  mid-write leaves a torn last line, which is dropped, never raised
-  on).  bench.py's TPU probe subprocess stamps these so the parent's
-  timeout handler can say WHICH phase hung and harvest the child's
-  ``faulthandler`` stack dump into the BENCH_*.json diagnosis.  The
-  acquisition half of the protocol (and the probe subprocess itself)
-  lives in parallel/acquire.py.
+* :func:`enable_xla_cache` — turns on jax's persistent compilation
+  cache with the size and compile-time floors dropped so every
+  executable is cached, and registers a ``jax.monitoring`` listener
+  that counts ``/jax/compilation_cache/cache_hits`` / ``cache_misses``
+  into ``crane_xla_cache_*``.  The directory is the operator's
+  ``JAX_COMPILATION_CACHE_DIR`` when that is set (jax reads it itself;
+  nothing is set in code), else ``profiles/xla_cache/`` of the checkout
+  this package was imported from — a fixed path, because the path is
+  part of the cache key and a directory that moves never hits.
 
-* :func:`enable_xla_cache` — points ``jax_compilation_cache_dir`` at a
-  persistent directory (default ``profiles/xla_cache/``) with the size
-  and compile-time floors dropped so every executable is cached, and
-  registers a ``jax.monitoring`` listener that counts
-  ``/jax/compilation_cache/cache_hits`` / ``cache_misses`` into
-  ``crane_xla_cache_*``.  A hung first-compile is the leading stall
-  suspect; a warm cache across probe runs removes the compile from the
-  critical path entirely — and the hit/miss counters prove whether it
-  actually did.
-
-jax is imported only inside :func:`enable_xla_cache` — the recorder and
-heartbeat halves must work in processes that are themselves trying to
-find out whether importing jax hangs.
+jax is imported only inside :func:`enable_xla_cache`.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import sys
 import threading
@@ -57,16 +35,6 @@ from collections import deque
 from typing import Callable, Optional
 
 from cranesched_tpu.obs.metrics import REGISTRY as _OBS
-
-#: the probe subprocess's named phases, in order.  A stamp marks the
-#: phase's START — on a timeout, the last stamp names where it hung.
-#: The first four are the acquisition handshake (owned by
-#: parallel/acquire.py: env pre-flight, jax import, the PJRT
-#: plugin/runtime init that BENCH_r10 caught wedged, device
-#: enumeration); the tail is the bench probe's compile warm-up.
-PROBE_PHASES = ("env_preflight", "jax_import", "backend_init",
-                "device_enum", "first_trace", "first_compile",
-                "first_execute", "steady_state")
 
 _MET_STAMPS = _OBS.counter(
     "crane_flight_stamps_total",
@@ -220,66 +188,11 @@ class FlightRecorder:
 
 
 # ---------------------------------------------------------------------------
-# the probe heartbeat protocol (bench.py TPU probe <-> parent)
-# ---------------------------------------------------------------------------
-
-
-class Heartbeat:
-    """fsync'd phase stamps: one JSON line per stamp, durable before
-    the writer proceeds — a probe killed mid-phase leaves its last
-    stamp on disk, which is the whole point."""
-
-    def __init__(self, path: str):
-        self.path = path
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        self._fh = open(path, "a", encoding="utf-8")
-
-    def stamp(self, phase: str, detail: str = "") -> None:
-        rec = {"t": time.time(), "phase": phase}
-        if detail:
-            rec["detail"] = detail
-        self._fh.write(json.dumps(rec) + "\n")
-        self._fh.flush()
-        os.fsync(self._fh.fileno())
-
-    def close(self) -> None:
-        try:
-            self._fh.close()
-        except Exception:
-            pass
-
-
-def read_heartbeat(path: str) -> list[dict]:
-    """Parse a heartbeat file; missing file -> [], torn last line
-    dropped (the writer died mid-write — exactly the case this exists
-    for)."""
-    out: list[dict] = []
-    try:
-        with open(path, encoding="utf-8") as fh:
-            for line in fh:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rec = json.loads(line)
-                except json.JSONDecodeError:
-                    continue  # torn tail
-                if isinstance(rec, dict) and "phase" in rec:
-                    out.append(rec)
-    except OSError:
-        return []
-    return out
-
-
-# ---------------------------------------------------------------------------
 # persistent XLA compilation cache
 # ---------------------------------------------------------------------------
 
 _xla_lock = threading.Lock()
-_xla_state = {"enabled": False, "dir": "", "hits": 0, "misses": 0,
-              "error": ""}
+_xla_state = {"enabled": False, "dir": "", "hits": 0, "misses": 0}
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -295,38 +208,44 @@ def _on_cache_event(event: str, **kw) -> None:
         _MET_XLA_MISSES.inc()
 
 
-def enable_xla_cache(cache_dir: str = "") -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``
-    (default ``profiles/xla_cache/`` under the cwd) and start counting
-    hits/misses.  Idempotent; returns False (with the error recorded in
-    :func:`xla_cache_stats`) when jax is unavailable or too old —
-    callers degrade to uncached compiles, never crash."""
-    cache_dir = cache_dir or os.path.join("profiles", "xla_cache")
-    with _xla_lock:
-        if _xla_state["enabled"] and _xla_state["dir"] == cache_dir:
-            return True
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
+#: default cache directory: ``profiles/xla_cache`` of the checkout this
+#: package lives in — never the cwd, a temp name, a pid or the time
+DEFAULT_XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), "profiles", "xla_cache")
+
+
+def enable_xla_cache() -> str:
+    """Turn on jax's persistent compilation cache and start counting
+    hits/misses; returns the directory in use.  Idempotent.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, jax has already taken the
+    directory from the environment and none is set here; otherwise the
+    cache goes to :data:`DEFAULT_XLA_CACHE_DIR`.  A cache that cannot
+    be enabled (unwritable directory) raises — on the paths that call
+    this, an uncached start is minutes of recompiles, not a detail."""
+    import jax
+    import jax.monitoring as _mon
+
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not cache_dir:
+        cache_dir = DEFAULT_XLA_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache EVERYTHING: the probe's first compile is exactly the
-        # small-and-fast executable the default floors would skip
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        import jax.monitoring as _mon
-        with _xla_lock:
-            if not _xla_state["enabled"]:
-                _mon.register_event_listener(_on_cache_event)
-            _xla_state["enabled"] = True
-            _xla_state["dir"] = cache_dir
-            _xla_state["error"] = ""
-        return True
-    except Exception as e:
-        with _xla_lock:
-            _xla_state["error"] = f"{type(e).__name__}: {e}"
-        return False
+    os.makedirs(cache_dir, exist_ok=True)
+    if not os.access(cache_dir, os.W_OK):
+        raise PermissionError(
+            f"XLA compilation cache directory {cache_dir!r} is not "
+            "writable")
+    # cache EVERYTHING: the small fast executables the default floors
+    # skip are most of a scheduler's programs
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    with _xla_lock:
+        if not _xla_state["enabled"]:
+            _mon.register_event_listener(_on_cache_event)
+        _xla_state["enabled"] = True
+        _xla_state["dir"] = cache_dir
+    return cache_dir
 
 
 def xla_cache_stats() -> dict:
@@ -345,5 +264,4 @@ def xla_cache_stats() -> dict:
     return {"enabled": st["enabled"], "dir": st["dir"],
             "hits": st["hits"], "misses": st["misses"],
             "entries": entries,
-            "hit_rate": round(st["hits"] / total, 4) if total else 0.0,
-            "error": st["error"]}
+            "hit_rate": round(st["hits"] / total, 4) if total else 0.0}

@@ -7,14 +7,13 @@ travel over ICI as ``all_gather``/``psum`` collectives.  See
 """
 
 # Lazy exports: parallel.acquire must be importable WITHOUT pulling
-# jax into the process (the acquisition probe's whole point is deciding
-# whether jax backend bring-up is safe), and sharded.py imports jax at
-# module scope.
+# jax into the process (its pre-flight report is taken before jax is
+# imported), and sharded.py imports jax at module scope.
 _SHARDED = ("make_node_mesh", "shard_cluster_state",
             "solve_greedy_sharded", "solve_greedy_sharded_classes")
 _DISTRIBUTED = ("bootstrap_process_mesh", "ProcessMesh",
                 "solve_greedy_sharded_classes_mp")
-_ACQUIRE = ("acquire_backend", "ensure_backend", "preflight_report")
+_ACQUIRE = ("acquire_backend", "expected_platform", "preflight_report")
 
 __all__ = [*_SHARDED, *_DISTRIBUTED, *_ACQUIRE]
 

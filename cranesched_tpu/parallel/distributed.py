@@ -68,8 +68,6 @@ from cranesched_tpu.models.solver import (
 from cranesched_tpu.obs.metrics import REGISTRY as _OBS
 from cranesched_tpu.parallel.sharded import (
     NODE_AXIS,
-    _SHARD_MAP_KW,
-    _shard_map,
     make_node_mesh,
 )
 from cranesched_tpu.rpc.rendezvous import RendezvousClient
@@ -239,12 +237,12 @@ def _select_step(avail, alive, cost, cm, jreq, jcls, *, mesh, k_slab):
 
     node_row = P(NODE_AXIS)
     node_mat = P(NODE_AXIS, None)
-    return _shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(node_mat, node_row, node_row, P(None, NODE_AXIS),
                   P(None, None), P(None)),
         out_specs=(P(None), P(None, None), P(None, None)),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(avail, alive, cost, cm, jreq, jcls)
 
 
@@ -280,14 +278,14 @@ def _apply_step(avail, cost, total, jreq, jnn, jtl, jv, counts,
 
     node_row = P(NODE_AXIS)
     node_mat = P(NODE_AXIS, None)
-    return _shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(node_mat, node_row, node_mat, P(None, None), P(None),
                   P(None), P(None), P(None), P(None, None),
                   P(None, None), P()),
         out_specs=(node_mat, node_row, P(None), P(None, None),
                    P(None)),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(avail, cost, total, jreq, jnn, jtl, jv, counts, sel_cost,
       sel_gidx, slab_offset)
 

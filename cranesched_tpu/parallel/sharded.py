@@ -52,15 +52,6 @@ from cranesched_tpu.obs.introspect import instrument_jit as _instrument_jit
 
 NODE_AXIS = "nodes"
 
-# jax moved shard_map out of experimental (and renamed the replication
-# check kwarg) around 0.5; support both spellings
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 def make_node_mesh(devices=None) -> Mesh:
     """1-D device mesh over which the node axis is sharded."""
@@ -161,13 +152,13 @@ def solve_greedy_sharded(state: ClusterState, jobs: JobBatch, mesh: Mesh,
 
     node_row = P(NODE_AXIS)
     node_mat = P(NODE_AXIS, None)
-    avail, cost, placed, nodes, reason = _shard_map(
+    avail, cost, placed, nodes, reason = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(node_mat, node_mat, node_row, node_row,
                   P(None, None), P(None), P(None), P(None, NODE_AXIS),
                   P(None)),
         out_specs=(node_mat, node_row, P(None), P(None, None), P(None)),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(state.avail, state.total, state.alive, state.cost,
       jobs.req, jobs.node_num, jobs.time_limit, jobs.part_mask, jobs.valid)
 
@@ -290,14 +281,14 @@ def _solve_sharded_streamed(state: ClusterState, req, node_num,
 
     node_row = P(NODE_AXIS)
     node_mat = P(NODE_AXIS, None)
-    avail, cost, placed, nodes, reason = _shard_map(
+    avail, cost, placed, nodes, reason = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(node_mat, node_mat, node_row, node_row,
                   P(None, NODE_AXIS), P(None, None, None), P(None, None),
                   P(None, None), P(None, None), P(None, None)),
         out_specs=(node_mat, node_row, P(None, None),
                    P(None, None, None), P(None, None)),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(state.avail, state.total, state.alive, state.cost,
       class_masks, req_sl, nn_sl, tl_sl, cls_sl, v_sl)
 

@@ -1844,9 +1844,18 @@ class CtldServer:
                 "watchdog_crash", "error", time=now,
                 detail=tb.strip().rsplit("\n", 1)[-1][:200])
 
+    #: how long stop() waits for the in-flight cycle to finish
+    STOP_CYCLE_GRACE_S = 120.0
+
     def stop(self) -> None:
         self._stop.set()
         self._cycle_kick.set()  # wake a possibly long idle sleep
+        if self._cycle_thread is not None:
+            # let the in-flight cycle commit, flush and dispatch: a
+            # daemon thread still inside an XLA call when the
+            # interpreter finalizes aborts the process (SIGABRT on
+            # SIGTERM mid-cycle, seen at 100k x 10k)
+            self._cycle_thread.join(timeout=self.STOP_CYCLE_GRACE_S)
         self.scheduler.flight.close()
         for cli in self._fwd_clients.values():
             try:

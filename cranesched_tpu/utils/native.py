@@ -1,15 +1,22 @@
 """ctypes bridge to the native C++ library.
 
 Builds ``native/crane_native.cpp`` on first use (g++ is baked into the
-image; ~1 s) and caches the .so next to the source.  Every entry point
-has a pure-Python twin, so environments without a toolchain still work —
+image; ~1 s) and caches the .so next to the source, under a name keyed
+by the source text and the CPU it was compiled for: the build uses
+``-march=native``, so a library that arrived with a copy of the tree
+from another machine (or predates an edit) is never loaded — its name
+does not match and a fresh one is built.  Every entry point has a
+pure-Python twin, so environments without a toolchain still work —
 ``available()`` tells callers which path they got.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -17,27 +24,56 @@ _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), "native")
 _SRC = os.path.join(_NATIVE_DIR, "crane_native.cpp")
-_SO = os.path.join(_NATIVE_DIR, "libcrane_native.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves against: the machine type and
+    the kernel's CPU feature flags."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo", encoding="utf-8") as fh:
+            for line in fh:
+                if line.startswith(("flags", "Features")):
+                    flags = line.split(":", 1)[1].strip()
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {flags}"
+
+
+def _so_path() -> str:
+    """``native/libcrane_native-<key>.so`` for THIS source on THIS CPU."""
+    with open(_SRC, "rb") as fh:
+        key = hashlib.sha256(fh.read() + _host_cpu().encode())
+    return os.path.join(_NATIVE_DIR,
+                        f"libcrane_native-{key.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
     # -march=native vectorizes the solver's per-dimension loops for the
     # host the .so is built on (it is always compiled locally, never
-    # shipped).  No -ffast-math: QuantizedDcost's round-half-to-even
-    # must stay bit-identical to the JAX ledger.
+    # shipped — see _so_path).  No -ffast-math: QuantizedDcost's
+    # round-half-to-even must stay bit-identical to the JAX ledger.
     base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
+    tmp = f"{so}.{os.getpid()}.tmp"
     for extra in (["-march=native"], []):
         try:
             subprocess.run(
-                base + extra + ["-o", _SO, _SRC],
+                base + extra + ["-o", tmp, _SRC],
                 check=True, capture_output=True, timeout=120)
-            return True
         except (OSError, subprocess.SubprocessError):
             continue
+        # libraries under any other key are for another source or CPU
+        for stale in glob.glob(
+                os.path.join(_NATIVE_DIR, "libcrane_native*.so")):
+            if stale != so:
+                os.remove(stale)
+        os.replace(tmp, so)     # atomic: concurrent daemons never see
+        return True             # a half-written library
     return False
 
 
@@ -48,13 +84,13 @@ def load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not os.path.exists(_SRC) or not _build():
-                return None
+        if not os.path.exists(_SRC):
+            return None
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError:
             return None
         lib.crane_parse_hostlist.restype = ctypes.c_int

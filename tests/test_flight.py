@@ -1,14 +1,11 @@
 """Stall forensics + federated observability (ISSUE 16): the flight
-recorder's phase ring and stall sentry, the probe heartbeat protocol
-(including a forced hang that must land with a phase attribution and a
-stack dump, never a bare timeout), the persistent XLA compilation
+recorder's phase ring and stall sentry, the persistent XLA compilation
 cache, the fed_forwarded / arbiter_reserve / arbiter_confirm spans on
 job timelines, and the cluster-level SLO merge against the
 single-controller oracle.
 
 All tests run in the ``make tier1-flight`` lane (``-m flight``); they
-are fast enough for tier-1 too (the two probe-subprocess tests pay one
-jax import each).
+are fast enough for tier-1 too.
 """
 
 import json
@@ -37,13 +34,7 @@ from cranesched_tpu.obs.fedobs import (
     cluster_doc,
     merge_metric_snapshots,
 )
-from cranesched_tpu.obs.flight import (
-    PROBE_PHASES,
-    FlightRecorder,
-    Heartbeat,
-    dump_all_stacks,
-    read_heartbeat,
-)
+from cranesched_tpu.obs.flight import FlightRecorder, dump_all_stacks
 from cranesched_tpu.obs.introspect import ProfilerWindow
 from cranesched_tpu.obs.jobtrace import (
     FED_EDGES,
@@ -132,128 +123,8 @@ def test_dump_all_stacks_sees_this_thread():
 
 
 # ---------------------------------------------------------------------------
-# the probe heartbeat protocol
+# persistent XLA compilation cache
 # ---------------------------------------------------------------------------
-
-def test_heartbeat_roundtrip_and_torn_tail(tmp_path):
-    path = str(tmp_path / "hb" / "heartbeat.jsonl")
-    hb = Heartbeat(path)
-    hb.stamp("jax_import")
-    hb.stamp("backend_init", detail="cpu")
-    hb.close()
-    beats = read_heartbeat(path)
-    assert [b["phase"] for b in beats] == ["jax_import", "backend_init"]
-    assert beats[1]["detail"] == "cpu"
-    assert beats[0]["t"] <= beats[1]["t"]
-    # a probe killed mid-write leaves a torn last line: dropped, plus
-    # blank lines and non-record JSON are skipped, never raised on
-    with open(path, "a", encoding="utf-8") as fh:
-        fh.write("\n42\n{\"t\": 17, \"pha")
-    beats = read_heartbeat(path)
-    assert [b["phase"] for b in beats] == ["jax_import", "backend_init"]
-    # missing file is the probe-died-pre-stamp case
-    assert read_heartbeat(str(tmp_path / "nope.jsonl")) == []
-
-
-def test_probe_forced_hang_names_phase_and_captures_stack(
-        tmp_path, monkeypatch):
-    """The r06-r09 regression guard: a hung probe must produce a
-    diagnosis naming the phase it hung in plus the child's faulthandler
-    stack dump — never a bare timeout."""
-    import bench
-    monkeypatch.setenv("BENCH_PROBE_INJECT_HANG", "jax_import")
-    monkeypatch.setenv("BENCH_XLA_CACHE_DIR", str(tmp_path / "xla"))
-    res = bench._devices_with_timeout(8.0)
-    assert res["acquired"] is False
-    assert res["last_phase"] == "jax_import"
-    assert res["phases"] == ["env_preflight", "jax_import"]
-    assert "hung in phase 'jax_import'" in res["diagnosis"]
-    assert "2/8 of the heartbeat protocol" in res["diagnosis"]
-    # the env pre-flight report rides the diagnosis: on a real TPU
-    # wedge it says WHY the plugin had a chance to hang
-    assert "env pre-flight" in res["diagnosis"]
-    assert "libtpu" in res["diagnosis"]
-    assert res["preflight"]["chips"]["visible"] >= 0
-    # SIGUSR1 harvested the wedged child's stacks before the kill: the
-    # injected hang sleeps inside stamp(), which must be visible
-    assert res["stacks"]
-    assert "stamp" in res["stacks"]
-
-
-def test_acquire_hang_hook_emits_backend_degraded_and_falls_back(
-        tmp_path, monkeypatch):
-    """The scheduler-boot half of the acquisition hardening: the
-    BENCH_ACQUIRE_INJECT_HANG hook wedges the PJRT handshake, the
-    bounded acquire must (a) attribute the phase, (b) emit a typed
-    backend_degraded event through the sink, (c) leave the process
-    forced to CPU — all within the budget."""
-    from cranesched_tpu.parallel.acquire import (
-        ACQUIRE_PHASES,
-        acquire_backend,
-    )
-    monkeypatch.setenv("BENCH_ACQUIRE_INJECT_HANG", "backend_init")
-    monkeypatch.delenv("BENCH_PROBE_INJECT_HANG", raising=False)
-    monkeypatch.setenv("BENCH_XLA_CACHE_DIR", str(tmp_path / "xla"))
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert ACQUIRE_PHASES == PROBE_PHASES[:4]
-    events = []
-    t0 = time.monotonic()
-    res = acquire_backend(8.0, warm=False,
-                          event_sink=lambda type, sev, detail:
-                          events.append((type, sev, detail)))
-    assert time.monotonic() - t0 < 30.0  # budget + harvest grace
-    assert res["acquired"] is False
-    assert res["last_phase"] == "backend_init"
-    assert "3/4 of the heartbeat protocol" in res["diagnosis"]
-    assert [e[0] for e in events] == ["backend_degraded"]
-    assert events[0][1] == "error"
-    assert "backend_init" in events[0][2]
-    # CPU fallback applied to THIS process
-    assert os.environ["JAX_PLATFORMS"] == "cpu"
-    # per-phase stamps for cflight: monotone times, named phases
-    stamps = res["phase_stamps"]
-    assert [s["phase"] for s in stamps] == res["phases"]
-    assert all(a["t"] <= b["t"] for a, b in zip(stamps, stamps[1:]))
-
-
-def test_ensure_backend_short_circuits_on_forced_cpu(monkeypatch):
-    """With JAX_PLATFORMS=cpu pre-set the boot path must not pay a
-    probe subprocess at all — just re-apply the config forcing."""
-    from cranesched_tpu.parallel.acquire import ensure_backend
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    # a hang injection that would wedge any probe proves none ran
-    monkeypatch.setenv("BENCH_ACQUIRE_INJECT_HANG", "env_preflight")
-    t0 = time.monotonic()
-    res = ensure_backend(timeout_s=60.0)
-    assert time.monotonic() - t0 < 5.0
-    assert res["acquired"] is True
-    assert res["platform"] == "cpu"
-    assert res["attempts"] == []
-    assert "preflight" in res
-
-
-def test_probe_happy_path_completes_protocol_and_warms_xla_cache(
-        tmp_path, monkeypatch):
-    """A healthy CPU probe walks all six phases; a second probe run
-    against the same cache dir must land persistent-cache hits (the
-    warm-compile contract that takes first_compile off the critical
-    path across runs)."""
-    import bench
-    monkeypatch.delenv("BENCH_PROBE_INJECT_HANG", raising=False)
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    cache_dir = str(tmp_path / "xla")
-    monkeypatch.setenv("BENCH_XLA_CACHE_DIR", cache_dir)
-    cold = bench._devices_with_timeout(120.0)
-    assert cold["acquired"] is True, cold
-    assert cold["phases"] == list(PROBE_PHASES)
-    assert cold["platform"] == "cpu"
-    xc = cold["xla_cache"]
-    assert xc["enabled"] and not xc["error"]
-    assert xc["entries"] >= 1  # the first compile was persisted
-    warm = bench._devices_with_timeout(120.0)
-    assert warm["acquired"] is True, warm
-    assert warm["xla_cache"]["hits"] >= 1
-
 
 def test_enable_xla_cache_counts_misses_in_subprocess(tmp_path):
     """enable_xla_cache + xla_cache_stats wiring, in a subprocess so
@@ -265,11 +136,12 @@ def test_enable_xla_cache_counts_misses_in_subprocess(tmp_path):
         "xla_cache_stats\n"
         "import json, sys\n"
         "d = sys.argv[1]\n"
-        "assert enable_xla_cache(d) and enable_xla_cache(d)\n"
+        "assert enable_xla_cache() == d and enable_xla_cache() == d\n"
         "import jax, jax.numpy as jnp\n"
         "jax.jit(lambda v: v * 3.0)(jnp.arange(8.0))\n"
         "print(json.dumps(xla_cache_stats()))\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"),
                PYTHONPATH=os.path.dirname(os.path.dirname(
                    os.path.abspath(__file__))))
     out = subprocess.run(
@@ -661,62 +533,6 @@ def test_profiler_capture_dirs_never_collide_across_shards(tmp_path):
 # ---------------------------------------------------------------------------
 # cflight: the forensics viewer
 # ---------------------------------------------------------------------------
-
-def test_cflight_renders_bench_probe_diagnosis(tmp_path, capsys):
-    from cranesched_tpu.cli import cmd_cflight
-    doc = {"device_acquisition": {
-        "acquired": False,
-        "phases": ["jax_import", "backend_init", "first_trace"],
-        "diagnosis": "the TPU probe hung in phase 'first_trace'",
-        "stacks": "Thread 0x01 (most recent call first):\n  ...",
-    }}
-    path = tmp_path / "BENCH_r10.json"
-    path.write_text(json.dumps(doc))
-    args = types.SimpleNamespace(file=str(path), tail=32)
-    assert cmd_cflight(args) == 1  # not acquired -> nonzero for drills
-    out = capsys.readouterr().out
-    assert "jax_import->backend_init->first_trace" in out
-    assert "hung in phase 'first_trace'" in out
-    assert "harvested probe stacks" in out
-    # a healthy probe exits 0
-    ok = {"device_acquisition": {"acquired": True,
-                                 "phases": list(PROBE_PHASES)}}
-    path.write_text(json.dumps(ok))
-    assert cmd_cflight(args) == 0
-    # the committed BENCH_rNN.json wrapper nests the bench doc under
-    # "parsed" — cflight digs the probe outcome out of it too
-    wrapper = {"n": 10, "cmd": "python bench.py", "rc": 0,
-               "parsed": {"detail": doc}}
-    path.write_text(json.dumps(wrapper))
-    capsys.readouterr()
-    assert cmd_cflight(args) == 1
-    assert "hung in phase 'first_trace'" in capsys.readouterr().out
-
-
-def test_cflight_renders_acquisition_phase_stamps(tmp_path, capsys):
-    """ISSUE 17: the acquisition handshake's heartbeat stamps render
-    as a relative timeline, so the gap after the last stamp names the
-    wedged phase at a glance."""
-    from cranesched_tpu.cli import cmd_cflight
-    doc = {"device_acquisition": {
-        "acquired": False,
-        "phases": ["env_preflight", "jax_import", "backend_init"],
-        "phase_stamps": [
-            {"phase": "env_preflight", "t": 100.0},
-            {"phase": "jax_import", "t": 100.25},
-            {"phase": "backend_init", "t": 101.5},
-        ],
-        "diagnosis": "wedged in backend_init",
-    }}
-    path = tmp_path / "BENCH_r11.json"
-    path.write_text(json.dumps(doc))
-    args = types.SimpleNamespace(file=str(path), tail=32)
-    assert cmd_cflight(args) == 1
-    out = capsys.readouterr().out
-    assert "stamp env_preflight" in out and "+0.000s" in out
-    assert "stamp jax_import" in out and "+0.250s" in out
-    assert "stamp backend_init" in out and "+1.500s" in out
-
 
 def test_cflight_renders_live_stall(capsys):
     from cranesched_tpu.cli import _render_flight

@@ -336,8 +336,6 @@ def test_two_real_processes_agree_with_oracle(tmp_path):
     try:
         for rank in range(2):
             env = dict(os.environ)
-            env.pop("BENCH_ACQUIRE_INJECT_HANG", None)
-            env.pop("BENCH_PROBE_INJECT_HANG", None)
             env.update({
                 "JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=4",

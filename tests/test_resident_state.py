@@ -47,6 +47,8 @@ def _cluster(num_nodes: int = 4, solver: str = "device",
     cfg.setdefault("backfill", False)
     sched = JobScheduler(meta, SchedulerConfig(
         solver=solver, resident_state=resident, **cfg))
+    # no TPU under pytest: the Pallas kernel runs in the interpreter
+    sched.pallas_interpret = solver == "pallas"
     sched.licenses.configure("lic", total=2)
     sim = SimCluster(sched)
     sim.wire(sched)

@@ -47,6 +47,8 @@ def build(overlap: bool, solver: str = "auto"):
         meta.craned_up(i)
     sched = JobScheduler(meta, SchedulerConfig(
         backfill=False, solver=solver))
+    # no TPU under pytest: the Pallas kernel runs in the interpreter
+    sched.pallas_interpret = True
     return meta, sched
 
 

@@ -32,6 +32,18 @@ def test_native_library_builds_and_loads():
     assert native.available(), "native library must build (g++ is baked)"
 
 
+def test_native_library_is_keyed_by_source_and_cpu(monkeypatch):
+    """The build uses -march=native: a .so that came with a copy of the
+    tree from another CPU must never be loaded, so the file name carries
+    a key of the source text and the build host's CPU."""
+    assert native.available()
+    here = native._so_path()
+    assert os.path.exists(here)
+    assert os.path.basename(here).startswith("libcrane_native-")
+    monkeypatch.setattr(native, "_host_cpu", lambda: "another cpu")
+    assert native._so_path() != here
+
+
 @pytest.mark.parametrize("expr,expected", CASES)
 def test_parse_native_and_python_agree(expr, expected):
     assert native.parse_hostlist(expr) == expected

@@ -9,9 +9,8 @@ shape (100k jobs x 10k nodes) and prints seconds per variant:
   full     — the real kernel (reference point)
 
 Findings recorded in profiles/R05_PROFILE.md; each run also appends
-its table to profiles/$PROFILE_TAG_PROFILE.md (tools/profmd.py).  On a
-CPU-only backend the kernels run in Pallas interpret mode (use small
-BENCH_JOBS/BENCH_NODES).
+its table to profiles/$PROFILE_TAG_PROFILE.md (tools/profmd.py).
+Needs a TPU: no TPU, no profile.
 """
 
 from __future__ import annotations
@@ -84,7 +83,7 @@ def make_variant(kind: str, BJ: int, R: int, W: int, K: int = 2):
     return kernel
 
 
-def run(kind, J, N, R=3, BJ=256, interpret=False):
+def run(kind, J, N, R=3, BJ=256):
     n_pad = -(-N // (SUB * LANES)) * (SUB * LANES)
     W = n_pad // SUB
     j_pad = -(-J // BJ) * BJ
@@ -104,7 +103,6 @@ def run(kind, J, N, R=3, BJ=256, interpret=False):
         out_shape=jax.ShapeDtypeStruct((NB, 1, BJ), jnp.int32),
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((1, BJ), jnp.int32)],
-        interpret=interpret,
     )
     out = jax.jit(lambda a, b, c: fn(a, b, c))
     r = out(job, avail, cost)
@@ -122,19 +120,17 @@ if __name__ == "__main__":
     J = int(os.environ.get("BENCH_JOBS", 100_000))
     N = int(os.environ.get("BENCH_NODES", 10_000))
     kinds = sys.argv[1:] or ["floor", "bcast", "onemin", "select"]
-    device = jax.devices()[0]
-    interp = device.platform == "cpu"
-    print("device:", device,
-          "(interpret mode)" if interp else "", file=sys.stderr)
+    from profmd import append_section, require_tpu
+    device = require_tpu()
+    print("device:", device, file=sys.stderr)
     rows = []
     for kind in kinds:
-        sec = run(kind, J, N, interpret=interp)
+        sec = run(kind, J, N)
         print(f"{kind:8s}: {sec:.4f} s   ({sec / J * 1e6:.3f} us/job)")
         rows.append((kind, f"{sec:.4f}", f"{sec / J * 1e6:.3f}"))
 
-    from profmd import append_section
     path = append_section(
-        "kattr", str(device) + (" [interpret]" if interp else ""),
+        "kattr", str(device),
         {"jobs": J, "nodes": N},
         rows, ("variant", "median s", "us/job"))
     print("profile:", path, file=sys.stderr)

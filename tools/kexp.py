@@ -9,9 +9,8 @@ profiles/R05_PROFILE.md.
 Usage: python tools/kexp.py [variant ...]   (default: base)
   BENCH_JOBS/BENCH_NODES override shapes; KEXP_TRACE=dir captures a
   profiler trace of the timed region.  Results are appended to
-  profiles/$PROFILE_TAG_PROFILE.md (tools/profmd.py).  On a CPU-only
-  backend the kernel runs in Pallas interpret mode automatically (use
-  small BENCH_JOBS/BENCH_NODES — interpret mode is slow).
+  profiles/$PROFILE_TAG_PROFILE.md (tools/profmd.py).  Needs a TPU: no
+  TPU, no profile.
 """
 
 from __future__ import annotations
@@ -78,11 +77,10 @@ if __name__ == "__main__":
 
     import jax
 
+    from profmd import append_section, require_tpu
+    device = require_tpu()
+    print("device:", device, file=sys.stderr)
     state, jobs, job_part, class_masks = build_problem(num_jobs, num_nodes)
-    device = jax.devices()[0]
-    interp = device.platform == "cpu"
-    print("device:", device,
-          "(interpret mode)" if interp else "", file=sys.stderr)
 
     from cranesched_tpu.models.pallas_solver import solve_greedy_pallas
 
@@ -90,15 +88,14 @@ if __name__ == "__main__":
     if "base" in variants:
         runs["base"] = lambda bj=256: solve_greedy_pallas(
             state, jobs.req, jobs.node_num, jobs.time_limit, jobs.valid,
-            job_part, class_masks, max_nodes=2, block_jobs=bj,
-            interpret=interp)
+            job_part, class_masks, max_nodes=2, block_jobs=bj)
     for v in variants:
         if v.startswith("bj"):  # block_jobs sweep, e.g. bj512
             bj = int(v[2:])
             runs[v] = (lambda bj=bj: solve_greedy_pallas(
                 state, jobs.req, jobs.node_num, jobs.time_limit,
                 jobs.valid, job_part, class_masks, max_nodes=2,
-                block_jobs=bj, interpret=interp))
+                block_jobs=bj))
     for v in variants:
         if v.startswith("streams"):  # e.g. streams4
             ns = int(v[len("streams"):] or 4)
@@ -107,7 +104,7 @@ if __name__ == "__main__":
             runs[v] = (lambda ns=ns: solve_greedy_pallas_auto(
                 state, jobs.req, jobs.node_num, jobs.time_limit,
                 jobs.valid, job_part, class_masks, max_nodes=2,
-                max_streams=ns, interpret=interp))
+                max_streams=ns))
     if "small" in variants:
         # simulate the per-partition split: quarter nodes, quarter jobs,
         # x4 sequential solves -> what would class-split buy?
@@ -119,7 +116,7 @@ if __name__ == "__main__":
             for _ in range(4):
                 outs.append(solve_greedy_pallas(
                     st4, jb4.req, jb4.node_num, jb4.time_limit, jb4.valid,
-                    jp4 * 0, cm1, max_nodes=2, interpret=interp))
+                    jp4 * 0, cm1, max_nodes=2))
             return outs
         runs["small(x4 quarter-size)"] = run_small
 
@@ -133,9 +130,8 @@ if __name__ == "__main__":
             with jax.profiler.trace(trace_dir):
                 jax.block_until_ready(fn())
 
-    from profmd import append_section
     path = append_section(
-        "kexp", str(device) + (" [interpret]" if interp else ""),
+        "kexp", str(device),
         {"jobs": num_jobs, "nodes": num_nodes},
         rows, ("variant", "median s", "decisions/s"))
     print("profile:", path, file=sys.stderr)

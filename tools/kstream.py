@@ -11,10 +11,8 @@ prints the Scheduler YAML to pin the measured optimum — which
 
 Usage: python tools/kstream.py
   BENCH_JOBS/BENCH_NODES override shapes; KSTREAM_STREAMS and
-  KSTREAM_BLOCKS override the sweep lists (comma-separated).  On a
-  CPU-only backend the kernel runs in Pallas interpret mode with small
-  default shapes — the numbers there validate the harness, not the
-  hardware; run on the TPU for a profile worth pinning.
+  KSTREAM_BLOCKS override the sweep lists (comma-separated).  Needs a
+  TPU: no TPU, no profile.
 """
 
 from __future__ import annotations
@@ -81,20 +79,13 @@ def _int_list(env, default):
 
 
 if __name__ == "__main__":
-    import jax
-
-    device = jax.devices()[0]
-    interp = device.platform == "cpu"
-    # interpret mode is orders of magnitude slower — default to a shape
-    # that finishes, not the north-star one
-    num_jobs = int(os.environ.get("BENCH_JOBS",
-                                  2_048 if interp else 100_000))
-    num_nodes = int(os.environ.get("BENCH_NODES",
-                                   256 if interp else 10_000))
+    from profmd import append_section, require_tpu
+    device = require_tpu()
+    num_jobs = int(os.environ.get("BENCH_JOBS", 100_000))
+    num_nodes = int(os.environ.get("BENCH_NODES", 10_000))
     streams = _int_list("KSTREAM_STREAMS", [1, 2, 4, 8])
     blocks = _int_list("KSTREAM_BLOCKS", [128, 256, 512])
-    print("device:", device,
-          "(interpret mode)" if interp else "", file=sys.stderr)
+    print("device:", device, file=sys.stderr)
 
     from cranesched_tpu.models.pallas_solver import (
         plan_streams,
@@ -117,8 +108,7 @@ if __name__ == "__main__":
                 return solve_greedy_pallas_auto(
                     state, req, node_num, time_limit, valid,
                     job_class, class_masks, max_nodes=2,
-                    block_jobs=bj, max_streams=ms, plan=plan,
-                    interpret=interp)
+                    block_jobs=bj, max_streams=ms, plan=plan)
 
             sec = time_fn(run)
             dps = num_jobs / sec
@@ -134,12 +124,11 @@ if __name__ == "__main__":
           f"({used} streams, {sec:.4f} s, "
           f"{num_jobs / sec:,.0f} decisions/s)\n\npin it with:\n{yaml}")
 
-    from profmd import append_section
     dev_tag = re.sub(r"\W+", "_",
                      getattr(device, "device_kind", None)
                      or device.platform).strip("_").upper()
     path = append_section(
-        "kstream", str(device) + (" [interpret]" if interp else ""),
+        "kstream", str(device),
         {"jobs": num_jobs, "nodes": num_nodes, "classes": NUM_CLASSES},
         rows, ("max_streams", "block_jobs", "streams used", "median s",
                "decisions/s"),

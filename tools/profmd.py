@@ -10,7 +10,20 @@ that file themselves.
 from __future__ import annotations
 
 import os
+import sys
 import time
+
+
+def require_tpu():
+    """The device a kernel profile may be taken on.  A profile is a
+    device measurement: without a TPU there is none to take (the Pallas
+    interpreter on a CPU times the interpreter), so the tool stops."""
+    import jax
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        sys.exit(f"no TPU, no profile: found {device} — run this tool "
+                 "on the chip")
+    return device
 
 
 def profile_path(tag: str | None = None) -> str:

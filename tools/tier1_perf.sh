@@ -103,8 +103,8 @@ assert "recompiles" in sc and "device_buffers" in sc, (
     f"sched_cycle detail lost the introspection fields: {sc}")
 # flight-recorder guards (ISSUE 16): the always-on phase ring must cost
 # <=1% of the churn cycle wall time, and the churn leg must report the
-# persistent-XLA-cache hit rate (the probe's cross-run warm-compile
-# contract depends on the cache actually being wired)
+# persistent-XLA-cache hit rate (a restart's warm compiles depend on
+# the cache actually being wired)
 fg = ch["flight"]
 assert fg["flight_overhead_share"] <= 0.01, (
     f"flight recorder added {fg['flight_overhead_share']:.1%} to the "
@@ -112,8 +112,7 @@ assert fg["flight_overhead_share"] <= 0.01, (
 xc = fg["xla_cache"]
 assert "hit_rate" in xc and "enabled" in xc, (
     f"churn leg lost the XLA cache stats: {fg}")
-assert xc["enabled"] or xc["error"], (
-    f"XLA cache neither enabled nor diagnosed: {xc}")
+assert xc["enabled"], f"XLA cache not enabled: {xc}"
 print(f"TIER1_PERF_OK prelude_share={share:.3f} "
       f"lock_held_share={lock_share:.3f} "
       f"wal_fsyncs_per_cycle={sc['wal_fsyncs_per_cycle']} "
