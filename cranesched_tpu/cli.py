@@ -1000,13 +1000,19 @@ def cmd_cstats(args) -> int:
                  t.get("prelude_ms"), t.get("solve_ms"),
                  t.get("commit_ms"), t.get("dispatch_ms"),
                  t.get("lock_held_ms"), t.get("total_ms"),
+                 # the cycle ledger (obs/trace.py): how long the cycle
+                 # thread stood in line for the server lock, and its
+                 # whole period; a long PERIOD with a long LOCK_WAIT is
+                 # the cycle starving behind handlers ("-": a row read
+                 # before its cycle closed)
+                 t.get("lock_wait_ms", "-"), t.get("period_ms", "-"),
                  t.get("wal_fsyncs"), t.get("topo_frag", "-"))
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
             "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "PLACED",
             "BACKFILL", "PREEMPT", "SKIP", "DIRTY", "PRELUDE_MS",
             "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS", "LOCK_MS",
-            "TOTAL_MS", "FSYNC", "FRAG")))
+            "TOTAL_MS", "LOCK_WAIT_MS", "PERIOD_MS", "FSYNC", "FRAG")))
         return 0
     if getattr(args, "slo", False):
         rows = []

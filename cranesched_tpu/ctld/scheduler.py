@@ -85,7 +85,11 @@ from cranesched_tpu.obs.events import EventLog
 from cranesched_tpu.obs.flight import FlightRecorder
 from cranesched_tpu.obs.jobtrace import JobTraceRecorder
 from cranesched_tpu.obs.slo import SloEngine
-from cranesched_tpu.obs.trace import CycleTraceRing, solve_span
+from cranesched_tpu.obs.trace import (
+    CycleClock,
+    CycleTraceRing,
+    solve_span,
+)
 from cranesched_tpu.topo.place import solve_greedy_topo
 from cranesched_tpu.ops.resources import CPU_SCALE, DIM_CPU, DIM_MEM
 
@@ -611,6 +615,12 @@ class JobScheduler:
         # by the server lock, so one slot suffices
         self.cycle_trace = CycleTraceRing(config.cycle_trace_ring)
         self._cur_trace: dict = {}
+        # the cycle thread's ledger (obs/trace.py CycleClock): the
+        # server's loop and this module mark phase boundaries on it,
+        # and cycle_phases' last act writes the period's parts into the
+        # row this cycle ringed (_ledger_row; None when it ringed none)
+        self.cycle_clock = CycleClock()
+        self._ledger_row: dict | None = None
         # per-job lifecycle tracing + SLO plane (obs/jobtrace.py,
         # obs/slo.py): None when JobTrace is off — every stamp site
         # guards on it, so "off" removes the whole layer from the hot
@@ -928,6 +938,7 @@ class JobScheduler:
                 "backfilled": 0, "running": len(self.running)}
             self.cycle_trace.push(trace)
             self._skip_trace = trace
+        self._ledger_row = self._skip_trace
         return []
 
     def can_idle(self) -> bool:
@@ -2228,8 +2239,13 @@ class JobScheduler:
         # introspection: per-cycle recompile attribution + the armed
         # profiler capture window tick (cheap no-ops when idle)
         self._cycle_compile_base = introspect.total_compiles()
+        clock = self.cycle_clock
+        clock.mark("record")
+        self._ledger_row = None
         self.profiler_window.tick()
+        clock.annotate = self.profiler_window.capturing
         self.flight.stamp("cycle_begin")
+        clock.mark("drain")
         self._wal_begin()
         try:
             started = yield from self._cycle_body(now)
@@ -2239,9 +2255,22 @@ class JobScheduler:
             # phases: no WAL event may sit buffered across cycles, and
             # a job committed to RUNNING must still get its dispatch
             # (drained inline here; the normal path drained lock-free)
+            clock.mark("wal")
             self._wal_flush()
             self._drain_dispatch_ring()
             self.flight.stamp("cycle_end")
+            self._close_cycle_ledger()
+
+    def _close_cycle_ledger(self) -> None:
+        """The period ends here, under the lock: its parts go into the
+        row this cycle ringed, in place (QueryStats serialises the ring
+        under the same lock).  dispatch_ms stays _note_dispatch's."""
+        fields = self.cycle_clock.close()
+        row = self._ledger_row
+        if row is not None:
+            row.update(fields)
+            if self.cycle_clock.annotate:
+                row["profiled"] = True
 
     def _wal_begin(self) -> None:
         if self.wal is not None:
@@ -2319,6 +2348,7 @@ class JobScheduler:
         import time as _time
 
         def run():
+            self.cycle_clock.mark("dispatch")
             t0 = _time.perf_counter()
             n = self._drain_dispatch_ring()
             return n, (_time.perf_counter() - t0) * 1e3
@@ -2375,6 +2405,8 @@ class JobScheduler:
 
         self.stats["cycles"] += 1
         _MET_CYCLES.inc()
+        clock = self.cycle_clock
+        clock.mark("candidates")
         candidates = self._pending_candidates(now)
         if self.jobtrace is not None and candidates:
             # first-sight "eligible" stamp per incarnation; the Job
@@ -2412,10 +2444,13 @@ class JobScheduler:
                 self._cand_rows = self._cand_rows[:limit]
 
         # snapshot + event capture window (cpp:1437)
+        clock.mark("snapshot")
         self.meta.start_logging()
         avail, total, alive = self.meta.snapshot()
 
+        clock.mark("priority")
         ordered = self._priority_sort(candidates, now)
+        clock.mark("build")
         for job in ordered:
             # spec epoch for the lock-free solve window: modify_job
             # REPLACES job.spec (dataclasses.replace), so object
@@ -2443,14 +2478,13 @@ class JobScheduler:
         if packed:
             state = make_cluster_state(avail, total, alive, cost0)
             pbatch = self._packed_batch(jobs_batch.dense, ordered)
-            self._wal_flush()
-            placements = yield self._traced_solve(
+            placements = yield from self._solve_phase(
                 "packed", lambda: solve_packed(
                     state, pbatch, max_nodes=max_nodes)[0])
-            self._wal_begin()
             started = self._commit(ordered, placements, now,
                                    tasks=np.asarray(placements.tasks))
             started += self._try_preemption(ordered, now)
+            clock.mark("wal")
             self._wal_flush()
             self._record_cycle_stats(t0, t_prelude, candidates, started,
                                      _time.perf_counter(), "packed")
@@ -2476,14 +2510,13 @@ class JobScheduler:
                      if isinstance(jobs_batch, FactoredJobBatch)
                      else jobs_batch)
             levels = topo.jnp_levels
-            self._wal_flush()
-            placements, _, topo_info = yield self._traced_solve(
+            placements, _, topo_info = yield from self._solve_phase(
                 "topo", lambda: solve_greedy_topo(
                     state, dense, levels, max_nodes=max_nodes))
-            self._wal_begin()
             self._note_topo(topo, ordered, topo_info)
             started = self._commit(ordered, placements, now)
             started += self._try_preemption(ordered, now)
+            clock.mark("wal")
             self._wal_flush()
             self._record_cycle_stats(t0, t_prelude, candidates, started,
                                      _time.perf_counter(), "topo")
@@ -2498,6 +2531,7 @@ class JobScheduler:
                     ordered, jobs_batch, avail, total, alive, cost0,
                     max_nodes, now)
                 started += self._try_preemption(ordered, now)
+                clock.mark("wal")
                 self._wal_flush()
                 self._record_cycle_stats(t0, t_prelude, candidates,
                                          started,
@@ -2508,29 +2542,27 @@ class JobScheduler:
                 return started
             state = self._timed_state(now, avail, total, alive, cost0)
             tbatch = self._timed_batch(jobs_batch.dense, ordered)
-            self._wal_flush()
-            placements = yield self._traced_solve(
+            placements = yield from self._solve_phase(
                 "backfill", lambda: solve_backfill(
                     state, tbatch, edges=self._grid.jnp_edges,
                     max_nodes=max_nodes)[0])
-            self._wal_begin()
             start_buckets = np.asarray(placements.start_bucket)
             self._cur_trace["backfilled"] = int(np.sum(
                 np.asarray(placements.placed) & (start_buckets > 0)))
         else:
-            self._wal_flush()
-            placements, solver_name = yield self._traced_solve(
+            placements, solver_name = yield from self._solve_phase(
                 None, lambda: self._immediate_solve(
                     avail, total, alive, cost0, jobs_batch, max_nodes,
                     resident_ok=True))
-            self._wal_begin()
             start_buckets = None
 
         started = self._commit(ordered, placements, now, start_buckets)
         started += self._try_preemption(ordered, now)
+        clock.mark("wal")
         self._wal_flush()
         # double buffer: pre-upload the rows this commit dirtied so the
         # next cycle's resident patch finds them already on device
+        clock.mark("commit_apply")
         self._resident.stage()
         self._record_cycle_stats(
             t0, t_prelude, candidates, started, _time.perf_counter(),
@@ -2715,35 +2747,49 @@ class JobScheduler:
 
         state = self._timed_state(now, avail, total, alive, cost0)
         tb = self._timed_batch(head_batch, head)
-        self._wal_flush()
-        placements, tstate = yield self._traced_solve(
+        placements, tstate = yield from self._solve_phase(
             "backfill", lambda: solve_backfill(
                 state, tb, edges=self._grid.jnp_edges,
                 max_nodes=max_nodes))
-        self._wal_begin()
         head_start = np.asarray(placements.start_bucket)
         self._cur_trace["backfilled"] = int(np.sum(
             np.asarray(placements.placed) & (head_start > 0)))
         started = self._commit(head, placements, now, head_start)
 
         # pass 2: the tail against the tightest bucket of the horizon
+        clock = self.cycle_clock
+        clock.mark("snapshot")
         self.meta.start_logging()   # fresh event window for this commit
 
         def _tail_solve():
+            clock.mark("solve_host")
             min_avail = np.asarray(jnp.min(tstate.time_avail, axis=1))
             cost1 = np.asarray(tstate.cost)
+            clock.mark("solve_enqueue")
             return self._immediate_solve(
                 min_avail, total, alive, cost1, tail_batch, max_nodes)
 
-        self._wal_flush()
-        placements2, _ = yield self._traced_solve(None, _tail_solve)
-        self._wal_begin()
+        placements2, _ = yield from self._solve_phase(None, _tail_solve)
         tail_placements = Placements(
             placed=placements2.placed[bf_max:],
             nodes=placements2.nodes[bf_max:],
             reason=placements2.reason[bf_max:])
         started += self._commit(tail, tail_placements, now)
         return started
+
+    def _solve_phase(self, backend, fn):
+        """Yield one solve closure with the cycle's WAL group closed
+        across it (a group must never stay open over a lock release)
+        and a fresh one begun once the lock is held again.  What
+        follows, up to the next mark, is the commit's."""
+        clock = self.cycle_clock
+        clock.mark("wal")
+        self._wal_flush()
+        out = yield self._traced_solve(backend, fn)
+        clock.mark("wal")
+        self._wal_begin()
+        clock.mark("commit_apply")
+        return out
 
     def _traced_solve(self, backend, fn):
         """Wrap a yielded solve closure: time it (this is the
@@ -2754,9 +2800,11 @@ class JobScheduler:
         (the _immediate_solve contract)."""
         import time as _time
         trace = self._cur_trace
+        clock = self.cycle_clock
 
         def run():
             label = backend or "immediate"
+            clock.mark("solve_enqueue")
             t0 = _time.perf_counter()
             # the cycle's PRELUDE ends when the first solve starts:
             # priority sort + batch build + stream planning all count
@@ -2770,8 +2818,10 @@ class JobScheduler:
             # solve to the commit phase (the np.asarray sync there)
             first = out[0] if isinstance(out, tuple) else out
             sync = getattr(first, "placed", None)
+            clock.mark("solve_device_wait")
             if hasattr(sync, "block_until_ready"):
                 sync.block_until_ready()
+            clock.mark("solve_host")
             dt = _time.perf_counter() - t0
             if (backend is None and isinstance(out, tuple)
                     and len(out) == 2 and isinstance(out[1], str)):
@@ -2787,6 +2837,7 @@ class JobScheduler:
     def _record_cycle_stats(self, t0, t_prelude, candidates, started,
                             t_end, solver: str) -> None:
         import time as _time
+        self.cycle_clock.mark("record")
         self.stats["jobs_started_total"] += len(started)
         _MET_STARTED.inc(len(started))
         self.flight.stamp("commit", detail=str(len(started)))
@@ -2814,7 +2865,6 @@ class JobScheduler:
         self.stats["last_cycle"] = {
             "solver": solver,
             "prelude_ms": round(prelude_ms, 3),
-            "solve_commit_ms": round((t_end - t_prelude) * 1e3, 3),
             "total_ms": round(total_ms, 3),
             "dispatch_ms": 0.0,
             "pending": len(candidates),
@@ -2825,7 +2875,6 @@ class JobScheduler:
         trace = self._cur_trace
         trace.update(
             solver=solver,
-            drain_ms=round(drain_ms, 3),
             prelude_ms=round(prelude_ms, 3),
             solve_ms=round(solve_ms, 3),
             commit_ms=round(commit_ms, 3),
@@ -2874,6 +2923,7 @@ class JobScheduler:
                     self.stats["cycles"], recompiles))
         self._in_cycle = False
         self.cycle_trace.push(trace)
+        self._ledger_row = trace
         self._skip_trace = None
         _MET_PHASE.observe(prelude_ms / 1e3, phase="prelude")
         _MET_PHASE.observe(solve_ms / 1e3, phase="solve")
@@ -3257,6 +3307,7 @@ class JobScheduler:
         job that got only a future-start backfill reservation can still
         preempt its way to an immediate start (the reference's ordering:
         TryPreempt_ before Backfill_, cpp:6369-6378)."""
+        self.cycle_clock.mark("preempt")
         if self.config.preempt_mode == "off" or self.accounts is None:
             return []
         # blocked preemptor candidates, in priority order

@@ -36,6 +36,12 @@ SNAPSHOTS = REGISTRY.counter(
     "crane_ha_snapshots_total", "durable snapshots written")
 WAL_SEQ_GAUGE = REGISTRY.gauge(
     "crane_ha_wal_seq", "last durable WAL sequence number")
+SNAPSHOT_LOCK_HELD = REGISTRY.histogram(
+    "crane_snapshot_lock_held_seconds",
+    "server-lock-held time of one snapshot (capture + WAL rotate)")
+SNAPSHOT_SECONDS = REGISTRY.histogram(
+    "crane_snapshot_seconds",
+    "whole length of one snapshot pass (lock wait, capture, save, prune)")
 
 from cranesched_tpu.ha.lease import FencingEpoch, LeaderLease  # noqa: E402
 from cranesched_tpu.ha.snapshot import (  # noqa: E402
@@ -48,6 +54,7 @@ from cranesched_tpu.ha.follower import HaFollower  # noqa: E402
 
 __all__ = [
     "ROLE_GAUGE", "LAG_GAUGE", "FAILOVERS", "SNAPSHOTS", "WAL_SEQ_GAUGE",
+    "SNAPSHOT_LOCK_HELD", "SNAPSHOT_SECONDS",
     "FencingEpoch", "LeaderLease", "SnapshotStore", "Snapshotter",
     "capture_snapshot", "restore_snapshot", "HaFollower",
 ]

@@ -20,6 +20,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 
 from cranesched_tpu.ctld.defs import JobStatus
 from cranesched_tpu.ctld.wal import _job_from_dict, _job_to_dict
@@ -191,18 +192,29 @@ class Snapshotter(threading.Thread):
         """One capture+rotate+persist+prune pass.  Returns the snapshot
         seq (0 = skipped, nothing new)."""
         from cranesched_tpu import ha as _ha
+        t0 = time.perf_counter()
         with self.lock:
+            t_locked = time.perf_counter()
             seq = self.wal.durable_seq
             if seq - self.last_seq < self.min_records:
                 return 0
             doc = capture_snapshot(self.scheduler, seq)
             self.wal.rotate()
+        # what every handler and the cycle waited behind (the wait FOR
+        # the lock is not in it), then the whole pass with its save
+        held = time.perf_counter() - t_locked
         self.store.save(doc)
         self.wal.prune_segments(seq)
+        took = time.perf_counter() - t0
         self.last_seq = seq
         self.snapshots_taken += 1
         _ha.SNAPSHOTS.inc()
         _ha.WAL_SEQ_GAUGE.set(seq)
+        _ha.SNAPSHOT_LOCK_HELD.observe(held)
+        _ha.SNAPSHOT_SECONDS.observe(took)
+        self.scheduler.events.emit(
+            "snapshot", "info",
+            detail="seq=%d lock_held=%.3fs took=%.3fs" % (seq, held, took))
         return seq
 
     def stop(self) -> None:

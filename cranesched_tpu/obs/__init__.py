@@ -4,7 +4,9 @@ lifecycle tracing with SLOs, and the scheduler watchdog.
 - ``metrics.py``   process-wide registry of counters / gauges /
                    histograms with Prometheus text exposition and a
                    stdlib HTTP endpoint (no prometheus_client dep).
-- ``trace.py``     bounded ring of structured per-cycle traces plus the
+- ``trace.py``     bounded ring of structured per-cycle traces, the
+                   cycle thread's partitioning clock (``CycleClock``:
+                   the parts of a period sum to it) and the
                    jax.profiler span helper used around solve closures.
 - ``jobtrace.py``  event-sourced per-job timelines (one span per
                    lifecycle edge, ctld + craned clock domains) and the

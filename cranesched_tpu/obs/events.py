@@ -42,6 +42,8 @@ docs assert on lives in :data:`EVENT_TYPES`:
     requeue (info)            a job went back to pending
     recompile_steady (warning) a warm cycle paid a fresh jit compile
     profile_capture (info)    a profiler window started/stopped
+    snapshot (info)           the snapshotter wrote one (detail: seq,
+                              seconds under the server lock, in all)
     fed_lease_granted (info)  this shard leased nodes to the arbiter
     fed_lease_revoked (warning) a lease expired/aborted and was dropped
     fed_forward (info)        a misrouted submit was forwarded
@@ -69,7 +71,7 @@ EVENT_TYPES = frozenset({
     "node_up", "node_down", "node_flap", "node_drain", "node_undrain",
     "node_poweroff", "node_wake", "fencing_rejection", "watchdog_crash",
     "failover", "slo_breach", "slo_clear", "preemption", "requeue",
-    "recompile_steady", "profile_capture",
+    "recompile_steady", "profile_capture", "snapshot",
     # federated control plane (fed/): lease lifecycle on the shard,
     # misrouted-submit forwarding, arbiter two-phase outcomes
     "fed_lease_granted", "fed_lease_revoked", "fed_forward",

@@ -181,8 +181,8 @@ class ProfilerWindow:
     ``request(cycles, out_dir)`` arms the window; the scheduler calls
     :meth:`tick` once per cycle (cheap no-op while disarmed).  The
     first tick after arming starts the trace; after ``cycles`` more
-    ticks the trace stops and the capture directory is recorded in
-    :attr:`last_capture`.  Never raises into the cycle loop."""
+    ticks a helper thread stops it and records the capture directory
+    in :attr:`last_capture`.  Never raises into the cycle loop."""
 
     def __init__(self, base_dir: str = "profiles",
                  event_sink: Optional[Callable] = None,
@@ -219,7 +219,7 @@ class ProfilerWindow:
         if cycles <= 0:
             return False, "cycles must be > 0"
         with self._lock:
-            if self._armed or self._remaining:
+            if self._armed or self._remaining or self._active_dir:
                 return False, "capture already in progress"
             self._capture_seq += 1
             ns = self._namespace()
@@ -243,7 +243,13 @@ class ProfilerWindow:
             try:
                 os.makedirs(d, exist_ok=True)
                 import jax
-                jax.profiler.start_trace(d)
+
+                # host tracer on (TraceAnnotations are its events), the
+                # Python tracer off: it stretched every traced cycle
+                # 3-6x and stalled the daemon ~18 s at stop_trace
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(d, profiler_options=options)
                 with self._lock:
                     self._remaining = self._armed
                     self._armed = 0
@@ -258,24 +264,41 @@ class ProfilerWindow:
                 log.warning("profiler capture failed to start: %s", e)
             return
         with self._lock:
-            self._remaining -= 1
-            done = self._remaining <= 0
+            self._remaining = max(self._remaining - 1, 0)
+            done = self._remaining == 0
         if done:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception as e:
-                with self._lock:
-                    self.last_error = str(e)
-                log.warning("profiler capture failed to stop: %s", e)
+            # off the cycle thread, and so off the server lock the tick
+            # runs under: with the Python tracer off, stop_trace still
+            # took 13.8 s with four minload-5k cycles in the trace (PERF.md)
+            threading.Thread(target=self._stop, name="profiler-stop",
+                             daemon=True).start()
+
+    def _stop(self) -> None:
+        """Stop the trace and record the capture; the window stays
+        taken (``_active_dir``) until the file is written."""
+        t0 = time.perf_counter()
+        try:
+            import jax
+            jax.profiler.stop_trace()
+        except Exception as e:
             with self._lock:
-                self.last_capture = self._active_dir
-                self._active_dir = ""
-                self._remaining = 0
-                self.captures_done += 1
-            if self.event_sink is not None:
-                self.event_sink("profile_capture", "info",
-                                detail="written: %s" % self.last_capture)
+                self.last_error = str(e)
+            log.warning("profiler capture failed to stop: %s", e)
+        with self._lock:
+            self.last_capture = self._active_dir
+            self._active_dir = ""
+            self.captures_done += 1
+        if self.event_sink is not None:
+            self.event_sink(
+                "profile_capture", "info",
+                detail="written: %s (stop_trace took %.2f s)" % (
+                    self.last_capture, time.perf_counter() - t0))
+
+    @property
+    def capturing(self) -> bool:
+        """True between the tick that started a trace and the tick
+        that stops it."""
+        return self._remaining > 0
 
     def status(self) -> dict:
         with self._lock:

@@ -1,4 +1,5 @@
-"""Structured per-cycle traces + profiler span helper.
+"""Structured per-cycle traces, the cycle thread's partitioning clock,
+and the profiler span helper.
 
 ``CycleTraceRing`` keeps the last N cycle traces (plain dicts, schema
 below) in a bounded deque — cheap enough to run always-on, queryable
@@ -8,13 +9,24 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
 
     now              float   scheduler clock the cycle ran at
     solver           str     backend ("native", "pallas", "backfill"...)
-    prelude_ms       float   lock-held bookkeeping before the solve
-    solve_ms         float   lock-RELEASED time in yielded closures
-    commit_ms        float   lock-held time after the first solve
+    prelude_ms       float   _cycle_body's start to the first solve
+                             closure's start (drains, candidates,
+                             snapshot, priority, batch build)
+    solve_ms         float   the solve closures, on their own timers
+    commit_ms        float   REMAINDER: total_ms - prelude_ms -
+                             solve_ms.  Includes the cycle thread's
+                             waits to retake the server lock after each
+                             closure, so an upper bound of the commit
+                             (commit_apply_ms + wal_ms + preempt_ms +
+                             record_ms are the commit itself)
     dispatch_ms      float   lock-RELEASED post-commit push fan-out
-    total_ms         float   wall time of the whole cycle
-    lock_held_ms     float   prelude_ms + commit_ms (never the solve
-                             and never the dispatch drain)
+    total_ms         float   _cycle_body's start to the end of
+                             _record_cycle_stats' inputs; leaves out the
+                             first lock take, the sim plane's advance
+                             and the dispatch closure
+    lock_held_ms     float   prelude_ms + commit_ms: an upper bound
+                             too, it books the retakes' WAITING as
+                             holding (lock_held_work_ms is the holding)
     wal_fsyncs       int     durability barriers this cycle (== WAL
                              groups when group commit is active)
     wal_groups       int     WAL groups flushed this cycle (<= 3)
@@ -33,16 +45,64 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
                              (idle clusters would otherwise flush the
                              ring with identical no-op entries)
 
+The cycle ledger (``CycleClock``; all ms, one clock, written into the
+ringed dict when the cycle closes, so a row read while its dispatch
+closure still runs lacks them).  The parts PARTITION the cycle thread's
+period: with dispatch_ms and unnamed_ms they sum to period_ms.
+
+    period_ms        the close of the previous cycle's ledger to the
+                     close of this one: the sleep before the cycle, the
+                     cycle, its dispatch closure, the retake after it
+    sleep_ms         in the loop's event wait
+    lock_wait_ms     waiting for the server lock, over every take of
+                     the cycle (the first and one after each closure)
+    lock_wait_max_ms the longest single one of those waits
+    sim_ms           first lock take to cycle_phases: the sim node
+                     plane's advance_to (0 on a real node plane) and
+                     the federation's lease expiry
+    record_ms        the instruments themselves: the profiler window's
+                     tick and _record_cycle_stats (device-memory
+                     sample, histograms, ring push)
+    drain_ms         process_status_changes ... array children, the
+                     no-op fingerprint
+    candidates_ms    _pending_candidates + the "eligible" stamps
+    snapshot_ms      meta.start_logging + meta.snapshot
+    priority_ms      _priority_sort (the device priority and its wait)
+    build_ms         _build_batch, cost0, route set-up, up to the first
+                     WAL flush before a solve
+    solve_enqueue_ms inside the closures: fn() until it returns
+    solve_device_wait_ms  block_until_ready on the placements
+    solve_host_ms    the rest of the closures (the split route's
+                     min-over-horizon round trip to numpy)
+    commit_apply_ms  from a solve's results to the end of _commit
+                     (both calls on the split route), and the resident
+                     state's staging
+    wal_ms           the cycle's _wal_flush / _wal_begin, fsync included
+    preempt_ms       _try_preemption
+    lock_held_work_ms  the sum of the parts that ran under the lock
+                     (LOCKED_PARTS): what a submit or a query waits
+                     behind
+    unnamed_ms       period_ms less every part: the loop's glue
+    gc_ms            growth over the period of the process-wide
+                     collector-pause accumulator (``GC_PAUSES``); lies
+                     INSIDE the parts above, on whichever thread paid
+    profiled         bool, only on rows of cycles that ran inside a
+                     ProfilerWindow capture
+
 ``solve_span`` wraps a solve closure in ``jax.profiler.TraceAnnotation``
 so tools/kexp.py traces line up with cycle phases; it degrades to a
-no-op when the profiler is unavailable (CPU CI containers).
+no-op when the profiler is unavailable (CPU CI containers).  Inside a
+capture (and only then) ``CycleClock`` puts each phase on the
+profiler's clock too, as ``crane:cycle:<phase>``.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import threading
+import time
 from typing import Iterator
 
 
@@ -66,6 +126,109 @@ class CycleTraceRing:
     def __len__(self) -> int:
         with self._lock:
             return len(self._ring)
+
+
+class GcPauses:
+    """Seconds the collector has paused the process, summed by one
+    ``gc.callbacks`` hook (two clock reads a collection).  Collections
+    never overlap, so one start slot suffices."""
+
+    def __init__(self):
+        self.total_s = 0.0
+        self._t0 = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.total_s += time.perf_counter() - self._t0
+            self._t0 = None
+
+    def install(self) -> None:
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+
+#: the process's one accumulator: the collector is the process's too
+GC_PAUSES = GcPauses()
+
+#: the phases that run under the server lock (lock_held_work_ms)
+LOCKED_PARTS = ("sim", "record", "drain", "candidates", "snapshot",
+                "priority", "build", "commit_apply", "wal", "preempt")
+#: every named phase; with "dispatch" and the glue they tile a period
+PARTS = ("sleep", "lock_wait") + LOCKED_PARTS + (
+    "solve_enqueue", "solve_device_wait", "solve_host")
+
+
+class CycleClock:
+    """The cycle thread's partitioning clock: ``mark(name)`` reads the
+    clock once, charges the time since the previous mark to the phase
+    that mark opened, and opens ``name``.  Every instant of the thread
+    therefore belongs to exactly one phase and the parts sum to the
+    period with no remainder arithmetic; a phase that recurs (the split
+    route solves and commits twice) accumulates.  Owned by the
+    scheduler, touched by the cycle thread alone.
+
+    While ``annotate`` is set (the scheduler sets it from the
+    ProfilerWindow once per cycle) each mark also closes the previous
+    and opens the next ``crane:cycle:<phase>`` TraceAnnotation, so the
+    host phases land in the profiler's trace beside the device ops.
+    Outside a capture no profiler object is touched."""
+
+    GLUE = "unnamed"
+
+    def __init__(self):
+        GC_PAUSES.install()
+        self.annotate = False
+        self._span = None
+        self._phase = self.GLUE
+        self._parts: dict[str, float] = {}
+        self._lock_wait_max = 0.0
+        self._t = self._t_open = time.perf_counter()
+        self._gc_open = GC_PAUSES.total_s
+
+    def mark(self, name: str) -> None:
+        t = time.perf_counter()
+        dt = t - self._t
+        self._t = t
+        phase = self._phase
+        self._parts[phase] = self._parts.get(phase, 0.0) + dt
+        if phase == "lock_wait" and dt > self._lock_wait_max:
+            self._lock_wait_max = dt
+        self._phase = name
+        if self._span is not None or self.annotate:
+            self._annotate(name)
+
+    def _annotate(self, name: str) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        if self.annotate and name != self.GLUE:
+            from jax.profiler import TraceAnnotation
+            self._span = TraceAnnotation("crane:cycle:" + name)
+            self._span.__enter__()
+
+    def close(self) -> dict:
+        """End the period: its fields, in ms, and a fresh ledger."""
+        self.mark(self.GLUE)
+        parts, self._parts = self._parts, {}
+        ms = {name: parts.get(name, 0.0) * 1e3 for name in PARTS}
+        fields = {name + "_ms": round(v, 3) for name, v in ms.items()}
+        period_ms = (self._t - self._t_open) * 1e3
+        fields.update(
+            period_ms=round(period_ms, 3),
+            lock_wait_max_ms=round(self._lock_wait_max * 1e3, 3),
+            lock_held_work_ms=round(
+                sum(ms[name] for name in LOCKED_PARTS), 3),
+            # by subtraction, so a phase no field names shows up here
+            unnamed_ms=round(period_ms - sum(ms.values())
+                             - parts.get("dispatch", 0.0) * 1e3, 3),
+            gc_ms=round((GC_PAUSES.total_s - self._gc_open) * 1e3, 3),
+        )
+        self._t_open = self._t
+        self._lock_wait_max = 0.0
+        self._gc_open = GC_PAUSES.total_s
+        return fields
 
 
 @contextlib.contextmanager

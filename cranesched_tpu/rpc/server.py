@@ -1738,12 +1738,19 @@ class CtldServer:
         crane_cycle_crashes_total is bumped, the half-run generator is
         closed, and the NEXT tick schedules normally (fault-injection
         test: tests/test_obs.py)."""
+        # the thread's ledger (obs/trace.py CycleClock): marks here and
+        # in _cycle_once tile its whole period, the sleep and every
+        # wait for the lock among the parts
+        clock = self.scheduler.cycle_clock
         while not self._stop.is_set():
             # condition-variable tick: any event ends the sleep early;
             # with no events the timeout is the base cadence, or the
             # idle bound when the scheduler proves the next cycle would
             # be a no-op anyway (_sleep_interval)
-            self._cycle_kick.wait(self._sleep_interval())
+            timeout = self._sleep_interval()
+            clock.mark("sleep")
+            self._cycle_kick.wait(timeout)
+            clock.mark(clock.GLUE)
             self._cycle_kick.clear()
             if self._stop.is_set():
                 break
@@ -1780,10 +1787,23 @@ class CtldServer:
         idle = float(getattr(sched.config, "cycle_idle_sleep", 0.0))
         if self.ha_role != "leader" or idle <= base:
             return base
+        # the loop's own take of the lock: the cycle thread queues here
+        # behind handlers as it does in _cycle_once
+        clock = sched.cycle_clock
+        clock.mark("lock_wait")
         with self._lock:
+            clock.mark(clock.GLUE)
             if not sched.can_idle():
                 return base
             wake = sched.next_wake_time(time.time())
+            if self.sim is not None:
+                # the sim node plane reports a completion only inside
+                # advance_to, i.e. inside a cycle, where a real craned's
+                # status RPC kicks the loop: wake for its next one, or
+                # an idle loop leaves finished jobs Running
+                due = self.sim.next_event_time()
+                if due is not None:
+                    wake = min(wake, due)
         if wake == float("inf"):
             return idle
         return min(idle, max(wake - time.time(), base))
@@ -1791,9 +1811,12 @@ class CtldServer:
     def _cycle_once(self, now: float) -> None:
         """One lock-break cycle: state phases under the lock, solve
         closures outside it."""
+        clock = self.scheduler.cycle_clock
         gen = None
         try:
+            clock.mark("lock_wait")
             with self._lock:
+                clock.mark("sim")
                 if self.sim is not None:
                     self.sim.advance_to(now)
                 if self.scheduler.fed is not None:
@@ -1808,7 +1831,9 @@ class CtldServer:
                     return
             while True:
                 result = fn()          # lock released: the solve
+                clock.mark("lock_wait")
                 with self._lock:
+                    clock.mark(clock.GLUE)
                     try:
                         fn = gen.send(result)
                     except StopIteration:

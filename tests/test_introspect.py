@@ -8,6 +8,7 @@ replication end-to-end), Prometheus exposition round-trip, the
 import collections
 import json
 import re
+import time
 import urllib.request
 
 import numpy as np
@@ -141,7 +142,7 @@ def test_profiler_window_lifecycle(tmp_path, monkeypatch):
 
     traces = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: traces.append(("start", d)))
+                        lambda d, **kw: traces.append(("start", d)))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: traces.append(("stop", None)))
     sink = []
@@ -159,7 +160,10 @@ def test_profiler_window_lifecycle(tmp_path, monkeypatch):
     assert pw.status()["remaining"] == 2
     pw.tick()
     assert pw.status()["remaining"] == 1 and pw.captures_done == 0
-    pw.tick()  # countdown hits zero -> stop + record
+    pw.tick()  # countdown hits zero -> a helper thread stops + records
+    deadline = time.time() + 10.0
+    while pw.captures_done == 0 and time.time() < deadline:
+        time.sleep(0.01)
     assert traces[-1] == ("stop", None)
     st = pw.status()
     assert st["captures_done"] == 1 and st["last_capture"] == d
@@ -175,7 +179,7 @@ def test_profiler_window_lifecycle(tmp_path, monkeypatch):
 def test_profiler_window_never_raises_into_cycle(tmp_path, monkeypatch):
     import jax
 
-    def boom(d):
+    def boom(d, **kw):
         raise RuntimeError("no backend profiler")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
