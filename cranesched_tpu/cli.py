@@ -989,7 +989,9 @@ def cmd_cstats(args) -> int:
                  # single process over 8 chips); "-" for host solvers
                  t.get("mesh", "-"),
                  t.get("queue_depth"),
-                 t.get("candidates"), t.get("placed"),
+                 t.get("candidates"),
+                 # K: the static gang bound the cycle's solves paid
+                 t.get("gang_bound", "-"), t.get("placed"),
                  t.get("backfilled"), t.get("preempted"),
                  # SKIP: coalesced short-circuit count (+ reason);
                  # DIRTY: jobs/nodes patched since the last cycle
@@ -1009,7 +1011,7 @@ def cmd_cstats(args) -> int:
                  t.get("wal_fsyncs"), t.get("topo_frag", "-"))
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
-            "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "PLACED",
+            "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "K", "PLACED",
             "BACKFILL", "PREEMPT", "SKIP", "DIRTY", "PRELUDE_MS",
             "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS", "LOCK_MS",
             "TOTAL_MS", "LOCK_WAIT_MS", "PERIOD_MS", "FSYNC", "FRAG")))

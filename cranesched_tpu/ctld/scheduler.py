@@ -2887,6 +2887,9 @@ class JobScheduler:
             wal_fsyncs=wal_fsyncs,
             wal_groups=wal_groups,
             candidates=len(candidates),
+            # BASELINE's yardstick as the served path pays it
+            decisions_per_s=round(len(candidates) * 1e3 / solve_ms, 1)
+            if solve_ms > 0 else 0.0,
             placed=len(started),
             dirty_jobs=self._ptable.last_dirty,
             dirty_nodes=self.meta.last_snapshot_dirty,
@@ -4003,6 +4006,13 @@ class JobScheduler:
                                self.config.max_nodes_per_job))
         # bucket the static gang bound too (it is a jit static arg)
         max_nodes = self._bucket(max_nodes, floor=1)
+        # every job of the cycle pays max_nodes selection passes whatever
+        # its own width: the bound, and the share of the passes needed
+        if ordered:
+            self._cur_trace.update(
+                gang_bound=max_nodes,
+                gang_fill_pct=round(100.0 * int(node_num.sum())
+                                    / (len(ordered) * max_nodes), 3))
         rows_np, table = self._mask_table.tables()
         batch = FactoredJobBatch(
             req=jnp.asarray(req), node_num=jnp.asarray(node_num),

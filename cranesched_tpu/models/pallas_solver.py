@@ -270,8 +270,15 @@ def _job_scalars(req, node_num, time_limit, valid, job_class, C):
     ], axis=1)                                         # [J, R + 4]
 
 
+#: the kernels' names as a device trace shows them (the custom call's
+#: HLO name, plus XLA's ".N"): what a per-kernel metric keys on, so a
+#: refactor of the functions around them does not rename them
+KERNEL_SERIAL = "crane_greedy_serial"
+KERNEL_STREAMED = "crane_greedy_streamed"
+
+
 def _launch(job_p, nelig, avail3, cost2, elig3, cputot3,
-            S, NB, BJ, K, R, W, C, interpret):
+            S, NB, BJ, K, R, W, C, interpret, name):
     """pallas_call plumbing shared by both entry points.  job_p is
     [S, R+4, NB*BJ] (scalar axis innermost so the SMEM BlockSpec
     (S, R+4, BJ) slices the job axis per grid step); returns raw
@@ -305,6 +312,7 @@ def _launch(job_p, nelig, avail3, cost2, elig3, cputot3,
             pltpu.VMEM((S, BJ), jnp.int32),
         ],
         interpret=interpret,
+        name=name,
     )(job_p, nelig, avail3, cost2, elig3, cputot3)
 
 
@@ -330,7 +338,7 @@ def _solve_serial_impl(state: ClusterState, req, node_num, time_limit,
 
     placed, chosen, reason, avail_f, cost_f = _launch(
         job_p, nelig, avail3, cost2, elig3, cputot3,
-        1, NB, BJ, K, R, W, C, interpret)
+        1, NB, BJ, K, R, W, C, interpret, KERNEL_SERIAL)
 
     placed = placed.reshape(-1)[:J].astype(bool)
     nodes = chosen.reshape(NB, K, BJ).transpose(0, 2, 1).reshape(-1, K)[:J]
@@ -413,7 +421,7 @@ def _solve_streamed_impl(state: ClusterState, req, node_num, time_limit,
 
     placed, chosen, reason, avail_f, cost_f = _launch(
         job_p, nelig, avail3, cost2, elig3, cputot3,
-        S, NB, BJ, K, R, W, C, interpret)
+        S, NB, BJ, K, R, W, C, interpret, KERNEL_STREAMED)
 
     # [NB, S, ..] -> [S, NB, ..] -> flat [S * L, ..], then gather each
     # original job's slot

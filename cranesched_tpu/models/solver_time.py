@@ -68,6 +68,8 @@ from cranesched_tpu.obs.introspect import instrument_jit
 
 # start_bucket value for jobs that could not be scheduled in the window
 NO_START = 2**30  # plain int: keep module import backend-free
+#: the scan of solve_backfill in a device trace's op metadata
+HEAD_SCOPE = "crane_backfill_head"
 
 
 class TimeGrid:
@@ -357,8 +359,11 @@ def solve_backfill(state: TimedClusterState, jobs: TimedJobBatch,
         return (ta, cost), (jnp.stack(oks), jnp.stack(ss),
                             jnp.stack(chosens), jnp.stack(reasons))
 
-    (ta, cost), (placed, start, nodes, reason) = jax.lax.scan(
-        step, (state.time_avail, state.cost), xs)
+    # XLA names the loop `while.N` whatever it is told; the scope lands
+    # in the op's metadata, where a trace viewer shows it
+    with jax.named_scope(HEAD_SCOPE):
+        (ta, cost), (placed, start, nodes, reason) = jax.lax.scan(
+            step, (state.time_avail, state.cost), xs)
 
     placed = placed.reshape(-1)[:J]
     start = start.reshape(-1)[:J]

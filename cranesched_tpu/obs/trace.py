@@ -31,6 +31,15 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
                              groups when group commit is active)
     wal_groups       int     WAL groups flushed this cycle (<= 3)
     candidates       int     jobs considered this cycle
+    gang_bound       int     the static gang bound K the cycle's solves
+                             ran with: the bucket of its widest
+                             candidate, capped at MaxNodesPerJob.  Every
+                             candidate pays K selection passes
+    gang_fill_pct    float   100 * sum of the candidates' node_num /
+                             (candidates * gang_bound): the share of
+                             those passes a job needed
+    decisions_per_s  float   candidates / solve_ms: BASELINE's
+                             yardstick as the served path pays it
     placed           int     jobs started (incl. backfill tail)
     preempted        int     victims killed by this cycle
     backfilled       int     placed with start_bucket > 0 (future start)

@@ -10,6 +10,8 @@ kernel's VMEM shape); the job axis is kept short because it only scales
 the surrounding XLA sort/scatter, not the kernel.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,14 +88,25 @@ def v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _assert_kernel_named(compiled, name):
+    """A device trace names an op by its HLO instruction: the kernel's
+    is the name the program gave it plus XLA's numbering, which is what
+    benchmark/readers/device_op.py keys on."""
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call",
+                     compiled.as_text())
+
+
 @pytest.mark.parametrize("max_nodes", [1, 8])
 def test_serial_kernel_compiles_for_v5e(v5e, max_nodes):
-    _lower_serial(max_nodes, sharding=v5e).compile()
+    _assert_kernel_named(_lower_serial(max_nodes, sharding=v5e).compile(),
+                         ps.KERNEL_SERIAL)
 
 
 @pytest.mark.parametrize("max_nodes", [1, 8])
 def test_streamed_kernel_compiles_for_v5e(v5e, max_nodes):
-    _lower_streamed(max_nodes, 4, sharding=v5e).compile()
+    _assert_kernel_named(
+        _lower_streamed(max_nodes, 4, sharding=v5e).compile(),
+        ps.KERNEL_STREAMED)
 
 
 def test_v5e_compile_enforces_the_vmem_limit(v5e):
