@@ -990,8 +990,11 @@ def cmd_cstats(args) -> int:
                  t.get("mesh", "-"),
                  t.get("queue_depth"),
                  t.get("candidates"),
-                 # K: the static gang bound the cycle's solves paid
-                 t.get("gang_bound", "-"), t.get("placed"),
+                 # K: the static gang bound of the cycle's solves;
+                 # PASS%: the share of its slots x K selection passes
+                 # the Pallas kernel ran
+                 t.get("gang_bound", "-"), t.get("tail_pass_pct", "-"),
+                 t.get("placed"),
                  t.get("backfilled"), t.get("preempted"),
                  # SKIP: coalesced short-circuit count (+ reason);
                  # DIRTY: jobs/nodes patched since the last cycle
@@ -1011,7 +1014,7 @@ def cmd_cstats(args) -> int:
                  t.get("wal_fsyncs"), t.get("topo_frag", "-"))
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
-            "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "K", "PLACED",
+            "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "K", "PASS%", "PLACED",
             "BACKFILL", "PREEMPT", "SKIP", "DIRTY", "PRELUDE_MS",
             "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS", "LOCK_MS",
             "TOTAL_MS", "LOCK_WAIT_MS", "PERIOD_MS", "FSYNC", "FRAG")))

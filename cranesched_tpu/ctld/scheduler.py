@@ -2649,7 +2649,9 @@ class JobScheduler:
                             np.int32(-1)).astype(np.int32)
             placements = Placements(placed=np.asarray(placements.placed),
                                     nodes=real,
-                                    reason=np.asarray(placements.reason))
+                                    reason=np.asarray(placements.reason),
+                                    passes=getattr(placements,
+                                                   "passes", None))
         return placements, solver_name
 
     # ---- topology-aware placement (topo/) ----
@@ -2822,6 +2824,13 @@ class JobScheduler:
             if hasattr(sync, "block_until_ready"):
                 sync.block_until_ready()
             clock.mark("solve_host")
+            # the Pallas kernels' pass counter came with the placements
+            # just waited for: eight bytes, here where the lock is free
+            passes = getattr(first, "passes", None)
+            if passes is not None:
+                ran, bound = np.asarray(passes).tolist()
+                trace["_tail_passes"] = trace.get("_tail_passes", 0) + ran
+                trace["_tail_bound"] = trace.get("_tail_bound", 0) + bound
             dt = _time.perf_counter() - t0
             if (backend is None and isinstance(out, tuple)
                     and len(out) == 2 and isinstance(out[1], str)):
@@ -2850,6 +2859,8 @@ class JobScheduler:
         prelude_ms = (drain_ms if prelude_end is None
                       else (prelude_end - t0) * 1e3)
         solve_ms = float(self._cur_trace.get("solve_ms", 0.0))
+        tail_passes = self._cur_trace.pop("_tail_passes", 0)
+        tail_bound = self._cur_trace.pop("_tail_bound", 0)
         # commit = everything after the prelude that ran under the
         # lock, i.e. total minus prelude minus the lock-released solves.
         # Dispatch is NOT in here: the ring drains post-lock and its
@@ -2890,6 +2901,12 @@ class JobScheduler:
             # BASELINE's yardstick as the served path pays it
             decisions_per_s=round(len(candidates) * 1e3 / solve_ms, 1)
             if solve_ms > 0 else 0.0,
+            # the share of its slots x K selection passes the cycle's
+            # Pallas kernel ran (100.0: no such kernel ran, so none was
+            # left out; never 0, which a reader of a share that is better
+            # lower would take for the best value)
+            tail_pass_pct=round(100.0 * tail_passes / tail_bound, 3)
+            if tail_bound else 100.0,
             placed=len(started),
             dirty_jobs=self._ptable.last_dirty,
             dirty_nodes=self.meta.last_snapshot_dirty,
@@ -4006,8 +4023,10 @@ class JobScheduler:
                                self.config.max_nodes_per_job))
         # bucket the static gang bound too (it is a jit static arg)
         max_nodes = self._bucket(max_nodes, floor=1)
-        # every job of the cycle pays max_nodes selection passes whatever
-        # its own width: the bound, and the share of the passes needed
+        # max_nodes is the static bound of the cycle's solves, and what
+        # the head's scan pays for every job whatever its width; the
+        # share of those passes a job needed (the Pallas tail runs only
+        # the passes a slot can use: tail_pass_pct)
         if ordered:
             self._cur_trace.update(
                 gang_bound=max_nodes,

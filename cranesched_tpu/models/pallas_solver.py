@@ -13,13 +13,23 @@ TPU-native fix is to run the WHOLE job loop inside a single kernel:
   traffic;
 * per-job scalars (req, node_num, time_limit, class id, valid) stream
   through SMEM in blocks of ``BJ`` jobs per grid step;
-* each job is ~30 full-width VPU ops (feasibility compare per resource
-  dim, masked min for the cheapest-k selection, masked subtract/add for
-  the resource/cost update) — no dynamic-index gathers or scatters at
-  all: selection and update are both expressed as elementwise ops
-  against a node-index iota, which is exactly what the VPU wants.  The
-  node axis is folded to (8 sublanes, N/8 lanes) so every op fills the
-  full 8x128 VPU instead of one sublane.
+* a slot of the job loop (one job of each of the S streams) costs what
+  its jobs can use, inside the static gang bound K that stays a
+  compile-time argument: one feasibility compare per resource dim, then
+  selection passes (a masked min over the node axis, a second for the
+  lowest tied node id, a mask of the winner) that end at the widest
+  ``node_num`` among the slot's valid streams and at the first infinite
+  minimum: the minima come out ascending, so a job whose first one is
+  infinite (no feasible node: nearly every job of a standing backlog) is
+  decided after ONE pass, as is a padded or invalidated slot.
+  Only a job that is placed pays its row writes and the masked
+  subtract/add of the resource/cost update.  The kernel counts the
+  passes it ran (``Placements.passes``; the cycle trace's
+  ``tail_pass_pct``).  No dynamic-index gathers or scatters at all:
+  selection and update are both expressed as elementwise ops against a
+  node-index iota, which is exactly what the VPU wants.  The node axis
+  is folded to (8 sublanes, N/8 lanes) so every op fills the full 8x128
+  VPU instead of one sublane.
 
 Semantics are bit-identical to ``solver.solve_greedy`` on the same
 backend (same fixed-point cost ledger, same (cost, lowest-index) tie
@@ -109,6 +119,7 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
     def kernel(job_s, nelig_s,                           # SMEM scalars
                avail_in, cost_in, elig_in, cputot_in,    # VMEM cluster in
                placed_o, chosen_o, reason_o, avail_o, cost_o,  # outputs
+               passes_o,                                 # SMEM counter out
                avail_s, cost_s, placed_s, chosen_s, reason_s):  # scratch
         nb = pl.num_programs(0)
         step = pl.program_id(0)
@@ -131,41 +142,72 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
         reason_s[...] = jnp.zeros((S, BJ), jnp.int32)
         chosen_s[...] = jnp.full((S, K, BJ), -1, jnp.int32)
 
-        def job_body(j, carry):
-            # --- selection phase: all S streams first, so the S
-            # latency-heavy reduce chains are mutually independent ---
-            sels = []
-            for c in range(S):
-                nn = job_s[c, R, j]
-                valid = job_s[c, R + 2, j] != 0
-                cls = job_s[c, R + 3, j]
+        def job_body(j, passes):
+            nns = [job_s[c, R, j] for c in range(S)]
+            valids = [job_s[c, R + 2, j] != 0 for c in range(S)]
+            clss = [job_s[c, R + 3, j] for c in range(S)]
+            # the selection passes a stream's job can use: its own
+            # width; none beyond pass 0 where it is refused whatever the
+            # minima read (padding, an invalidated row, a gang wider
+            # than K)
+            wants = [jnp.where(valids[c] & (nns[c] <= K), nns[c], 0)
+                     for c in range(S)]
 
-                feas = elig_in[cls] != 0                 # [SUB, W]
+            def select(k, mcosts):
+                """Pass k for all S streams side by side (the
+                latency-heavy reduce chains of one pass are mutually
+                independent), then the passes after it, which run only
+                while some stream that still wants a node found one:
+                the minima come out ascending, so after an infinite one
+                every later one is infinite.  A minimum a stream gets
+                only because a neighbour needed the pass changes
+                nothing: admission counts finite minima up to nn.
+                Returns (ms[k:], idxs[k:], passes run), [pass][stream]."""
+                ms = [jnp.min(mcost) for mcost in mcosts]
+                idxs = [jnp.min(jnp.where(mcost == m, nid, npad))
+                        for mcost, m in zip(mcosts, ms)]
+                if k + 1 == K:
+                    return [ms], [idxs], jnp.int32(1)
+                more = functools.reduce(jnp.logical_or, [
+                    (wants[c] > k + 1) & (ms[c] < inf) for c in range(S)])
+
+                def rest():
+                    # mask the winners for the next gang member
+                    return select(k + 1, [
+                        jnp.where(nid == idx, inf, mcost)
+                        for mcost, idx in zip(mcosts, idxs)])
+
+                def skip():
+                    # what the passes that do not run read: no finite
+                    # minimum (an id is only taken beside one)
+                    left = range(k + 1, K)
+                    return ([[inf] * S for _ in left],
+                            [[jnp.int32(0)] * S for _ in left],
+                            jnp.int32(0))
+
+                r_ms, r_idxs, ran = jax.lax.cond(more, rest, skip)
+                return [ms] + r_ms, [idxs] + r_idxs, ran + 1
+
+            # --- selection phase: reads pre-update state for all S
+            # streams, which is exact because no other stream can touch
+            # the nodes a stream sees.  Pass 0 runs for every slot: a
+            # branch round it for the few slots with no valid job
+            # (padding, the head's rows) cost a sixth of the kernel on
+            # the chip (PERF.md, PR 32), and K = 1 stays the
+            # straight-line code it was. ---
+            mcosts = []
+            for c in range(S):
+                feas = elig_in[clss[c]] != 0             # [SUB, W]
                 for r in range(R):
                     feas = feas & (avail_s[r] >= job_s[c, r, j])
-
-                # K masked mins (reduction-only)
-                mcost = jnp.where(feas, cost_s[0], inf)  # [SUB, W]
-                ms, idxs = [], []
-                for k in range(K):
-                    m = jnp.min(mcost)
-                    idx = jnp.min(jnp.where(mcost == m, nid, npad))
-                    ms.append(m)
-                    idxs.append(idx)
-                    # mask the winner for the next gang member
-                    # (cheapest_k masks unconditionally; on an all-INF
-                    # row the mask is a no-op, same as cheapest_k)
-                    if k + 1 < K:
-                        mcost = jnp.where(nid == idx, inf, mcost)
-                sels.append((nn, valid, cls, ms, idxs))
+                mcosts.append(jnp.where(feas, cost_s[0], inf))
+            ms, idxs, ran = select(0, mcosts)
 
             # --- decide + update phase.  Updates touch only the
             # stream's own (disjoint) nodes, so stream order here is
-            # immaterial; selections above read pre-update state,
-            # which is exact because no other stream can touch the
-            # nodes this stream sees. ---
+            # immaterial. ---
             for c in range(S):
-                nn, valid, cls, ms, idxs = sels[c]
+                nn, valid, cls = nns[c], valids[c], clss[c]
                 tl = job_s[c, R + 1, j]
 
                 # admission (decide_job): the masked minima are sorted
@@ -176,7 +218,7 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
                 cnt_finite = jnp.int32(0)
                 for k in range(K):
                     cnt_finite = (cnt_finite
-                                  + (ms[k] < inf).astype(jnp.int32))
+                                  + (ms[k][c] < inf).astype(jnp.int32))
                 ok = valid & (nn > 0) & (nn <= K) & (cnt_finite >= nn)
                 bad = jnp.logical_not(valid) | (nn <= 0)
                 never = bad | (nelig_s[cls, 0] < nn)
@@ -184,27 +226,25 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
                                    jnp.where(never, REASON_CONSTRAINT,
                                              REASON_RESOURCE))
 
-                # per-job result rows (always written)
-                for k in range(K):
-                    take = ok & (k < nn) & (ms[k] < inf)
-                    chosen_s[c, k:k + 1, :] = jnp.where(
-                        (jlane == j) & take, idxs[k],
-                        chosen_s[c, k:k + 1, :])
                 placed_s[c:c + 1, :] = jnp.where(
                     jlane == j, ok.astype(jnp.int32),
                     placed_s[c:c + 1, :])
                 reason_s[c:c + 1, :] = jnp.where(
                     jlane == j, reason, reason_s[c:c + 1, :])
 
-                # one combined state update for all gang members —
-                # gated on ok: the ~40% of jobs that fail at scale
-                # skip the whole masked-subtract/cost pass
+                # the chosen rows (they start a block at -1) and one
+                # combined state update for all gang members, both only
+                # for a job that is placed: the jobs that fail skip the
+                # row writes and the whole masked-subtract/cost pass
                 @pl.when(ok)
-                def _(c=c, nn=nn, tl=tl, ms=ms, idxs=idxs):
+                def _(c=c, nn=nn, tl=tl):
                     win = jnp.zeros((SUB, W), bool)
                     for k in range(K):
-                        take = (k < nn) & (ms[k] < inf)
-                        win = win | ((nid == idxs[k]) & take)
+                        take = (k < nn) & (ms[k][c] < inf)
+                        chosen_s[c, k:k + 1, :] = jnp.where(
+                            (jlane == j) & take, idxs[k][c],
+                            chosen_s[c, k:k + 1, :])
+                        win = win | ((nid == idxs[k][c]) & take)
                     # MinCpuTimeRatioFirst increment, elementwise over
                     # nodes with this job's scalars — identical f32
                     # expression (and associativity) to
@@ -218,12 +258,13 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
                         avail_s[r] = avail_s[r] - jnp.where(
                             win, job_s[c, r, j], 0)
                     cost_s[0] = cost_s[0] + jnp.where(win, dcost, 0)
-            return carry
+            return passes + ran
 
         # no partial unroll: the Mosaic lowering of the installed JAX
         # accepts only unroll=1 or a full unroll of the BJ steps
         # (tests/test_pallas_lowering.py lowers every entry point)
-        jax.lax.fori_loop(0, BJ, job_body, jnp.int32(0))
+        passes_o[0, step] = jax.lax.fori_loop(0, BJ, job_body,
+                                              jnp.int32(0))
 
         # per-job outputs live whole in VMEM (tiny); write this block's
         # row at a dynamic offset — blocked specs would need a
@@ -282,7 +323,7 @@ def _launch(job_p, nelig, avail3, cost2, elig3, cputot3,
     """pallas_call plumbing shared by both entry points.  job_p is
     [S, R+4, NB*BJ] (scalar axis innermost so the SMEM BlockSpec
     (S, R+4, BJ) slices the job axis per grid step); returns raw
-    blocked outputs + final ledgers."""
+    blocked outputs, final ledgers and ``Placements.passes``."""
     def vmem_full():
         return pl.BlockSpec(memory_space=pltpu.VMEM)
 
@@ -292,8 +333,9 @@ def _launch(job_p, nelig, avail3, cost2, elig3, cputot3,
         jax.ShapeDtypeStruct((NB, S, BJ), jnp.int32),     # reason
         jax.ShapeDtypeStruct((R, SUB, W), jnp.int32),     # avail out
         jax.ShapeDtypeStruct((1, SUB, W), jnp.int32),     # cost out
+        jax.ShapeDtypeStruct((1, NB), jnp.int32),         # passes run
     )
-    return pl.pallas_call(
+    *outs, passes = pl.pallas_call(
         _make_kernel(BJ, K, R, W, S),
         grid=(NB,),
         in_specs=[pl.BlockSpec((S, R + 4, BJ), lambda i: (0, 0, i),
@@ -302,8 +344,8 @@ def _launch(job_p, nelig, avail3, cost2, elig3, cputot3,
                                memory_space=pltpu.SMEM),
                   vmem_full(), vmem_full(), vmem_full(), vmem_full()],
         out_shape=out_shapes,
-        out_specs=tuple(pl.BlockSpec(memory_space=pltpu.VMEM)
-                        for _ in out_shapes),
+        out_specs=(*(vmem_full() for _ in out_shapes[:-1]),
+                   pl.BlockSpec(memory_space=pltpu.SMEM)),
         scratch_shapes=[
             pltpu.VMEM((R, SUB, W), jnp.int32),
             pltpu.VMEM((1, SUB, W), jnp.int32),
@@ -314,6 +356,7 @@ def _launch(job_p, nelig, avail3, cost2, elig3, cputot3,
         interpret=interpret,
         name=name,
     )(job_p, nelig, avail3, cost2, elig3, cputot3)
+    return (*outs, jnp.stack([jnp.sum(passes), jnp.int32(NB * BJ * K)]))
 
 
 def _solve_serial_impl(state: ClusterState, req, node_num, time_limit,
@@ -336,7 +379,7 @@ def _solve_serial_impl(state: ClusterState, req, node_num, time_limit,
     job_p = _pad_to(_job_scalars(req, node_num, time_limit, valid,
                                  job_class, C), j_pad, 0, 0).T[None]
 
-    placed, chosen, reason, avail_f, cost_f = _launch(
+    placed, chosen, reason, avail_f, cost_f, passes = _launch(
         job_p, nelig, avail3, cost2, elig3, cputot3,
         1, NB, BJ, K, R, W, C, interpret, KERNEL_SERIAL)
 
@@ -346,7 +389,8 @@ def _solve_serial_impl(state: ClusterState, req, node_num, time_limit,
     avail_new = avail_f.reshape(R, n_pad)[:, :N].T
     cost_new = cost_f.reshape(n_pad)[:N]
     new_state = state.replace(avail=avail_new, cost=cost_new)
-    return Placements(placed=placed, nodes=nodes, reason=reason), new_state
+    return Placements(placed=placed, nodes=nodes, reason=reason,
+                      passes=passes), new_state
 
 
 # jit twins: the donating variant hands the ClusterState's device
@@ -419,7 +463,7 @@ def _solve_streamed_impl(state: ClusterState, req, node_num, time_limit,
         scal[order], mode="drop")
     job_p = job_p.reshape(S, L, R + 4).transpose(0, 2, 1)
 
-    placed, chosen, reason, avail_f, cost_f = _launch(
+    placed, chosen, reason, avail_f, cost_f, passes = _launch(
         job_p, nelig, avail3, cost2, elig3, cputot3,
         S, NB, BJ, K, R, W, C, interpret, KERNEL_STREAMED)
 
@@ -436,8 +480,8 @@ def _solve_streamed_impl(state: ClusterState, req, node_num, time_limit,
     avail_new = avail_f.reshape(R, n_pad)[:, :N].T
     cost_new = cost_f.reshape(n_pad)[:N]
     new_state = state.replace(avail=avail_new, cost=cost_new)
-    return (Placements(placed=placed_j, nodes=nodes_j, reason=reason_j),
-            new_state)
+    return (Placements(placed=placed_j, nodes=nodes_j, reason=reason_j,
+                       passes=passes), new_state)
 
 
 _STREAM_STATICS = ("max_nodes", "block_jobs", "num_streams",

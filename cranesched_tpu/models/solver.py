@@ -237,11 +237,15 @@ class Placements:
     placed: bool[J]
     nodes:  int32[J, K] chosen node indices, -1 padded (K = max gang size)
     reason: int32[J]    REASON_* for unplaced jobs
+    passes: int32[2]    the Pallas kernels only: the selection passes
+                        the solve ran, and the slots x K that the static
+                        bound allows (models/pallas_solver.py)
     """
 
     placed: jax.Array
     nodes: jax.Array
     reason: jax.Array
+    passes: jax.Array | None = None
 
 
 def make_cluster_state(avail, total, alive, cost=None) -> ClusterState:

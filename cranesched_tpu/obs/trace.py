@@ -33,11 +33,18 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
     candidates       int     jobs considered this cycle
     gang_bound       int     the static gang bound K the cycle's solves
                              ran with: the bucket of its widest
-                             candidate, capped at MaxNodesPerJob.  Every
-                             candidate pays K selection passes
+                             candidate, capped at MaxNodesPerJob.  The
+                             head's scan pays K selection passes a job
     gang_fill_pct    float   100 * sum of the candidates' node_num /
                              (candidates * gang_bound): the share of
                              those passes a job needed
+    tail_pass_pct    float   100 * selection passes the cycle's Pallas
+                             kernel ran / (its slots * gang_bound):
+                             after pass 0 a slot stops at its widest
+                             node_num and at the first infinite minimum
+                             (models/pallas_solver.py); 100.0 when
+                             no Pallas kernel ran in the cycle (no
+                             pass was left out)
     decisions_per_s  float   candidates / solve_ms: BASELINE's
                              yardstick as the served path pays it
     placed           int     jobs started (incl. backfill tail)

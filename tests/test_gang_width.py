@@ -211,15 +211,15 @@ def test_the_head_scan_carries_its_scope():
 # one cycle of the default block, held to the benchmark's own replay
 # ---------------------------------------------------------------------------
 
-def _cycle(widths, nodes=48):
-    """`nodes` nodes in two partitions, one job of 2 cpu / 4 GiB a node
-    for each width, dealt over the partitions; one cycle.  Returns the
-    harness's view (cluster, acks, rows) and the cycle's trace row."""
+def _cycle(widths, nodes=48, parts=2, config=None, interpret=False):
+    """`nodes` nodes in `parts` partitions, one job of 2 cpu / 4 GiB a
+    node for each width, dealt over the partitions; one cycle.  Returns
+    the harness's view (cluster, acks, rows) and the cycle's trace row."""
     meta = MetaContainer()
     cluster = {"names": [], "cpu": [], "mem_gib": [], "part": [],
                "drained": []}
     for i in range(nodes):
-        part = "batch0" if i < nodes // 2 else "batch1"
+        part = f"batch{i * parts // nodes}"
         meta.add_node(f"cn{i:05d}", meta.layout.encode(
             cpu=16, mem_bytes=32 << 30, memsw_bytes=32 << 30,
             is_capacity=True), partitions=(part,))
@@ -228,13 +228,14 @@ def _cycle(widths, nodes=48):
         cluster["cpu"].append(16)
         cluster["mem_gib"].append(32)
         cluster["part"].append(part)
-    sched = JobScheduler(meta, SchedulerConfig())
+    sched = JobScheduler(meta, config or SchedulerConfig())
+    sched.pallas_interpret = interpret      # no TPU under pytest
     sim = SimCluster(sched)
     sched.dispatch = sim.dispatch
     sched.dispatch_terminate = sim.terminate
     acks = {}
     for k, width in enumerate(widths):
-        part = f"batch{k % 2}"
+        part = f"batch{k % parts}"
         job_id = sched.submit(JobSpec(
             partition=part, node_num=width, time_limit=3600,
             res=ResourceSpec(cpu=2.0, mem_bytes=4 << 30,
@@ -271,6 +272,27 @@ def test_a_cycle_of_widths_1_to_8_passes_the_replay_and_says_its_bound():
     # cycle's few decisions a second need the absolute slack
     assert trace["decisions_per_s"] == pytest.approx(
         24 * 1e3 / trace["solve_ms"], rel=1e-3, abs=0.06)
+
+
+def test_the_tail_says_what_share_of_its_passes_it_ran():
+    """The default block's split with the Pallas tail (interpreted): the
+    head takes the first four jobs; the tail's serial kernel runs pass 0
+    for each of its one block's 256 slots (the head's invalidated rows
+    and the padding too) and one more pass for every further node a job
+    asks for (every job fits)."""
+    widths = [1, 2, 3, 4, 5, 6, 7, 8] * 3
+    _, _, rows, started, trace = _cycle(
+        widths, parts=1, interpret=True,
+        config=SchedulerConfig(solver="pallas", backfill_max_jobs=4))
+    assert len(started) == 24 and trace["solver"] == "backfill-split"
+    assert trace["gang_bound"] == 8 and trace["num_streams"] == 1
+    # by hand: 108 nodes asked for, 1 + 2 + 3 + 4 of them by the head;
+    # the tail's 20 jobs take 98, the first of each in pass 0
+    assert trace["tail_pass_pct"] == pytest.approx(
+        100.0 * (256 + 98 - 20) / (256 * 8), abs=1e-3)
+    # a cycle that ran no Pallas kernel left no pass out: 100, and not a
+    # 0 for a reader of a "lower is better" share to take for the best
+    assert _cycle(widths)[4]["tail_pass_pct"] == 100.0
 
 
 def test_the_bound_is_the_bucket_of_the_widest_candidate():

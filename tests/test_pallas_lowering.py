@@ -25,6 +25,10 @@ NUM_NODES = 10_000
 NUM_DIMS = 3
 NUM_CLASSES = 4
 BLOCK_JOBS = 256
+# the static gang bounds a cycle can ask for (the buckets of K up to
+# MaxNodesPerJob 8): K = 1 is straight-line code, every larger one nests
+# a branch a selection pass (ISSUE 32)
+GANG_BOUNDS = [1, 2, 4, 8]
 
 
 def _abstract_args(num_nodes, sharding=None):
@@ -64,13 +68,13 @@ def _lower_streamed(max_nodes, num_streams, sharding=None):
             ).lower(lowering_platforms=("tpu",))
 
 
-@pytest.mark.parametrize("max_nodes", [1, 8])
+@pytest.mark.parametrize("max_nodes", GANG_BOUNDS)
 def test_serial_kernel_lowers_for_tpu(max_nodes):
     assert "tpu_custom_call" in _lower_serial(max_nodes).as_text()
 
 
 @pytest.mark.parametrize("num_streams", [1, 4])
-@pytest.mark.parametrize("max_nodes", [1, 8])
+@pytest.mark.parametrize("max_nodes", GANG_BOUNDS)
 def test_streamed_kernel_lowers_for_tpu(max_nodes, num_streams):
     text = _lower_streamed(max_nodes, num_streams).as_text()
     assert "tpu_custom_call" in text
@@ -96,13 +100,13 @@ def _assert_kernel_named(compiled, name):
                      compiled.as_text())
 
 
-@pytest.mark.parametrize("max_nodes", [1, 8])
+@pytest.mark.parametrize("max_nodes", GANG_BOUNDS)
 def test_serial_kernel_compiles_for_v5e(v5e, max_nodes):
     _assert_kernel_named(_lower_serial(max_nodes, sharding=v5e).compile(),
                          ps.KERNEL_SERIAL)
 
 
-@pytest.mark.parametrize("max_nodes", [1, 8])
+@pytest.mark.parametrize("max_nodes", GANG_BOUNDS)
 def test_streamed_kernel_compiles_for_v5e(v5e, max_nodes):
     _assert_kernel_named(
         _lower_streamed(max_nodes, 4, sharding=v5e).compile(),

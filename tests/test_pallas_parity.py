@@ -16,17 +16,23 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from cranesched_tpu.models.pallas_solver import (
+    _solve_streamed,
     classes_from_part_mask,
     plan_streams,
+    solve_greedy_pallas,
     solve_greedy_pallas_auto,
     solve_greedy_pallas_from_batch,
 )
 from cranesched_tpu.models.solver import (
+    REASON_CONSTRAINT,
+    REASON_NONE,
+    REASON_RESOURCE,
     JobBatch,
     make_cluster_state,
     solve_greedy,
 )
 from cranesched_tpu.ops.resources import ResourceLayout
+from cranesched_tpu.testing.oracle import solve_greedy_oracle
 
 
 def _random_problem(rng, num_jobs, num_nodes, num_classes=3,
@@ -202,3 +208,129 @@ def test_classes_from_part_mask_roundtrip():
     pm = rng.random((20, 9)) > 0.4
     job_class, masks = classes_from_part_mask(pm)
     np.testing.assert_array_equal(masks[job_class], pm)
+
+
+# ---------------------------------------------------------------------------
+# the selection passes a slot runs (ISSUE 32): pass 0 always, then a slot
+# stops at the widest node_num among its valid streams and at the first
+# infinite minimum.  Every edge against the numpy oracle and solve_greedy bit
+# for bit, and the kernel's pass counter against a count made by hand.
+# ---------------------------------------------------------------------------
+
+EDGE_PARTS = 4          # one stream a partition at S = 4
+EDGE_PER = 10           # nodes a partition: node i of it has i + 1 cpus free
+EDGE_BLOCK = 8          # jobs a block; a stream is two blocks long
+EDGE_LEN = 16
+
+
+def _edge_case(name, K):
+    """(jobs, passes run AFTER pass 0, (placed, reason) a job).  A job is
+    (partition, cpus, node_num, valid); a job of c cpus has
+    EDGE_PER - c + 1 feasible nodes in an untouched partition, and
+    partition 3 has ONE node alive.  The streamed kernel puts the n-th
+    job of every partition into slot n, the serial kernel one job a
+    slot; in every case here the jobs that share a slot run as many
+    further passes together as they would alone, so the count is one
+    number.  Every slot, padding too, runs pass 0: the test adds one a
+    slot."""
+    yes, no = (True, REASON_NONE), (False, REASON_RESOURCE)
+    never = (False, REASON_CONSTRAINT)
+    if name == "one_to_nn_minus_1_feasible":
+        # nn = K, f = 1 .. K-1 feasible nodes: passes 0 .. f run, pass f
+        # reads the first infinite minimum.  One partition, so a slot
+        # holds one job in either kernel
+        jobs = [(0, EDGE_PER - f + 1, K, True) for f in range(1, K)]
+        count = sum(f for f in range(1, K))
+        return jobs, count, [no] * (K - 1)
+    if name == "nn_equals_K_all_feasible":
+        return [(1, 1, K, True)], K - 1, [yes]
+    if name == "no_job_of_the_block_feasible":
+        # 8 jobs, two a partition, widths mixed, 16 cpus each: pass 0
+        # says so; the last asks partition 3 for K of its one live node
+        jobs = [(j % 3, 16, 1 + j % K, True) for j in range(6)]
+        jobs += [(3, 16, 1, True), (3, 16, K, True)]
+        return jobs, 0, [no] * 7 + [never]
+    if name == "wide_feasible_beside_narrow_infeasible":
+        return [(0, 1, K, True), (1, 16, 1, True)], K - 1, [yes, no]
+    if name == "narrow_feasible_beside_wide_infeasible":
+        return [(0, 1, 1, True), (1, 16, K, True)], 0, [yes, no]
+    if name == "invalid_slot_and_padded_stream":
+        # slot 0: three invalidated rows (two of them K wide, one of
+        # those feasible had it been valid) and a stream with no job;
+        # then a gang of 2 that fits, in slot 1 of its stream
+        jobs = [(0, 1, K, False), (1, 1, 1, False), (2, 16, K, False),
+                (0, 1, 2, True)]
+        return jobs, 1, [never] * 3 + [yes]
+    if name == "fewer_eligible_nodes_than_nn":
+        # partition 3 has one node alive: pass 0 finds it, pass 1 nothing
+        return [(3, 1, 2, True)], 1, [never]
+    if name == "wider_than_the_bound":
+        # refused whatever the minima read: pass 0 alone, though every
+        # node fits; the gang of 2 behind it in the stream pays its own
+        return [(0, 1, K + 1, True), (0, 1, 2, True)], 1, [no, yes]
+    raise KeyError(name)
+
+
+EDGE_CASES = [
+    "one_to_nn_minus_1_feasible", "nn_equals_K_all_feasible",
+    "no_job_of_the_block_feasible", "wide_feasible_beside_narrow_infeasible",
+    "narrow_feasible_beside_wide_infeasible",
+    "invalid_slot_and_padded_stream", "fewer_eligible_nodes_than_nn",
+    "wider_than_the_bound"]
+
+
+@pytest.mark.parametrize("K", [2, 4, 8])
+@pytest.mark.parametrize("kernel", ["serial", "streamed"])
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_selection_pass_edges(case, kernel, K):
+    jobs, want_passes, want_jobs = _edge_case(case, K)
+    lay = ResourceLayout()
+    N = EDGE_PARTS * EDGE_PER
+    node_part = np.arange(N) // EDGE_PER
+    total = np.tile(lay.encode(cpu=16, mem_bytes=64 << 30,
+                               is_capacity=True), (N, 1))
+    avail = np.stack([lay.encode(cpu=int(i % EDGE_PER) + 1,
+                                 mem_bytes=64 << 30, is_capacity=True)
+                      for i in range(N)])
+    alive = np.ones(N, bool)
+    alive[3 * EDGE_PER + 1:] = False
+    cost = (np.arange(N) % 3).astype(np.float32)   # ties inside a partition
+    part, cpus, node_num, valid = (np.asarray(x) for x in zip(*jobs))
+    req = np.stack([lay.encode(cpu=float(c), mem_bytes=1 << 30)
+                    for c in cpus])
+    time_limit = np.full(len(jobs), 3600, np.int32)
+    part_mask = part[:, None] == node_part[None, :]
+    class_masks = np.arange(EDGE_PARTS)[:, None] == node_part[None, :]
+
+    state = make_cluster_state(avail.copy(), total, alive, cost)
+    args = (state, jnp.asarray(req), jnp.asarray(node_num, jnp.int32),
+            jnp.asarray(time_limit), jnp.asarray(valid),
+            jnp.asarray(part, jnp.int32), jnp.asarray(class_masks))
+    if kernel == "serial":
+        got, new_state = solve_greedy_pallas(
+            *args, max_nodes=K, block_jobs=EDGE_BLOCK, interpret=True)
+        slots = EDGE_BLOCK
+    else:
+        got, new_state = _solve_streamed(
+            *args, jnp.arange(EDGE_PARTS, dtype=jnp.int32), max_nodes=K,
+            block_jobs=EDGE_BLOCK, num_streams=EDGE_PARTS,
+            stream_len=EDGE_LEN, interpret=True)
+        slots = EDGE_LEN
+
+    o_placed, o_nodes, o_reason, o_avail, o_cost = solve_greedy_oracle(
+        avail.copy(), total, alive, cost, req, node_num, time_limit,
+        part_mask, valid, K)
+    ref, ref_state = solve_greedy(state, JobBatch(
+        req=args[1], node_num=args[2], time_limit=args[3],
+        part_mask=jnp.asarray(part_mask), valid=args[4]), max_nodes=K)
+    for want in ((o_placed, o_nodes, o_reason, o_avail, o_cost),
+                 (ref.placed, ref.nodes, ref.reason, ref_state.avail,
+                  ref_state.cost)):
+        for a, b in zip((got.placed, got.nodes, got.reason,
+                         new_state.avail, new_state.cost), want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert list(zip(o_placed.tolist(), o_reason.tolist())) == want_jobs
+    if not o_placed.any():      # nothing placed: the ledgers are untouched
+        np.testing.assert_array_equal(np.asarray(new_state.avail), avail)
+    assert np.asarray(got.passes).tolist() == [slots + want_passes,
+                                                slots * K]
