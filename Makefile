@@ -1,129 +1,38 @@
 # Test entry points (README.md "Tests").
 #
-# tier1      — ROADMAP.md's tier-1 verify, verbatim (tools/tier1.sh):
-#              the whole suite on the CPU backend with an 870 s cap;
-#              prints DOTS_PASSED=<n> at the end.
-# tier1-obs  — fast lane: only the observability tests (@pytest.mark.obs
-#              in tests/test_obs.py) — seconds, not minutes.  Use while
-#              iterating on obs/, the cycle trace, or the watchdog.
-# tier1-perf — perf smoke lane (tools/tier1_perf.sh): bench.py at a
-#              tiny CPU shape, asserting the scheduler cycle's prelude
-#              share stays <= 25% and the LOCK-HELD share (prelude +
-#              commit) <= 35% of wall time, and that group commit keeps
-#              fsyncs-per-cycle == WAL groups (<= 3).
-# tier1-ha   — HA failover lane (@pytest.mark.ha in
-#              tests/test_ha_failover.py): leader+standby e2e — kill
-#              the leader, assert promotion, fencing, and no lost or
-#              double-dispatched jobs.
-# tier1-commit — commit-path lane: WAL recovery/group-commit + commit
-#              and dispatch-ring tests only — seconds, not minutes.
-#              Use while iterating on wal.py, _commit, or the
-#              dispatcher fan-out.
-# tier1-topo — topology lane (@pytest.mark.topo in
-#              tests/test_topo_place.py): best-fit-block solve vs the
-#              numpy oracle, permutation equivalence, and the scheduler
-#              e2e on torus/explicit-tree topologies.
-# tier1-delta — incremental cycle-state lane (@pytest.mark.delta in
-#              tests/test_delta_cycle.py): PendingTable/delta-snapshot
-#              oracle parity vs the from-scratch rebuild, no-op
-#              fingerprint re-arm/skip guards, event-driven wakeups.
-# tier1-trace — per-job tracing + SLO lane (@pytest.mark.jobtrace in
-#              tests/test_job_trace.py): timeline completeness across
-#              submit/hold/requeue/preempt/HA-failover, gRPC trace
-#              propagation ctld→craned, SLO window/burn math, and the
-#              bounded-ring spill accounting.
-# tier1-fed  — federated control-plane lane (@pytest.mark.fed in
-#              tests/test_federation.py): shard-map routing + misrouted
-#              submit forwarding, the arbiter's two-phase gang commit
-#              under a mid-reserve shard crash, bounded-staleness read
-#              refusal, and bit-exact single-vs-federated parity.
-# tier1-flight — stall-forensics + federated-observability lane
-#              (@pytest.mark.flight in tests/test_flight.py): flight
-#              recorder ring/stall sentry, probe heartbeat protocol +
-#              forced-hang diagnosis, XLA cache wiring, federated span
-#              propagation, and the cluster SLO merge vs the
-#              single-controller oracle.
-# tier1-multihost — multi-process mesh solve lane
-#              (@pytest.mark.multihost in tests/test_multihost.py):
-#              2-rank hierarchical solve vs the single-process oracle
-#              (overlapping + disjoint class tables), real 2-process
-#              CPU-mesh smoke (XLA_FLAGS forced host devices), mesh
-#              bootstrap failure modes over the rendezvous.
-# tier1-rebalance — elastic-federation lane (@pytest.mark.rebalance in
-#              tests/test_rebalance.py): live partition migration
-#              (four-phase WAL handoff, source SIGKILL mid-handoff,
-#              exactly-once by job name), hot-shard detector hysteresis,
-#              map-epoch client re-learn over the wire, and global
-#              MaxJobs/MaxSubmitJobs vs the single-controller oracle.
-# tier1-lint — metrics/docs parity (tools/check_metrics_docs.py):
-#              every registered crane_* metric has a row in the
-#              ARCHITECTURE.md metric inventory table and vice-versa.
-#              Runs first under `make tier1`.
-# tier1-resident — device-resident cluster-state lane
-#              (@pytest.mark.resident in tests/test_resident_state.py):
-#              steady-state patch (no full [N,R] rebuild), donation
-#              ownership discipline, invalidation epochs (mask table,
-#              node re-register, topology, backend switch), and the
-#              randomized event-script parity oracle vs the rebuild
-#              path.
+# tier1        — ROADMAP.md's tier-1 verify, verbatim (tools/tier1.sh):
+#                the whole suite on the CPU backend; prints
+#                DOTS_PASSED=<n> at the end.  Runs tier1-lint first.
+# tier1-lint   — metrics/docs parity (tools/check_metrics_docs.py):
+#                every registered crane_* metric has a row in the
+#                ARCHITECTURE.md metric inventory table and vice-versa.
+# tier1-commit — commit-path lane: WAL recovery/group-commit, commit and
+#                dispatch-ring tests and the per-route WAL counts only —
+#                seconds, not minutes.  Use while iterating on wal.py,
+#                _commit, or the dispatcher fan-out.
+# tier1-<mark> — one lane a marker of pytest.ini (obs, ha, topo, delta,
+#                resident, jobtrace, fed, flight, rebalance): the tests
+#                that carry @pytest.mark.<mark>, e.g. `make tier1-obs`
+#                while iterating on obs/, `make tier1-ha` for the
+#                leader+standby failover drill.
+#
+# How fast the system is is not a test's business: that is measured on
+# the chip by benchmark/run.py (README.md "Measuring", PERF.md).
 
-.PHONY: tier1 tier1-obs tier1-perf tier1-ha tier1-commit tier1-topo \
-	tier1-delta tier1-resident tier1-trace tier1-fed tier1-flight \
-	tier1-multihost tier1-rebalance tier1-lint
+PYTEST = env JAX_PLATFORMS=cpu python -m pytest -q \
+	-p no:cacheprovider -p no:xdist -p no:randomly
+
+.PHONY: tier1 tier1-lint tier1-commit
 
 tier1: tier1-lint
 	bash tools/tier1.sh
 
-# metrics/docs parity lint: every registered crane_* metric must have a
-# row in the ARCHITECTURE.md metric inventory table and vice-versa
 tier1-lint:
 	python tools/check_metrics_docs.py
 
-tier1-obs:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m obs \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-perf:
-	bash tools/tier1_perf.sh
-
-tier1-ha:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m ha \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
 tier1-commit:
-	env JAX_PLATFORMS=cpu python -m pytest \
-	  tests/test_wal_recovery.py tests/test_commit_dispatch.py \
-	  -q -m "not slow" \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
+	$(PYTEST) tests/test_wal_recovery.py tests/test_commit_dispatch.py \
+	  tests/test_cycle_counts.py -m "not slow"
 
-tier1-topo:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m topo \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-delta:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m delta \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-resident:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m resident \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-trace:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m jobtrace \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-fed:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m fed \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-flight:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m flight \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-multihost:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m multihost \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
-
-tier1-rebalance:
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m rebalance \
-	  -p no:cacheprovider -p no:xdist -p no:randomly
+tier1-%:
+	$(PYTEST) tests/ -m $*

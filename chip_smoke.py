@@ -82,7 +82,7 @@ _T0 = time.monotonic()
 
 def make_cluster(seed: int, num_nodes: int):
     """Node capacities: cpu 32-128 cores, memory 64-512 GiB, node i in
-    partition p{i % 4} (the shape of bench.py's north-star cluster)."""
+    partition p{i % 4}."""
     rng = np.random.default_rng(seed)
     cpu = rng.integers(32, 129, num_nodes)
     mem_gib = rng.integers(64, 513, num_nodes)
@@ -531,7 +531,7 @@ def resident_drill(args, cpu, mem_gib, part, num_nodes: int = 1024,
                    ticks: int = 8, per_tick: int = 192) -> dict:
     """Immediate-fit cycles (Backfill off) through ``JobScheduler`` with
     the resident, donated device state, against the same script with
-    ``ResidentState`` off: same jobs started on the same nodes, same
+    ``resident_state=False``: same jobs started on the same nodes, same
     ledger, tick by tick, while completions dirty rows in between."""
     from cranesched_tpu.craned.sim import SimCluster
     from cranesched_tpu.ctld import (

@@ -7,8 +7,8 @@ A from-scratch rebuild of the capability surface of PKUHPC/CraneSched
                 (fixed-point cpu, feasibility masks, fit counts).
 - ``models/``   jit-compiled solvers mapping (cluster state, job batch) ->
                 placements: the greedy scan, the time-axis backfill grid,
-                task packing/exclusive, the fast exact speculative paths,
-                and the multifactor priority sort (reference:
+                task packing/exclusive, the Pallas kernels, and the
+                multifactor priority sort (reference:
                 src/CraneCtld/JobScheduler.cpp:6507,7606).
 - ``parallel/`` Mesh/sharding layer: shard_map'd solvers splitting the node
                 axis across devices with ICI collectives for the merges.

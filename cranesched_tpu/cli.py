@@ -1162,8 +1162,7 @@ def _render_flight(fl: dict, tail: int = 32) -> list[str]:
     else:
         out.append("(no phase stamps recorded)")
     out.append(f"# stalls_total={fl.get('stalls_total', 0)} "
-               f"armed={fl.get('armed', False)} "
-               f"self_time_s={fl.get('self_time_s', 0.0)}")
+               f"armed={fl.get('armed', False)}")
     stall = fl.get("last_stall")
     if stall:
         out.append(f"LAST STALL label={stall.get('label')!r} "

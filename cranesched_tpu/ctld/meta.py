@@ -170,7 +170,7 @@ class MetaContainer:
         # fingerprint.  ``_dirty_nodes`` are the rows snapshot() must
         # patch in its cached arrays; ``delta_snapshot=False`` restores
         # the full per-node rebuild (oracle baseline for the parity
-        # tests and bench --churn).
+        # tests).
         self.meta_epoch = 0
         self._dirty_nodes: set[int] = set()
         self._snap: tuple | None = None

@@ -138,10 +138,9 @@ class ResidentClusterState:
         given the solve's returned state via adopt().  Returns
         ``(state, mode)`` with mode "rebuild", "patch", or "ledger"
         ("ledger" = empty delta, only the time-dependent cost ledger
-        shipped — exactly 4*N bytes; the BENCH_r10 churn legs ran
-        entirely in this mode but reported it as "patch", which made
-        the steady-state H2D look like patch traffic with zero dirty
-        rows).
+        shipped — exactly 4*N bytes; kept apart from "patch" so that
+        steady-state H2D does not read as patch traffic with zero
+        dirty rows).
         """
         state, self._state = self._state, None
         n = int(np.asarray(avail).shape[0])

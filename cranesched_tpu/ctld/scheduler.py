@@ -191,9 +191,7 @@ class SchedulerConfig:
     # only for the top-priority slice and place the tail with the fast
     # immediate solver against the MIN-over-horizon availability — a
     # tail job that fits the tightest bucket can never violate any
-    # reservation, so the split is strictly conservative.  Measured at
-    # 100k x 10k the full timed solve is ~15 s/cycle on TPU
-    # (BENCH_r04_backfill) while the split fits the 1 s cycle budget.
+    # reservation, so the split is strictly conservative.
     backfill_max_jobs: int = 1024
     # real node plane: a craned that misses pings for this long is down
     # (reference kCranedTimeoutSec = 30, PublicHeader.h:146)
@@ -226,10 +224,11 @@ class SchedulerConfig:
     # max(8, nodes // 64), capped at 128 — a 10k-node cluster gets 128
     # concurrent pushes instead of the historical hardcoded 8.
     dispatch_workers: int | None = None
-    # incremental cycle state (YAML ``Incremental``): the PendingTable
-    # candidate pass, delta meta snapshots, and the no-op-cycle
-    # fingerprint short-circuit.  False restores the from-scratch
-    # rebuild every cycle — the parity oracle and bench baseline.
+    # incremental cycle state: the PendingTable candidate pass, delta
+    # meta snapshots, and the no-op-cycle fingerprint short-circuit.
+    # TEST-ONLY reference, not a deployment setting (no YAML key reads
+    # it): False restores the from-scratch rebuild every cycle, the
+    # parity oracle of tests/test_delta_cycle.py.
     incremental: bool = True
     # event-driven loop (YAML ``CycleIdleSleep``): the longest the
     # server's cycle loop may sleep when the no-op fingerprint is armed
@@ -237,21 +236,16 @@ class SchedulerConfig:
     # event/edge model (e.g. remote license syncs, which deliberately
     # do not kick the loop).
     cycle_idle_sleep: float = 30.0
-    # device-resident cluster state (YAML ``ResidentState``): keep the
-    # immediate-fit solve's ClusterState buffers on device across
-    # cycles and scatter-patch only the dirty rows instead of
-    # re-uploading [N, R] every tick (ctld/resident.py).  Effective for
-    # solver "device" and "pallas" and only with ``incremental`` (the
-    # dirty feed is the delta-snapshot machinery); False rebuilds from
-    # the host snapshot every cycle — the parity oracle.
+    # device-resident cluster state: keep the immediate-fit solve's
+    # ClusterState buffers on device across cycles and scatter-patch
+    # only the dirty rows instead of re-uploading [N, R] every tick
+    # (ctld/resident.py).  Effective for solver "device" and "pallas"
+    # and only with ``incremental`` (the dirty feed is the
+    # delta-snapshot machinery).  TEST-ONLY reference like
+    # ``incremental`` (no YAML key): False rebuilds from the host
+    # snapshot every cycle, the parity oracle of
+    # tests/test_resident_state.py.
     resident_state: bool = True
-    # S-stream Pallas solve knobs (YAML ``MaxStreams``/``BlockJobs``),
-    # fed to plan_streams / solve_greedy_pallas_auto.  Defaults match
-    # the shipped stream profile; re-measure on new hardware with
-    # tools/kstream.py (writes profiles/<device>_STREAMS_PROFILE.md and
-    # prints the YAML to pin).
-    max_streams: int = 4
-    block_jobs: int = 256
     # per-job lifecycle tracing (YAML ``Observability: JobTrace``):
     # event-sourced timelines (obs/jobtrace.py) stamped at submit /
     # candidate / commit / durable-dispatch / terminal edges plus the
@@ -275,10 +269,6 @@ class SchedulerConfig:
             raise ValueError(
                 "solver must be auto|device|native|pallas|sharded, "
                 f"got {self.solver!r}")
-        if self.max_streams < 1 or self.block_jobs < 1:
-            raise ValueError(
-                f"max_streams/block_jobs must be >= 1, got "
-                f"{self.max_streams}/{self.block_jobs}")
 
 
 @dataclasses.dataclass
@@ -2811,7 +2801,7 @@ class JobScheduler:
             # the cycle's PRELUDE ends when the first solve starts:
             # priority sort + batch build + stream planning all count
             # toward it (that is the span the device-resident tables
-            # exist to shrink, and what bench/tier1-perf assert on)
+            # exist to shrink)
             trace.setdefault("_prelude_end", t0)
             with solve_span(f"crane:solve:{label}"):
                 out = fn()
@@ -3102,7 +3092,6 @@ class JobScheduler:
 
         interpret = self.pallas_interpret
         donate = not interpret   # the interpreter's CPU ignores donation
-        cfg = self.config
         if resident_ok and self._resident.enabled:
             state, _mode = self._resident.acquire(
                 avail, total, alive, cost0,
@@ -3115,8 +3104,6 @@ class JobScheduler:
             placements, new_state, used_plan = (
                 solve_greedy_pallas_from_batch(
                     state, jobs_batch, max_nodes=max_nodes,
-                    block_jobs=cfg.block_jobs,
-                    max_streams=cfg.max_streams,
                     interpret=interpret, donate=donate,
                     return_plan=True))
         else:
@@ -3127,15 +3114,12 @@ class JobScheduler:
                 # reduction
                 plan = plan_streams(jobs_batch.job_class_np,
                                     jobs_batch.class_rows_np,
-                                    max_streams=cfg.max_streams,
-                                    block_jobs=cfg.block_jobs,
                                     known_disjoint=True)
             placements, new_state, used_plan = solve_greedy_pallas_auto(
                 state, jobs_batch.req, jobs_batch.node_num,
                 jobs_batch.time_limit, jobs_batch.valid,
                 jobs_batch.job_class, jobs_batch.class_masks,
-                max_nodes=max_nodes, block_jobs=cfg.block_jobs,
-                max_streams=cfg.max_streams, interpret=interpret,
+                max_nodes=max_nodes, interpret=interpret,
                 donate=donate, plan=plan, return_plan=True)
         if resident_ok and self._resident.enabled:
             self._resident.adopt(new_state)

@@ -112,8 +112,7 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
     # selections of all S streams are mutually independent and their
     # latency chains overlap (the kernel is latency-bound on each
     # job's compare→min-reduce→update dependency chain, NOT on vector
-    # width — measured: quartering the node axis changed per-job time
-    # by <4%, tools/kexp.py).  This is the TPU analog of the
+    # width).  This is the TPU analog of the
     # reference's per-partition LocalScheduler split
     # (src/CraneCtld/JobScheduler.cpp:6516-6530).
     def kernel(job_s, nelig_s,                           # SMEM scalars
@@ -398,8 +397,8 @@ def _solve_serial_impl(state: ClusterState, req, node_num, time_limit,
 # total/alive alias straight through).  Callers opt in per call via
 # ``donate=`` — a donated state must not be touched again, so only the
 # scheduler's cycle loop (which always adopts the returned state) asks
-# for it; parity tests and bench repeats re-solve from the same state
-# and must keep the non-donating twin.
+# for it; parity tests re-solve from the same state and must keep the
+# non-donating twin.
 _SERIAL_STATICS = ("max_nodes", "block_jobs", "interpret")
 _solve_serial_jit = _instrument_jit(
     "solve_pallas_serial", functools.partial(
@@ -541,9 +540,8 @@ def plan_streams(job_class, class_masks, max_streams: int = 4,
     if longest * 2 > total:
         return None                 # too skewed: streams mostly padding
     # quantize the padded stream length to 8-block steps: padding
-    # stays under 8 * block_jobs slots (measured: the 1.25^k quantum
-    # wasted 24% of the kernel at the bench shape) while shifting
-    # workloads still reuse a bounded set of compiled kernels
+    # stays under 8 * block_jobs slots while shifting workloads still
+    # reuse a bounded set of compiled kernels
     nb = -(-max(longest, 1) // block_jobs)
     stream_len = (-(-nb // 8) * 8) * block_jobs
     return jnp.asarray(stream_of_class), S, stream_len

@@ -17,9 +17,9 @@ Design (TPU-first, not a translation):
 * The inherently sequential greedy loop (each placement mutates
   availability) is a ``lax.scan`` over the priority-ordered job batch.  Each
   scan step is O(N*R) vector work that XLA fuses; there is no data-dependent
-  control flow.  ``solve_batched`` (models/speculative.py) processes many
-  jobs per step with conflict repair and is the fast path; this scan is the
-  semantics-defining reference path the fast path must agree with.
+  control flow.  This scan is the semantics-defining reference path: the
+  Pallas kernels (models/pallas_solver.py), the native solver and the
+  sharded solves are each asserted bit-identical to it.
 * Selection semantics match the reference: nodes are considered in ascending
   cost order and the first ``node_num`` nodes whose *current* availability
   fits the per-node requirement are taken (GetNodesAndTrySchedule_ iterates

@@ -8,8 +8,7 @@ XLA compilation cache wiring.
   sentry captures ``sys._current_frames()`` for every thread into
   ``last_stall`` alongside the ring tail — the "what was the scheduler
   doing when it stopped" answer, without attaching a debugger to a
-  wedged daemon.  All bookkeeping self-time is accumulated so the bench
-  can prove the recorder costs <= 1% of a cycle.
+  wedged daemon.
 
 * :func:`enable_xla_cache` — turns on jax's persistent compilation
   cache with the size and compile-time floors dropped so every
@@ -87,7 +86,6 @@ class FlightRecorder:
         self.event_sink = event_sink
         self._ring: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
-        self.self_time_s = 0.0
         self.stalls_total = 0
         self.last_stall: dict | None = None
         # sentry state: deadline on the monotonic clock, None = disarmed
@@ -102,14 +100,12 @@ class FlightRecorder:
     def stamp(self, phase: str, detail: str = "",
               t: float | None = None) -> None:
         """Append one phase stamp (wall time, phase, detail)."""
-        t0 = time.perf_counter()
         rec = {"t": time.time() if t is None else t, "phase": phase}
         if detail:
             rec["detail"] = detail
         with self._lock:
             self._ring.append(rec)
         _STAMPS_CELL.inc()
-        self.self_time_s += time.perf_counter() - t0
 
     # -- the stall sentry --
 
@@ -183,7 +179,6 @@ class FlightRecorder:
             return {"phases": list(self._ring)[-tail:],
                     "stalls_total": self.stalls_total,
                     "last_stall": self.last_stall,
-                    "self_time_s": round(self.self_time_s, 6),
                     "armed": self._deadline is not None}
 
 

@@ -27,9 +27,7 @@ The compile counters are process-global (the jit caches they observe
 are), but per-cycle attribution is delta-based: the scheduler snapshots
 :func:`total_compiles` at cycle start and records the delta in the
 cycle trace (``recompiles``), emitting a ``recompile_steady`` event
-when a warm cycle pays one.  All bookkeeping self-time is accumulated
-in :func:`self_time_s` so the bench can prove the introspection plane
-itself costs < 2% of a cycle.
+when a warm cycle pays one.
 """
 
 from __future__ import annotations
@@ -62,7 +60,6 @@ _MET_DEV_BUFFERS = _OBS.gauge(
 
 _lock = threading.Lock()
 _total_compiles = 0
-_self_time = 0.0  # seconds spent inside introspection bookkeeping
 
 
 def total_compiles() -> int:
@@ -71,23 +68,10 @@ def total_compiles() -> int:
         return _total_compiles
 
 
-def self_time_s() -> float:
-    """Cumulative seconds of introspection overhead (observer probes +
-    memory sampling) — the numerator of the bench's overhead share."""
-    with _lock:
-        return _self_time
-
-
 def _note(n: int, dt: float) -> None:
     global _total_compiles
     with _lock:
         _total_compiles += n
-
-
-def _add_self_time(dt: float) -> None:
-    global _self_time
-    with _lock:
-        _self_time += dt
 
 
 def instrument_jit(name: str, jitted: Callable) -> Callable:
@@ -106,13 +90,11 @@ def instrument_jit(name: str, jitted: Callable) -> Callable:
     def wrapper(*args, **kwargs):
         if probe is None:
             return jitted(*args, **kwargs)
-        p0 = time.perf_counter()
         try:
             before = probe()
         except Exception:  # pragma: no cover - defensive vs jax internals
             return jitted(*args, **kwargs)
         t0 = time.perf_counter()
-        _add_self_time(t0 - p0)
         out = jitted(*args, **kwargs)
         t1 = time.perf_counter()
         try:
@@ -125,7 +107,6 @@ def instrument_jit(name: str, jitted: Callable) -> Callable:
             _note(grew, t1 - t0)
             log.debug("jit compile: %s (+%d entries, %.3fs)",
                       name, grew, t1 - t0)
-        _add_self_time(time.perf_counter() - t1)
         return out
 
     wrapper.__name__ = f"observed_{name}"
@@ -148,7 +129,6 @@ def sample_device_memory(peak_reset: bool = False) -> dict:
     bytes/peak are -1 when the backend exposes no ``memory_stats()``
     (the stock CPU client).  ``buffers`` counts live jax arrays in the
     process, which works on every backend."""
-    t0 = time.perf_counter()
     bytes_live = peak = -1
     buffers = -1
     try:
@@ -171,7 +151,6 @@ def sample_device_memory(peak_reset: bool = False) -> dict:
     _MET_DEV_PEAK.set(peak)
     if buffers >= 0:
         _MET_DEV_BUFFERS.set(buffers)
-    _add_self_time(time.perf_counter() - t0)
     return {"bytes": bytes_live, "peak_bytes": peak, "buffers": buffers}
 
 

@@ -46,7 +46,6 @@ Derived metrics (per-process REGISTRY):
 from __future__ import annotations
 
 import threading
-import time
 
 from cranesched_tpu.obs.metrics import REGISTRY
 
@@ -131,10 +130,6 @@ class JobTraceRecorder:
         self._done: dict[tuple[int, int], _Timeline] = {}
         self.stamps_total = 0
         self.spilled = 0
-        # wall seconds spent recording — the direct measurement behind
-        # the "tracing costs <=2% of the cycle" guard (differencing
-        # whole trace-on/off runs just reads scheduler jitter)
-        self.self_time_s = 0.0
 
     # ------------------------------------------------------------------
     # recording
@@ -145,13 +140,10 @@ class JobTraceRecorder:
               seq: int | None = None, synthetic: bool = False) -> bool:
         """Record one span; returns False when this (incarnation, edge)
         was already stamped (idempotent — the HA re-stamp contract)."""
-        t0 = time.perf_counter()
         with self._lock:
-            out = self._stamp_locked(job_id, incarnation, edge, t,
-                                     node_id, epoch, skew, seq,
-                                     synthetic)
-        self.self_time_s += time.perf_counter() - t0
-        return out
+            return self._stamp_locked(job_id, incarnation, edge, t,
+                                      node_id, epoch, skew, seq,
+                                      synthetic)
 
     def stamp_many(self, edge: str, items, t: float) -> int:
         """Batch stamp under ONE lock acquisition: ``items`` yields
@@ -161,7 +153,6 @@ class JobTraceRecorder:
         batch instead of three per stamp."""
         n = 0
         lats: list[tuple[float, int]] = []
-        t0 = time.perf_counter()
         with self._lock:
             for job_id, incarnation in items:
                 if self._stamp_locked(job_id, incarnation, edge, t,
@@ -179,7 +170,6 @@ class JobTraceRecorder:
                         _MET_LAT.observe(lat, edge=edge)
                 worst_lat, worst_job = max(lats)
                 self._note_exemplar(edge, worst_lat, worst_job)
-        self.self_time_s += time.perf_counter() - t0
         return n
 
     def _stamp_locked(self, job_id, incarnation, edge, t, node_id,
@@ -324,7 +314,6 @@ class JobTraceRecorder:
                     "completed": len(self._done),
                     "spilled": self.spilled,
                     "stamps_total": self.stamps_total,
-                    "self_time_s": round(self.self_time_s, 6),
                     "capacity": self.capacity}
 
 

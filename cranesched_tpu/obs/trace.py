@@ -106,7 +106,7 @@ period: with dispatch_ms and unnamed_ms they sum to period_ms.
                      ProfilerWindow capture
 
 ``solve_span`` wraps a solve closure in ``jax.profiler.TraceAnnotation``
-so tools/kexp.py traces line up with cycle phases; it degrades to a
+so a captured device trace lines up with cycle phases; it degrades to a
 no-op when the profiler is unavailable (CPU CI containers).  Inside a
 capture (and only then) ``CycleClock`` puts each phase on the
 profiler's clock too, as ``crane:cycle:<phase>``.
@@ -252,9 +252,9 @@ def solve_span(name: str) -> Iterator[None]:
     """jax.profiler.TraceAnnotation span, no-op without a profiler.
 
     Used around the lock-released solve closures so a captured device
-    trace (KEXP_TRACE / jax.profiler.trace) shows one named span per
-    cycle phase — kernel attribution in tools/kexp.py then lines up
-    with the cycle trace timings."""
+    trace (CaptureProfile / jax.profiler.trace) shows one named span
+    per cycle phase, so kernel attribution lines up with the cycle
+    trace timings."""
     try:
         from jax.profiler import TraceAnnotation
     except Exception:       # pragma: no cover - jax always importable here

@@ -11,20 +11,15 @@ travel over ICI as ``all_gather``/``psum`` collectives.  See
 # imported), and sharded.py imports jax at module scope.
 _SHARDED = ("make_node_mesh", "shard_cluster_state",
             "solve_greedy_sharded", "solve_greedy_sharded_classes")
-_DISTRIBUTED = ("bootstrap_process_mesh", "ProcessMesh",
-                "solve_greedy_sharded_classes_mp")
 _ACQUIRE = ("acquire_backend", "expected_platform", "preflight_report")
 
-__all__ = [*_SHARDED, *_DISTRIBUTED, *_ACQUIRE]
+__all__ = [*_SHARDED, *_ACQUIRE]
 
 
 def __getattr__(name):
     import importlib
     if name in _SHARDED:
         mod = importlib.import_module("cranesched_tpu.parallel.sharded")
-    elif name in _DISTRIBUTED:
-        mod = importlib.import_module(
-            "cranesched_tpu.parallel.distributed")
     elif name in _ACQUIRE:
         mod = importlib.import_module("cranesched_tpu.parallel.acquire")
     else:

@@ -160,7 +160,7 @@ REPLAY_SLOS = (
 
 def _run_closed_loop(sched, sim, specs, wal_path: str | None = None,
                      max_cycles=100_000):
-    """SLO-asserted closed loop (REPLAY_r06): the full RPC path, after
+    """SLO-asserted closed loop: the full RPC path, after
     which the run audits itself from its own telemetry — the timeline
     ledger proves no job was lost or double-finalized, every finished
     job's span sum matches the wall clock within its recorded skew
@@ -393,7 +393,7 @@ def replay_topo(scale: float, rng, run=_run_direct):
 
 def replay_federation(scale: float, rng, wal_dir: str | None = None,
                       kill_shard: str = "east"):
-    """Closed-loop federation drill (REPLAY_r07): two WAL-backed shards
+    """Closed-loop federation drill: two WAL-backed shards
     + the placement arbiter on one virtual clock, a submit storm that is
     40% cross-partition gangs, and one shard SIGKILL'd mid-storm at the
     worst possible moment — immediately after a durable gang reserve,
@@ -520,7 +520,7 @@ def replay_federation(scale: float, rng, wal_dir: str | None = None,
 
 
 def replay_rebalance(scale: float, rng, wal_dir: str | None = None):
-    """Elastic-federation drill (REPLAY_r08): a two-shard submit storm
+    """Elastic-federation drill: a two-shard submit storm
     with global per-user limits gossiping under bounded staleness, then
     a LIVE migration of the loaded partition mid-storm — with the
     source shard SIGKILL'd at the worst moment of the handoff (begin

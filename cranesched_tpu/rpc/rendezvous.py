@@ -230,7 +230,13 @@ class RendezvousServer:
 
 class RendezvousClient:
     """Member-side stub (used by cranesched_tpu.coord) — the shared
-    GrpcStub plumbing with the gang-token header."""
+    GrpcStub plumbing with the gang-token header.
+
+    Every call waits for the service to be there (``wait_for_ready``),
+    up to its own timeout: rank 0's supervisor hosts it, and a member
+    on another node may well reach its first put or fence before that
+    supervisor has bound the port.  Failing at once on the refused
+    connection would make the gang's outcome a race between nodes."""
 
     def __init__(self, address: str, token: str = "", tls=None,
                  epoch: int = 0):
@@ -247,14 +253,14 @@ class RendezvousClient:
             "Put", pb.RdzvPutRequest(
                 key=key, value=value,
                 epoch=self.epoch if epoch is None else epoch),
-            pb.OkReply)
+            pb.OkReply, wait_for_ready=True)
         if not reply.ok:
             raise RuntimeError(f"put {key!r} rejected: {reply.error}")
 
     def get(self, key: str, timeout: float = 0.0) -> bytes | None:
         reply = self._stub.call(
             "Get", pb.RdzvGetRequest(key=key, timeout=timeout),
-            pb.RdzvGetReply, timeout=timeout + 30.0)
+            pb.RdzvGetReply, timeout=timeout + 30.0, wait_for_ready=True)
         return reply.value if reply.ok else None
 
     def fence(self, fence_id: str, rank: int, nranks: int,
@@ -266,7 +272,8 @@ class RendezvousClient:
                 fence_id=fence_id, rank=rank, nranks=nranks, data=data,
                 timeout=timeout,
                 epoch=self.epoch if epoch is None else epoch),
-            pb.RdzvFenceReply, timeout=timeout + 30.0)
+            pb.RdzvFenceReply, timeout=timeout + 30.0,
+            wait_for_ready=True)
         if not reply.ok:
             raise RuntimeError(f"fence {fence_id!r} failed: "
                                f"{reply.error}")

@@ -119,7 +119,7 @@ class Topology:
     @classmethod
     def uniform_blocks(cls, num_nodes: int, block_size: int,
                        name_prefix: str = "block") -> "Topology":
-        """Contiguous-id blocks of equal size (bench/replay generator)."""
+        """Contiguous-id blocks of equal size (replay generator)."""
         if block_size <= 0 or num_nodes % block_size:
             raise ValueError(
                 f"block size {block_size} does not divide {num_nodes}")

@@ -254,18 +254,8 @@ class CraneConfig:
             # derive it from cluster size (max(8, nodes // 64), cap 128)
             dispatch_workers=(int(sc["DispatchWorkers"])
                               if sc.get("DispatchWorkers") else None),
-            # incremental cycle state (PendingTable + delta snapshot +
-            # no-op fingerprint); off = from-scratch rebuild every tick
-            incremental=bool(sc.get("Incremental", True)),
             # provably-idle loop sleep bound (event kicks end it early)
             cycle_idle_sleep=float(sc.get("CycleIdleSleep", 30)),
-            # device-resident ClusterState across cycles (dirty-row
-            # scatter patch instead of a full [N, R] upload per tick)
-            resident_state=bool(sc.get("ResidentState", True)),
-            # S-stream Pallas solve knobs; pin from the measured optimum
-            # in profiles/<device>_STREAMS_PROFILE.md (tools/kstream.py)
-            max_streams=int(sc.get("MaxStreams", 4)),
-            block_jobs=int(sc.get("BlockJobs", 256)),
             # per-job lifecycle tracing (obs/jobtrace.py) + SLO targets
             # (obs/slo.py) from the Observability: block
             job_trace=_parse_onoff(
@@ -353,9 +343,24 @@ def make_node_event_script_hook(script: str):
     return hook
 
 
+# ``Scheduler:`` keys this program once read and no longer does.  A
+# site's stale key must not silently change meaning (``Incremental:
+# false`` would now run the incremental path), so a file that still
+# carries one is refused.
+_REMOVED_SCHEDULER_KEYS = ("Incremental", "ResidentState", "MaxStreams",
+                           "BlockJobs")
+
+
 def load_config(path: str) -> CraneConfig:
     with open(path, encoding="utf-8") as fh:
         raw = yaml.safe_load(fh) or {}
+
+    scheduler = raw.get("Scheduler", {}) or {}
+    for key in _REMOVED_SCHEDULER_KEYS:
+        if key in scheduler:
+            raise ValueError(
+                f"{path}: `Scheduler: {key}` is no longer a setting "
+                "(the value it selected is fixed); remove the key")
 
     nodes = []
     for entry in raw.get("Nodes", []):
@@ -389,7 +394,7 @@ def load_config(path: str) -> CraneConfig:
             (raw.get("Accounting") or {}).get("Store", "") or ""),
         nodes=nodes,
         partitions=partitions,
-        scheduler=raw.get("Scheduler", {}) or {},
+        scheduler=scheduler,
         priority=raw.get("Priority", {}) or {},
         licenses=raw.get("Licenses", []) or [],
         submit_hook_path=str(raw.get("SubmitHook", "") or ""),

@@ -1,0 +1,114 @@
+"""What a cycle may cost in WAL barriers, route by route.
+
+``_cycle_body`` serves five routes (packed, topo, backfill,
+backfill-split, immediate).  Each runs every lock-held segment inside one
+WAL group and closes it before every yielded solve closure, so a cycle
+pays one fsync a non-empty group and never holds a group open while the
+server lock is released.  These are counts, exact on any platform: every
+route, once in a cycle that places and once in a cycle that places
+nothing, against a real fsyncing WAL.  They pin the numbers each route
+has today, so that folding the routes' shared epilogue cannot move one
+unseen."""
+
+import pytest
+
+from cranesched_tpu.ctld import (
+    JobScheduler,
+    JobSpec,
+    MetaContainer,
+    ResourceSpec,
+    SchedulerConfig,
+)
+from cranesched_tpu.ctld.wal import WriteAheadLog
+from cranesched_tpu.topo.model import Topology
+
+NODES = 8
+CPU = 16.0
+
+
+def _res(cpu):
+    return ResourceSpec(cpu=cpu, mem_bytes=1 << 30, memsw_bytes=1 << 30)
+
+
+# route -> (SchedulerConfig fields, the spec whose presence selects the
+# route, jobs a cycle, the trace row's label, solves yielded a cycle,
+# non-empty WAL groups of a cycle that places).  The split route commits
+# its head and its tail apart: two groups, where every other route has one
+# (the documented bound is three a cycle).
+ROUTES = {
+    "packed": (dict(backfill=True),
+               dict(res=_res(1.0), exclusive=True), 4, "packed", 1, 1),
+    "topo": (dict(backfill=True),
+             dict(res=_res(2.0), node_num=2), 3, "topo", 1, 1),
+    "backfill": (dict(backfill=True),
+                 dict(res=_res(2.0)), 4, "backfill", 1, 1),
+    "backfill-split": (dict(backfill=True, backfill_max_jobs=2),
+                       dict(res=_res(2.0)), 6, "backfill-split", 2, 2),
+    # the route fifo-1k serves: Backfill off, the Pallas kernel (here
+    # through the interpreter) over the resident state
+    "immediate": (dict(backfill=False, solver="pallas"),
+                  dict(res=_res(2.0)), 4, "pallas", 1, 1),
+}
+
+
+def _drive(sched, wal, now):
+    """``schedule_cycle``, with the WAL looked at wherever the server
+    would release its lock: at each yielded closure no group is open and
+    nothing is buffered."""
+    gen = sched.cycle_phases(now)
+    yields = 0
+    try:
+        fn = next(gen)
+        while True:
+            assert wal._group_depth == 0, "a WAL group open across a yield"
+            assert not wal._group_buf, "records buffered across a yield"
+            yields += 1
+            fn = gen.send(fn())
+    except StopIteration as stop:
+        return stop.value or [], yields
+
+
+@pytest.mark.parametrize("places", [True, False],
+                         ids=["places", "places_nothing"])
+@pytest.mark.parametrize("route", sorted(ROUTES))
+def test_wal_counts_of_a_cycle(tmp_path, route, places):
+    config, spec, count, label, solves, groups = ROUTES[route]
+    meta = MetaContainer()
+    for i in range(NODES):
+        meta.add_node(f"cn{i}", meta.layout.encode(
+            cpu=CPU, mem_bytes=64 << 30, memsw_bytes=64 << 30,
+            is_capacity=True), partitions=("default",))
+        meta.craned_up(i)
+    if route == "topo":
+        meta.set_topology(Topology.uniform_blocks(NODES, 4))
+    wal = WriteAheadLog(str(tmp_path / "ctld.wal"))
+    sched = JobScheduler(meta, SchedulerConfig(**config), wal=wal)
+    sched.dispatch = lambda *a, **kw: None
+    sched.pallas_interpret = True
+    now = 0.0
+    if not places:
+        # every node taken whole by a job that outlasts the test
+        for _ in range(NODES):
+            sched.submit(JobSpec(res=_res(CPU), time_limit=86400), now=now)
+        assert len(sched.schedule_cycle(now=1.0)) == NODES
+        now = 2.0
+    ids = [sched.submit(JobSpec(time_limit=600, **spec), now=now)
+           for _ in range(count)]
+    assert all(ids)
+
+    started, yields = _drive(sched, wal, now + 1.0)
+
+    row = sched.cycle_trace.snapshot()[-1]
+    assert row["solver"] == label
+    assert row["candidates"] == count
+    assert sorted(started) == (ids if places else [])
+    assert row["placed"] == len(started)
+    # one fsync a non-empty group, and no barrier outside a group
+    assert row["wal_fsyncs"] == row["wal_groups"]
+    assert row["wal_groups"] == (groups if places else 0)
+    # the solves, and one more yield for the dispatch ring where a job started
+    assert yields == solves + (1 if places else 0)
+    # the cycle's last act left nothing behind it either
+    assert wal._group_depth == 0 and not wal._group_buf
+    assert wal.durable_seq == wal.seq
+    wal.close()

@@ -65,7 +65,6 @@ def test_ring_is_bounded_and_report_tails():
     assert rep["stalls_total"] == 0
     assert rep["last_stall"] is None
     assert rep["armed"] is False
-    assert rep["self_time_s"] >= 0.0
     fr.close()
 
 

@@ -86,8 +86,6 @@ def test_instrument_jit_counts_fresh_compiles_only():
     assert introspect.total_compiles() == base + 2
     assert (introspect._MET_COMPILES.value(fn="t_introspect_probe")
             == mbase + 2)
-    # the observer's own cost is accounted, for the bench's <=2% proof
-    assert introspect.self_time_s() > 0.0
 
 
 def test_instrument_jit_preserves_jit_surface():

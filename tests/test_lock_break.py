@@ -86,8 +86,8 @@ def test_submit_and_query_latency_during_slow_cycle():
         lat.sort()
         p99 = lat[int(len(lat) * 0.99) - 1]
         # >=2 one-second solves ran inside this window; with the lock
-        # held across solves p99 would be ~1 s (REPLAY_r04 measured
-        # 1.5 s max).  50 ms is the VERDICT r5 #4 budget.
+        # held across solves p99 would be ~1 s.  50 ms is the
+        # VERDICT r5 #4 budget.
         assert p99 < 0.05, f"submit+query p99 {p99 * 1e3:.1f} ms"
         assert len(lat) > 50  # the client genuinely ran during solves
     finally:

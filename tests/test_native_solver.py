@@ -125,8 +125,7 @@ def test_native_partition_ids_equal_dense_mask():
 
 
 def test_native_throughput_smoke():
-    """The ordered-frontier walk must stay fast at a mid-size shape (the
-    full 100k x 10k run is bench.py's job)."""
+    """The ordered-frontier walk must stay fast at a mid-size shape."""
     import time
     rng = np.random.default_rng(0)
     N, J = 2000, 20000

@@ -70,23 +70,23 @@ def _random_problem(rng, num_jobs, num_nodes, num_classes=3,
     return state, jobs
 
 
+def _assert_same_as_scan(got, new_state, state, jobs, max_nodes):
+    """Placements and ledgers of a kernel against ``solve_greedy``'s."""
+    ref, ref_state = solve_greedy(state, jobs, max_nodes=max_nodes)
+    for a, b in ((got.placed, ref.placed), (got.nodes, ref.nodes),
+                 (got.reason, ref.reason),
+                 (new_state.avail, ref_state.avail),
+                 (new_state.cost, ref_state.cost)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def _assert_bit_identical(state, jobs, max_nodes):
-    p_ref, s_ref = solve_greedy(state, jobs, max_nodes=max_nodes)
     p_pl, s_pl = solve_greedy_pallas_from_batch(
         state, jobs, max_nodes=max_nodes, interpret=True)
-    np.testing.assert_array_equal(np.asarray(p_ref.placed),
-                                  np.asarray(p_pl.placed))
-    np.testing.assert_array_equal(np.asarray(p_ref.nodes),
-                                  np.asarray(p_pl.nodes))
-    np.testing.assert_array_equal(np.asarray(p_ref.reason),
-                                  np.asarray(p_pl.reason))
-    np.testing.assert_array_equal(np.asarray(s_ref.avail),
-                                  np.asarray(s_pl.avail))
-    np.testing.assert_array_equal(np.asarray(s_ref.cost),
-                                  np.asarray(s_pl.cost))
+    _assert_same_as_scan(p_pl, s_pl, state, jobs, max_nodes)
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("seed", range(6))
 def test_random_parity(seed):
     rng = np.random.default_rng(seed)
     state, jobs = _random_problem(rng, num_jobs=70, num_nodes=50)
@@ -124,26 +124,16 @@ def _assert_auto_bit_identical(state, jobs, max_nodes, max_streams=4):
     """The auto dispatcher (streamed kernel when classes are disjoint)
     must match the scan solver bit-for-bit as well."""
     job_class, masks = classes_from_part_mask(np.asarray(jobs.part_mask))
-    p_ref, s_ref = solve_greedy(state, jobs, max_nodes=max_nodes)
     p_st, s_st = solve_greedy_pallas_auto(
         state, jobs.req, jobs.node_num, jobs.time_limit, jobs.valid,
         jnp.asarray(job_class), jnp.asarray(masks),
         max_nodes=max_nodes, max_streams=max_streams, interpret=True)
-    np.testing.assert_array_equal(np.asarray(p_ref.placed),
-                                  np.asarray(p_st.placed))
-    np.testing.assert_array_equal(np.asarray(p_ref.nodes),
-                                  np.asarray(p_st.nodes))
-    np.testing.assert_array_equal(np.asarray(p_ref.reason),
-                                  np.asarray(p_st.reason))
-    np.testing.assert_array_equal(np.asarray(s_ref.avail),
-                                  np.asarray(s_st.avail))
-    np.testing.assert_array_equal(np.asarray(s_ref.cost),
-                                  np.asarray(s_st.cost))
+    _assert_same_as_scan(p_st, s_st, state, jobs, max_nodes)
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("seed", range(6))
 def test_streamed_parity_disjoint_classes(seed):
-    """Bench-like shape: disjoint partitions -> the auto path takes the
+    """Deployment-like shape: disjoint partitions -> the auto path takes the
     S-stream kernel; placements must still be bit-identical to the
     scan solver."""
     rng = np.random.default_rng(seed)
@@ -201,6 +191,109 @@ def test_streamed_parity_gangs_and_dead_nodes():
                                   num_classes=4, dead_frac=0.2,
                                   max_nodes=3)
     _assert_auto_bit_identical(state, jobs, max_nodes=3)
+
+
+# ---------------------------------------------------------------------------
+# each kernel by name (the auto dispatch above picks one from the classes):
+# inputs the random problem of this file does not draw: nodes partly in use,
+# fractional costs, gangs up to K = 4, a cluster that saturates, a spread
+# regime in which every placement reorders the costs
+# ---------------------------------------------------------------------------
+
+KERNEL_BLOCK = 8        # jobs a block: the interpreter pays a slot, not a job
+
+
+def _assert_kernel_bit_identical(state, jobs, max_nodes, kernel):
+    """``serial``: the one-job-a-slot kernel whatever the classes are.
+    ``streamed``: one stream a class, which needs the classes disjoint
+    (the caller's business) and takes no advice from ``plan_streams``."""
+    job_class, masks = classes_from_part_mask(np.asarray(jobs.part_mask))
+    args = (state, jobs.req, jobs.node_num, jobs.time_limit, jobs.valid,
+            jnp.asarray(job_class), jnp.asarray(masks))
+    if kernel == "serial":
+        got, new_state = solve_greedy_pallas(
+            *args, max_nodes=max_nodes, block_jobs=KERNEL_BLOCK,
+            interpret=True)
+    else:
+        C = masks.shape[0]
+        assert 2 <= C <= 4 and not (masks.sum(axis=0) > 1).any()
+        longest = int(np.bincount(job_class, minlength=C).max())
+        got, new_state = _solve_streamed(
+            *args, jnp.arange(C, dtype=jnp.int32), max_nodes=max_nodes,
+            block_jobs=KERNEL_BLOCK, num_streams=C,
+            stream_len=-(-longest // KERNEL_BLOCK) * KERNEL_BLOCK,
+            interpret=True)
+    _assert_same_as_scan(got, new_state, state, jobs, max_nodes)
+    return got
+
+
+def _partitioned(jobs, num_nodes, parts, rng):
+    """The batch with eligibility redrawn as ``parts`` disjoint partitions."""
+    node_part = rng.integers(0, parts, num_nodes)
+    job_part = rng.integers(0, parts, jobs.valid.shape[0])
+    return jobs.replace(part_mask=jnp.asarray(
+        job_part[:, None] == node_part[None, :]))
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("kernel", ["serial", "streamed"])
+def test_used_nodes_fractional_costs_k4(kernel, seed):
+    from test_sharded_parity import _random_problem as used_problem
+    rng = np.random.default_rng(seed)
+    state, jobs = used_problem(rng, num_jobs=100, num_nodes=40,
+                               max_nodes=4)
+    if kernel == "streamed":
+        jobs = _partitioned(jobs, 40, 4, rng)
+    _assert_kernel_bit_identical(state, jobs, 4, kernel)
+
+
+@pytest.mark.parametrize("kernel", ["serial", "streamed"])
+def test_gangs_saturate_the_cluster(kernel):
+    """Whole-node gangs of 2, 1, 3, ... on six nodes a partition: the
+    first three take all six, every later one reads a cluster with
+    nothing left."""
+    lay = ResourceLayout()
+    parts = 1 if kernel == "serial" else 2
+    N, J = 6 * parts, 12 * parts
+    total = np.tile(lay.encode(cpu=8, is_capacity=True), (N, 1))
+    state = make_cluster_state(total.copy(), total, np.ones(N, bool),
+                               np.arange(N, dtype=np.float32))
+    jobs = JobBatch(
+        req=jnp.asarray(np.tile(lay.encode(cpu=8), (J, 1))),
+        node_num=jnp.asarray([2, 1, 3, 1, 2, 1] * (J // 6), jnp.int32),
+        time_limit=jnp.full(J, 3600, jnp.int32),
+        part_mask=jnp.asarray((np.arange(J) // 12)[:, None]
+                              == (np.arange(N) // 6)[None, :]),
+        valid=jnp.ones(J, bool))
+    got = _assert_kernel_bit_identical(state, jobs, 4, kernel)
+    placed = np.asarray(got.placed).reshape(parts, 12)
+    assert placed[:, :3].all() and not placed[:, 3:].any()
+
+
+@pytest.mark.parametrize("kernel", ["serial", "streamed"])
+def test_spread_regime_over_two_partitions(kernel):
+    """Distinct costs and a large increment a placement: the cheapest
+    node is another one after nearly every job."""
+    lay = ResourceLayout()
+    rng = np.random.default_rng(3)
+    N, J = 32, 64
+    total = np.tile(lay.encode(cpu=64, is_capacity=True), (N, 1))
+    state = make_cluster_state(total.copy(), total, np.ones(N, bool),
+                               rng.random(N).astype(np.float32))
+    jpart = rng.integers(0, 2, J)
+    jobs = JobBatch(
+        req=jnp.asarray(np.tile(lay.encode(cpu=4), (J, 1))),
+        node_num=jnp.asarray(rng.integers(1, 3, J), jnp.int32),
+        time_limit=jnp.full(J, 36000, jnp.int32),
+        part_mask=jnp.asarray(jpart[:, None] == (np.arange(N) % 2)[None, :]),
+        valid=jnp.ones(J, bool))
+    got = _assert_kernel_bit_identical(state, jobs, 2, kernel)
+    assert np.asarray(got.placed).all()
+    # spread: no node of a partition is taken twice before all were once
+    first = np.asarray(got.nodes)[:, 0]
+    for part in (0, 1):
+        firsts = first[jpart == part][:8]
+        assert len(set(firsts.tolist())) == len(firsts)
 
 
 def test_classes_from_part_mask_roundtrip():

@@ -128,23 +128,35 @@ def test_suspend_resume_real_process(plane):
     d = add_craned("rn04")
     assert wait_for(lambda: d.state == CranedState.READY)
     stamp = tmp_path / "stamp.txt"
+    writes = 15                     # one every 0.2 s: three seconds of them
     jid = sched.submit(JobSpec(
         res=ResourceSpec(cpu=1.0),
-        script=f"for i in 1 2 3 4 5; do date +%s%N >> {stamp}; "
+        script=f"for i in $(seq {writes}); do date +%s%N >> {stamp}; "
                "sleep 0.2; done"), now=time.time())
+
+    def size():
+        return stamp.stat().st_size if stamp.exists() else 0
+
     assert wait_for(
         lambda: sched.job_info(jid).status == JobStatus.RUNNING)
-    time.sleep(0.3)
+    assert wait_for(lambda: size() > 0)
+    line = size()                   # bytes a write (fixed-width stamps)
     sched.suspend(jid, now=time.time())
-    size_at_suspend = stamp.stat().st_size if stamp.exists() else 0
+    # the stop travels ctld -> craned -> SIGSTOP on the group, so a write
+    # or two may land after suspend() has returned: the size the test
+    # holds the process to is the one read once the stop has landed
+    time.sleep(0.6)
+    size_at_suspend = size()
     time.sleep(1.0)
-    # frozen: no new writes while suspended (SIGSTOP on the group)
-    size_after_wait = stamp.stat().st_size if stamp.exists() else 0
-    assert size_after_wait == size_at_suspend
+    # frozen: no new writes while suspended (five write periods), and not
+    # because the script had run out of writes
+    assert size() == size_at_suspend
+    assert size_at_suspend < writes * line
+    assert sched.job_info(jid).status == JobStatus.SUSPENDED
     sched.resume(jid, now=time.time())
     assert wait_for(
         lambda: sched.job_info(jid).status == JobStatus.COMPLETED)
-    assert stamp.stat().st_size > size_at_suspend
+    assert size() == writes * line  # it went on where it had stopped
 
 
 def test_two_craneds_gang_job(plane):

@@ -115,6 +115,58 @@ def test_token_gates_everything(service):
         rogue.fence("f", 0, 1)
 
 
+def test_member_that_dials_first_waits_for_the_service():
+    """Rank 0's supervisor hosts the service, and a member on another
+    node can reach its fence before that supervisor has bound the port:
+    the call waits for the listener, it does not fail on the refused
+    connection (which made a gang's outcome a race between its nodes)."""
+    import socket
+    with socket.socket() as probe:
+        probe.bind(("127.0.0.1", 0))
+        port = probe.getsockname()[1]
+    early = RendezvousClient(f"127.0.0.1:{port}", token="t")
+    out = []
+    t = threading.Thread(target=lambda: out.append(
+        early.fence("ready", 1, 2, data=b"r1", timeout=30)))
+    t.start()
+    time.sleep(0.5)                 # the dial has been refused by now
+    assert t.is_alive() and not out
+    server = RendezvousServer(token="t", nranks=2)
+    server.start(f"127.0.0.1:{port}")
+    late = RendezvousClient(f"127.0.0.1:{port}", token="t")
+    try:
+        assert late.fence("ready", 0, 2, data=b"r0",
+                          timeout=30) == [b"r0", b"r1"]
+        t.join(timeout=30)
+        assert out == [[b"r0", b"r1"]]
+    finally:
+        early.close()
+        late.close()
+        server.stop()
+
+
+def _job_id_with_its_own_port(dispatcher) -> int:
+    """A job id whose gang port differs from that of every low id a
+    fresh scheduler hands out, and is free on this host now."""
+    import socket
+
+    def port_of(job_id):
+        return int(dispatcher._gang_ctx(
+            job_id, [0], 0)["rendezvous"].rsplit(":", 1)[1])
+    low = {port_of(j) for j in range(1, 513)}
+    for job_id in range(700_001, 700_513):
+        port = port_of(job_id)
+        if port in low:
+            continue
+        with socket.socket() as probe:     # no SO_REUSEPORT: held = refused
+            try:
+                probe.bind(("0.0.0.0", port))
+            except OSError:
+                continue
+        return job_id
+    raise AssertionError("no free gang port among 512 job ids")
+
+
 def test_real_gang_cross_node_fence(tmp_path):
     """Two craneds, one node_num=2 gang job: each member publishes its
     rank through the coord CLI and blocks on a fence — the job can
@@ -138,10 +190,19 @@ def test_real_gang_cross_node_fence(tmp_path):
     dispatcher.wire(sched)
     server, port = serve(sched, cycle_interval=0.15,
                          dispatcher=dispatcher)
-    # node names that resolve on this host (/etc/hosts loopback
-    # aliases): the gang's rendezvous address is "<rank0-name>:port"
+    # The gang's rendezvous address is "<rank0-name>:<port hashed from
+    # the job id>", and rank 0 serves it on every interface of this
+    # host.  Both parts are made this test's own: node names that
+    # resolve on any host (loopback literals, not names some
+    # /etc/hosts happens to carry), and a job id whose port no other
+    # scheduler on this host hands out — a gang of job 1 in another
+    # xdist worker (tests/test_real_node_plane.py) would share port and
+    # host with this one (gRPC binds SO_REUSEPORT), and a member's
+    # fence then reaches the other gang's service: "bad rendezvous
+    # token", exit 9.
+    sched._next_job_id = _job_id_with_its_own_port(dispatcher)
     daemons = []
-    for name in ("runsc", "vm"):
+    for name in ("127.0.0.1", "127.0.0.2"):
         d = CranedDaemon(name, f"127.0.0.1:{port}", cpu=4.0,
                          mem_bytes=4 << 30, workdir=str(tmp_path),
                          ping_interval=0.5,
@@ -161,13 +222,15 @@ def test_real_gang_cross_node_fence(tmp_path):
             f"exec > {tmp_path}/gang_rank_$CRANE_NODE_RANK.log 2>&1\n"
             "echo rank=$CRANE_NODE_RANK rdzv=$CRANE_RENDEZVOUS\n"
             "python -m cranesched_tpu.coord fence ready "
-            "--data r$CRANE_NODE_RANK --timeout 30 || exit 9\n"
+            "--data r$CRANE_NODE_RANK --timeout 90 || exit 9\n"
             "echo fenced-$CRANE_NODE_RANK\n")
         jid = sched.submit(JobSpec(
             res=ResourceSpec(cpu=1.0), node_num=2,
-            script=script, time_limit=90), now=time.time())
+            script=script, time_limit=300), now=time.time())
         assert jid > 0
-        deadline = time.time() + 45
+        # room for two interpreters to start beside five busy xdist
+        # workers; a run that passes waits for none of it
+        deadline = time.time() + 150
         while time.time() < deadline:
             j = sched.job_info(jid)
             if j is not None and j.status.is_terminal:

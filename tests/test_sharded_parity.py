@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from cranesched_tpu.models.pallas_solver import plan_streams
 from cranesched_tpu.models.solver import (
     JobBatch,
     make_cluster_state,
@@ -20,6 +21,7 @@ from cranesched_tpu.parallel import (
     make_node_mesh,
     shard_cluster_state,
     solve_greedy_sharded,
+    solve_greedy_sharded_classes,
 )
 
 
@@ -145,3 +147,41 @@ def test_sharded_second_cycle_reuses_sharded_state():
     p_ref2, s_ref2 = solve_greedy(s_ref, jobs2, max_nodes=2)
     p_sh2, s_sh2 = solve_greedy_sharded(s_sh, jobs2, mesh, max_nodes=2)
     _assert_same(p_ref2, s_ref2, p_sh2, s_sh2)
+
+
+def _class_problem(seed, num_jobs, num_nodes, num_classes, max_nodes,
+                   disjoint):
+    """A scheduling problem with eligibility FACTORED into a class table
+    (the form ``_solve_sharded`` serves a FactoredJobBatch with):
+    (state, the factored arguments, the same batch with dense rows)."""
+    rng = np.random.default_rng(seed)
+    state, jobs = _random_problem(rng, num_jobs, num_nodes, max_nodes)
+    job_class = rng.integers(0, num_classes,
+                             size=num_jobs).astype(np.int32)
+    if disjoint:
+        owner = rng.integers(0, num_classes, size=num_nodes)
+        class_masks = np.stack([owner == c for c in range(num_classes)])
+    else:
+        class_masks = rng.random((num_classes, num_nodes)) > 0.25
+    factored = (jobs.req, jobs.node_num, jobs.time_limit, jobs.valid,
+                jnp.asarray(job_class), jnp.asarray(class_masks))
+    return state, factored, jobs.replace(
+        part_mask=jnp.asarray(class_masks[job_class]))
+
+
+@pytest.mark.parametrize("disjoint", [False, True],
+                         ids=["overlapping", "disjoint"])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_sharded_classes_matches_single_device(seed, disjoint):
+    """Overlapping tables run the scan in serial order over the mesh,
+    disjoint ones the S-stream scan: either way solve_greedy's answer."""
+    state, factored, jobs = _class_problem(
+        seed, num_jobs=48, num_nodes=32, num_classes=3, max_nodes=4,
+        disjoint=disjoint)
+    mesh = make_node_mesh()
+    assert (plan_streams(factored[4], factored[5], block_jobs=1)
+            is not None) == disjoint
+    p_ref, s_ref = solve_greedy(state, jobs, max_nodes=4)
+    p_sh, s_sh = solve_greedy_sharded_classes(
+        shard_cluster_state(state, mesh), *factored, mesh, max_nodes=4)
+    _assert_same(p_ref, s_ref, p_sh, s_sh)

@@ -1,7 +1,7 @@
 """Start-up rules: one process touches the chip, nothing falls back.
 
 * a start that expects a TPU and finds none exits non-zero with the
-  pre-flight report (ctld_main and bench.py alike);
+  pre-flight report;
 * ``JAX_PLATFORMS=cpu`` set explicitly boots, and banner and QueryStats
   say ``cpu``;
 * the compile cache lives where ``JAX_COMPILATION_CACHE_DIR`` says, with
@@ -68,16 +68,6 @@ def test_ctld_expecting_tpu_without_one_exits_nonzero(tmp_path):
     assert "pre-flight report" in out.stderr
     assert '"expected_platform": "tpu"' in out.stderr
     assert '"libtpu_path"' in out.stderr and '"chips"' in out.stderr
-
-
-def test_bench_expecting_tpu_without_one_exits_nonzero(tmp_path):
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=120, cwd=str(tmp_path),
-        env=_env(JAX_PLATFORMS=None))
-    assert out.returncode != 0
-    assert out.stdout.strip() == ""       # no number without a device
-    assert "pre-flight report" in out.stderr
 
 
 def test_explicit_cpu_boots_and_says_cpu(tmp_path):

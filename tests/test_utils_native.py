@@ -129,6 +129,27 @@ def test_load_example_config_and_build():
     assert sched.config.backfill
 
 
+@pytest.mark.parametrize("key,value", [
+    ("Incremental", "false"), ("ResidentState", "false"),
+    ("MaxStreams", "2"), ("BlockJobs", "128")])
+def test_removed_scheduler_key_is_refused_by_name(tmp_path, key, value):
+    """A site's stale key must not silently change meaning: the file is
+    refused, and the message says which key to take out."""
+    cfg = tmp_path / "config.yaml"
+    cfg.write_text(f"""
+ClusterName: t
+Partitions: [{{name: default}}]
+Scheduler:
+  Backfill: true
+  {key}: {value}
+""")
+    with pytest.raises(ValueError, match=rf"Scheduler: {key}\b"):
+        load_config(str(cfg))
+    # the same file without the key loads
+    cfg.write_text(cfg.read_text().replace(f"  {key}: {value}\n", ""))
+    assert load_config(str(cfg)).scheduler == {"Backfill": True}
+
+
 # ---------------- daemon entry points ----------------
 
 def test_ctld_main_and_craned_main_end_to_end(tmp_path):
