@@ -24,6 +24,18 @@ Rows live in insertion order (append-only with tombstones, compacted
 in-order when mostly dead), which preserves the dict-iteration candidate
 order of the old Python loop exactly — required for bit-exact parity
 with the from-scratch rebuild path (tests/test_delta_cycle.py).
+
+A row's index is stable until a compaction moves it; ``generation``
+counts compactions, so whoever kept row indices across a lock release
+(the cycle, over its solves) can tell whether they still hold.
+
+``stamped`` remembers, per row, the solver reason code the commit last
+wrote on that row's job (-1: unknown), so the commit visits in Python
+only the rows a cycle placed or whose reason changed.  The invariant:
+``stamped[row] != STAMP_NONE`` implies the job's ``pending_reason`` is
+what the commit wrote for that code.  Every event that rewrites a row
+(``upsert``), every gate that flips (``candidates``) and every other
+writer of a pending job's reason (``forget``) resets it to unknown.
 """
 
 from __future__ import annotations
@@ -42,6 +54,15 @@ GATE_DEP = 3
 GATE_DEP_NEVER = 4
 GATE_LICENSE = 5
 
+# ``stamped``: no reason the commit wrote is known to stand on the job
+STAMP_NONE = -1
+
+# every per-row column: what a grow copies and a compaction moves
+_COLUMNS = ("job_id", "live", "template", "held", "begin", "dep",
+            "dep_never", "lic", "gate", "stamped", "submit", "qos", "part",
+            "nnum", "cpus", "mem", "acct", "tlimit", "packed", "req", "cls",
+            "cls_gen")
+
 
 class PendingTable:
     """SoA mirror of ``scheduler.pending`` (non-terminal rows only).
@@ -58,6 +79,9 @@ class PendingTable:
         #: rows dirtied since the last candidates() call (trace column)
         self.last_dirty = 0
         self._dirty = 0
+        #: bumped whenever rows MOVE (a compaction): row indices taken
+        #: under an older generation no longer name the same jobs
+        self.generation = 0
         self._row: dict[int, int] = {}     # job_id -> row index
         self._n = 0                        # rows used, incl. tombstones
         self._dead = 0
@@ -76,6 +100,8 @@ class PendingTable:
         self.dep_never = np.zeros(cap, bool)
         self.lic = np.zeros(cap, np.int32)        # license-set id
         self.gate = np.full(cap, GATE_NONE, np.int8)
+        # solver reason code the commit last wrote on the row's job
+        self.stamped = np.full(cap, STAMP_NONE, np.int8)
         # priority-row attributes (gathered by _priority_sort)
         self.submit = np.zeros(cap, np.float64)
         self.qos = np.zeros(cap, np.int32)
@@ -102,16 +128,13 @@ class PendingTable:
     def _grow(self) -> None:
         old, cap = self._n, len(self.job_id)
         new_cap = cap * 2
-        for name in ("job_id", "live", "template", "held", "begin",
-                     "dep", "dep_never", "lic", "gate", "submit", "qos",
-                     "part", "nnum", "cpus", "mem", "acct", "tlimit",
-                     "packed", "req", "cls", "cls_gen"):
+        for name in _COLUMNS:
             col = getattr(self, name)
             shape = (new_cap,) + col.shape[1:]
             fresh = np.zeros(shape, col.dtype)
             if name == "gate":
                 fresh[:] = GATE_NONE
-            elif name == "cls_gen":
+            elif name in ("cls_gen", "stamped"):
                 fresh[:] = -1
             elif name in ("begin", "dep"):
                 fresh[:] = -np.inf
@@ -149,6 +172,7 @@ class PendingTable:
         self.dep_never[row] = dep_never
         self.lic[row] = lic
         self.gate[row] = GATE_NONE       # force one reason rewrite
+        self.stamped[row] = STAMP_NONE   # ... by the commit too
         self.submit[row] = submit
         self.qos[row] = qos
         self.part[row] = part
@@ -174,18 +198,23 @@ class PendingTable:
         if self._dead > 64 and self._dead * 2 > self._n:
             self._compact()
 
+    def forget(self, job_id: int) -> None:
+        """A pending job's reason was written by someone other than the
+        commit: its row's stamp no longer says what the job carries."""
+        row = self._row.get(job_id)
+        if row is not None:
+            self.stamped[row] = STAMP_NONE
+
     def _compact(self) -> None:
         """Drop tombstones, preserving insertion order."""
         keep = np.nonzero(self.live[:self._n])[0]
         k = len(keep)
-        for name in ("job_id", "live", "template", "held", "begin",
-                     "dep", "dep_never", "lic", "gate", "submit", "qos",
-                     "part", "nnum", "cpus", "mem", "acct", "tlimit",
-                     "packed", "req", "cls", "cls_gen"):
+        for name in _COLUMNS:
             col = getattr(self, name)
             col[:k] = col[keep]
         self._n = k
         self._dead = 0
+        self.generation += 1
         self._row = {int(j): i for i, j in enumerate(self.job_id[:k])}
 
     # ---- per-cycle vectorized evaluation ----
@@ -226,6 +255,9 @@ class PendingTable:
         vis = self.live[:n] & ~self.template[:n]
         changed = np.nonzero(vis & (gate != self.gate[:n]))[0]
         self.gate[:n] = np.where(vis, gate, self.gate[:n])
+        # a flipped gate has its reason written by the caller (or, for a
+        # row that turned candidate, left stale for the commit to fix)
+        self.stamped[changed] = STAMP_NONE
         cand = np.nonzero(vis & (gate == GATE_CANDIDATE))[0]
         return cand, changed, gate[changed]
 

@@ -49,6 +49,7 @@ from cranesched_tpu.ctld.pending_table import (
     GATE_DEP_NEVER,
     GATE_HELD,
     GATE_LICENSE,
+    STAMP_NONE,
     PendingTable,
 )
 from cranesched_tpu.ctld.resident import ResidentClusterState
@@ -520,6 +521,9 @@ class JobScheduler:
         # candidates/ordered lists (the vectorized row-build gathers)
         self._cand_rows: np.ndarray | None = None
         self._ordered_rows: np.ndarray | None = None
+        # PendingTable.generation those rows were taken under: a
+        # compaction since (a cancel while a solve ran) moved them
+        self._rows_gen = -1
         # running-set priority attrs: rebuilt only when running-set
         # MEMBERSHIP changes (the dict hooks bump _run_epoch on
         # start/finish/requeue) — per cycle only run_time is recomputed
@@ -850,6 +854,16 @@ class JobScheduler:
         if job.job_id in self.pending:
             self._table_upsert(job)
             self._kick()
+
+    def _set_reason(self, job: Job, reason: PendingReason) -> None:
+        """Write a PENDING job's reason from outside ``_commit``'s
+        stamped pass, and forget the row's stamp so that the next commit
+        visits it (PendingTable ``stamped``).  The other writers need no
+        such call: the gate reasons ride ``candidates()``' own reset,
+        the batch cut resets its rows in one slice, and hold / modify /
+        requeue / eviction re-upsert the row they wrote."""
+        job.pending_reason = reason
+        self._ptable.forget(job.job_id)
 
     def _cycle_fingerprint(self) -> tuple:
         """Everything a zero-placement solve's outcome depends on, as
@@ -2431,6 +2445,7 @@ class JobScheduler:
                 job.pending_reason = PendingReason.PRIORITY
             candidates = candidates[:limit]
             if self._cand_rows is not None:
+                self._ptable.stamped[self._cand_rows[limit:]] = STAMP_NONE
                 self._cand_rows = self._cand_rows[:limit]
 
         # snapshot + event capture window (cpp:1437)
@@ -2457,7 +2472,9 @@ class JobScheduler:
         # full-fidelity packed solver (immediate-fit; such jobs don't get
         # backfill reservations this round)
         orows = self._ordered_rows
-        if orows is not None and len(orows) == len(ordered):
+        if orows is not None and len(orows) != len(ordered):
+            orows = None
+        if orows is not None:
             packed = bool(self._ptable.packed[orows].any())
         else:
             packed = any(j.spec.exclusive or j.spec.task_res is not None
@@ -2472,7 +2489,8 @@ class JobScheduler:
                 "packed", lambda: solve_packed(
                     state, pbatch, max_nodes=max_nodes)[0])
             started = self._commit(ordered, placements, now,
-                                   tasks=np.asarray(placements.tasks))
+                                   tasks=np.asarray(placements.tasks),
+                                   rows=orows)
             started += self._try_preemption(ordered, now)
             clock.mark("wal")
             self._wal_flush()
@@ -2487,7 +2505,7 @@ class JobScheduler:
             self._update_topo_fragmentation(topo, avail, total, alive)
         if topo is not None and (
                 bool((self._ptable.nnum[orows] > 1).any())
-                if orows is not None and len(orows) == len(ordered)
+                if orows is not None
                 else any(j.spec.node_num > 1 for j in ordered)):
             # gang cycle with a topology configured: route through the
             # best-fit-block solve (topo/place.py).  Backfill is skipped
@@ -2504,7 +2522,7 @@ class JobScheduler:
                 "topo", lambda: solve_greedy_topo(
                     state, dense, levels, max_nodes=max_nodes))
             self._note_topo(topo, ordered, topo_info)
-            started = self._commit(ordered, placements, now)
+            started = self._commit(ordered, placements, now, rows=orows)
             started += self._try_preemption(ordered, now)
             clock.mark("wal")
             self._wal_flush()
@@ -2518,8 +2536,8 @@ class JobScheduler:
             bf_max = max(1, self.config.backfill_max_jobs)
             if len(ordered) > bf_max:
                 started = yield from self._split_backfill_phases(
-                    ordered, jobs_batch, avail, total, alive, cost0,
-                    max_nodes, now)
+                    ordered, orows, jobs_batch, avail, total, alive,
+                    cost0, max_nodes, now)
                 started += self._try_preemption(ordered, now)
                 clock.mark("wal")
                 self._wal_flush()
@@ -2546,7 +2564,8 @@ class JobScheduler:
                     resident_ok=True))
             start_buckets = None
 
-        started = self._commit(ordered, placements, now, start_buckets)
+        started = self._commit(ordered, placements, now, start_buckets,
+                               rows=orows)
         started += self._try_preemption(ordered, now)
         clock.mark("wal")
         self._wal_flush()
@@ -2707,8 +2726,8 @@ class JobScheduler:
                 if in_b[i] and blocks[i] >= 0
                 else ("spanning" if crs[i] else ""))
 
-    def _split_backfill_phases(self, ordered, jobs_batch, avail, total,
-                               alive, cost0, max_nodes, now):
+    def _split_backfill_phases(self, ordered, orows, jobs_batch, avail,
+                               total, alive, cost0, max_nodes, now):
         """Bounded backfill lookahead (Slurm's sched/bf split): the
         timed solve with full reservation semantics covers only the top
         ``backfill_max_jobs`` priority jobs; the tail is placed by the
@@ -2718,6 +2737,10 @@ class JobScheduler:
         strictly conservative, like the rest of the grid design)."""
         bf_max = max(1, self.config.backfill_max_jobs)
         head, tail = ordered[:bf_max], ordered[bf_max:]
+        # ``orows``: the table rows of ``ordered`` (or None), cut like it
+        # for the two commits' visit masks
+        head_rows, tail_rows = ((orows[:bf_max], orows[bf_max:])
+                                if orows is not None else (None, None))
 
         # slice the already-built batch — rebuilding it would pay the
         # prelude twice per cycle in exactly the regime this split
@@ -2746,7 +2769,8 @@ class JobScheduler:
         head_start = np.asarray(placements.start_bucket)
         self._cur_trace["backfilled"] = int(np.sum(
             np.asarray(placements.placed) & (head_start > 0)))
-        started = self._commit(head, placements, now, head_start)
+        started = self._commit(head, placements, now, head_start,
+                               rows=head_rows)
 
         # pass 2: the tail against the tightest bucket of the horizon
         clock = self.cycle_clock
@@ -2766,7 +2790,7 @@ class JobScheduler:
             placed=placements2.placed[bf_max:],
             nodes=placements2.nodes[bf_max:],
             reason=placements2.reason[bf_max:])
-        started += self._commit(tail, tail_placements, now)
+        started += self._commit(tail, tail_placements, now, rows=tail_rows)
         return started
 
     def _solve_phase(self, backend, fn):
@@ -2851,6 +2875,8 @@ class JobScheduler:
         solve_ms = float(self._cur_trace.get("solve_ms", 0.0))
         tail_passes = self._cur_trace.pop("_tail_passes", 0)
         tail_bound = self._cur_trace.pop("_tail_bound", 0)
+        commit_visited = self._cur_trace.pop("_commit_visited", 0)
+        commit_scan_s = self._cur_trace.pop("_commit_scan_s", 0.0)
         # commit = everything after the prelude that ran under the
         # lock, i.e. total minus prelude minus the lock-released solves.
         # Dispatch is NOT in here: the ring drains post-lock and its
@@ -2897,6 +2923,13 @@ class JobScheduler:
             # lower would take for the best value)
             tail_pass_pct=round(100.0 * tail_passes / tail_bound, 3)
             if tail_bound else 100.0,
+            # the rows the cycle's commits visited in Python (placed, or
+            # told another reason than the one they carried) over its
+            # candidates, and the part of commit_apply_ms spent on the
+            # array pulls and that visit
+            commit_visited_pct=round(
+                100.0 * commit_visited / len(candidates), 3),
+            commit_scan_ms=round(commit_scan_s * 1e3, 3),
             placed=len(started),
             dirty_jobs=self._ptable.last_dirty,
             dirty_nodes=self.meta.last_snapshot_dirty,
@@ -3468,7 +3501,7 @@ class JobScheduler:
                     for victim_id in evict_ids:
                         self._deferred_evictions[victim_id] = (
                             due, job.job_id)
-                    job.pending_reason = PendingReason.PRIORITY
+                    self._set_reason(job, PendingReason.PRIORITY)
                 continue
             if self._commit_preemption(job, chosen, evict_ids,
                                        layouts[i], now):
@@ -3492,11 +3525,11 @@ class JobScheduler:
             return False
         if job.spec.licenses and not self.licenses.malloc(
                 job.spec.licenses):
-            job.pending_reason = PendingReason.LICENSE
+            self._set_reason(job, PendingReason.LICENSE)
             return False
         if not self._malloc_run_limits(job):
             self.licenses.free(job.spec.licenses or {})
-            job.pending_reason = PendingReason.QOS_LIMIT
+            self._set_reason(job, PendingReason.QOS_LIMIT)
             return False
 
         for victim_id in evict_ids:
@@ -3512,7 +3545,7 @@ class JobScheduler:
             job.node_ids = []
             job.task_layout = []
             job.alloc_cache = None
-            job.pending_reason = PendingReason.RESOURCE
+            self._set_reason(job, PendingReason.RESOURCE)
             return False
         del self.pending[job.job_id]
         job.status = JobStatus.RUNNING
@@ -3635,6 +3668,7 @@ class JobScheduler:
                 continue
             job.pending_reason = _GATE_REASON[gate]
         self._cand_rows = cand_rows
+        self._rows_gen = pt.generation
         return [pending[int(j)] for j in jid[cand_rows]]
 
     def _pending_candidates_rebuild(self, now: float) -> list[Job]:
@@ -4027,7 +4061,8 @@ class JobScheduler:
         return batch, max_nodes
 
     def _commit(self, ordered: list[Job], placements: Placements,
-                now: float, start_buckets=None, tasks=None) -> list[int]:
+                now: float, start_buckets=None, tasks=None,
+                rows=None) -> list[int]:
         """Host authoritative commit + dispatch (cpp:1557-1839): re-check
         against the live ledger and the cycle's reduce events; jobs whose
         nodes died mid-cycle simply stay pending for the next cycle.
@@ -4037,16 +4072,31 @@ class JobScheduler:
         (the reference's flow at cpp:6795-6835) — only bucket-0 starts
         dispatch.
 
-        The commit scales with BATCHES, not jobs: admission checks that
-        are pure array functions (placed/reason rows, the mid-cycle
-        dirty-node flag) run as one vectorized pre-pass; the per-job
-        loop keeps only what must stay per-job (pending membership,
-        spec-epoch void, license/QoS takes with their undo ordering);
-        the ledger commit goes through meta.malloc_resource_batch +
-        _ledger_add_batch over the whole placed set; WAL ``start``
-        records land in the cycle's open group (one fsync for all);
-        dispatch is QUEUED on the ring and issued post-lock, after the
-        group's durability barrier."""
+        The commit scales with the rows a cycle PLACED or whose reason
+        CHANGED, not with its candidates.  ``rows`` are the PendingTable
+        rows of ``ordered``: an unplaced row whose job already carries
+        the reason the solve returns for it (``stamped``) is not visited
+        in Python at all, since a visit would rewrite the value already
+        there or skip the row as void.  Every row that is visited goes
+        through the whole body below.  Without a row map that can be
+        trusted (none given, another length, a compaction since the
+        rows were taken) every row is visited and every stamp
+        forgotten.  The stamps are written BEFORE the started jobs
+        leave ``pending``: each removal can compact the table and move
+        the rows.  ``commit_visited_pct`` and ``commit_scan_ms`` (cycle
+        trace) say what the pass cost.
+
+        Admission checks that are pure array functions (placed/reason
+        rows, the mid-cycle dirty-node flag) run as one vectorized
+        pre-pass; the per-job loop keeps only what must stay per-job
+        (pending membership, spec-epoch void, license/QoS takes with
+        their undo ordering); the ledger commit goes through
+        meta.malloc_resource_batch + _ledger_add_batch over the whole
+        placed set; WAL ``start`` records land in the cycle's open
+        group (one fsync for all); dispatch is QUEUED on the ring and
+        issued post-lock, after the group's durability barrier."""
+        import time as _time
+        t_scan = _time.perf_counter()
         events = self.meta.stop_logging()
         dirty_nodes = {ev.node_id for ev in events}
 
@@ -4073,7 +4123,23 @@ class JobScheduler:
         # them to the resident state so it force-patches them next cycle
         rejected_rows: list[int] = []
         future_start: list[tuple[Job, list[int]]] = []
-        for i, job in enumerate(ordered):
+        n = len(ordered)
+        pt = self._ptable
+        if (rows is not None and len(rows) == n
+                and self._rows_gen == pt.generation):
+            took, why = placed[:n], reasons[:n]
+            visit = np.flatnonzero(
+                took | (why != pt.stamped[rows])).tolist()
+            # an unplaced row is told ``why`` below (a row not visited
+            # carries it already); a placed one starts, or is refused
+            # with a reason of the host's
+            pt.stamped[rows] = np.where(took, STAMP_NONE, why)
+        else:
+            rows = None
+            visit = range(n)
+            pt.stamped.fill(STAMP_NONE)
+        for i in visit:
+            job = ordered[i]
             if (job.job_id not in self.pending or job.held
                     or job.spec is not getattr(job, "_plan_spec",
                                                job.spec)):
@@ -4084,6 +4150,10 @@ class JobScheduler:
                 # next cycle, which sees the new spec.
                 if placed[i]:
                     rejected_rows.append(i)
+                elif rows is not None:
+                    # not told: the hold's or the modify's own reason
+                    # stands on the job
+                    pt.stamped[rows[i]] = STAMP_NONE
                 continue
             if not placed[i]:
                 job.pending_reason = _REASON_MAP.get(
@@ -4121,6 +4191,10 @@ class JobScheduler:
                                if tasks is not None else [])
             admitted.append(job)
             admitted_rows.append(i)
+        cur = self._cur_trace
+        cur["_commit_visited"] = cur.get("_commit_visited", 0) + len(visit)
+        cur["_commit_scan_s"] = (cur.get("_commit_scan_s", 0.0)
+                                 + _time.perf_counter() - t_scan)
         # batched ledger commit: ONE meta call checks and subtracts the
         # whole placed set in admission order (each entry sees earlier
         # subtractions exactly as per-job malloc_resource calls would)
@@ -4317,6 +4391,7 @@ class JobScheduler:
         self._noop_fp = None
         self._cand_rows = None
         self._ordered_rows = None
+        self._rows_gen = -1
         self._run_attrs = None
         # the resident ClusterState mirrors the OLD leader's ledger —
         # drop it; the first cycle pays one full rebuild

@@ -47,6 +47,17 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
                              pass was left out)
     decisions_per_s  float   candidates / solve_ms: BASELINE's
                              yardstick as the served path pays it
+    commit_visited_pct float 100 * rows the cycle's commits visited in
+                             Python / candidates (head + tail on the
+                             split route): the rows the solve placed
+                             (a backfill reservation counts) or told
+                             another reason than the one last stamped
+                             on them (ctld/pending_table.py stamped);
+                             100.0 is the pass over every candidate
+    commit_scan_ms   float   the part of commit_apply_ms in _commit's
+                             array pulls and that visit; the rest is
+                             the ledger batch, WAL records and the
+                             dispatch queue of the jobs that start
     placed           int     jobs started (incl. backfill tail)
     preempted        int     victims killed by this cycle
     backfilled       int     placed with start_bucket > 0 (future start)

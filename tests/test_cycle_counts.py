@@ -12,6 +12,7 @@ unseen."""
 
 import pytest
 
+from cranesched_tpu.craned import SimCluster
 from cranesched_tpu.ctld import (
     JobScheduler,
     JobSpec,
@@ -51,6 +52,16 @@ ROUTES = {
 }
 
 
+def _meta():
+    meta = MetaContainer()
+    for i in range(NODES):
+        meta.add_node(f"cn{i}", meta.layout.encode(
+            cpu=CPU, mem_bytes=64 << 30, memsw_bytes=64 << 30,
+            is_capacity=True), partitions=("default",))
+        meta.craned_up(i)
+    return meta
+
+
 def _drive(sched, wal, now):
     """``schedule_cycle``, with the WAL looked at wherever the server
     would release its lock: at each yielded closure no group is open and
@@ -73,12 +84,7 @@ def _drive(sched, wal, now):
 @pytest.mark.parametrize("route", sorted(ROUTES))
 def test_wal_counts_of_a_cycle(tmp_path, route, places):
     config, spec, count, label, solves, groups = ROUTES[route]
-    meta = MetaContainer()
-    for i in range(NODES):
-        meta.add_node(f"cn{i}", meta.layout.encode(
-            cpu=CPU, mem_bytes=64 << 30, memsw_bytes=64 << 30,
-            is_capacity=True), partitions=("default",))
-        meta.craned_up(i)
+    meta = _meta()
     if route == "topo":
         meta.set_topology(Topology.uniform_blocks(NODES, 4))
     wal = WriteAheadLog(str(tmp_path / "ctld.wal"))
@@ -112,3 +118,91 @@ def test_wal_counts_of_a_cycle(tmp_path, route, places):
     assert wal._group_depth == 0 and not wal._group_buf
     assert wal.durable_seq == wal.seq
     wal.close()
+
+
+# ---------------------------------------------------------------------------
+# what a commit visits in Python (ISSUE 34): the rows the cycle placed or
+# whose reason changed, never again a standing row that is told the same
+# ---------------------------------------------------------------------------
+
+VISIT_ROUTES = {
+    "immediate": dict(backfill=False),
+    # a head of ONE job: it holds a reservation (``backfilled`` 1), which
+    # counts as placed in every cycle; the backlog goes through the tail
+    "backfill-split": dict(backfill=True, backfill_max_jobs=1),
+}
+
+
+def _visit_cluster(route):
+    sched = JobScheduler(_meta(), SchedulerConfig(**VISIT_ROUTES[route]))
+    sim = SimCluster(sched)
+    sim.wire(sched)
+    return sched, sim
+
+
+def _visited(sched, sim, now):
+    """One cycle: (jobs started, rows its commits visited, candidates,
+    reservations the head holds)."""
+    sim.advance_to(now)
+    started = sched.schedule_cycle(now=now)
+    row = sched.cycle_trace.snapshot()[-1]
+    assert row["now"] == now, "the cycle short-circuited"
+    assert row["commit_scan_ms"] >= 0.0
+    visited = row["commit_visited_pct"] * row["candidates"] / 100.0
+    assert visited == pytest.approx(round(visited), abs=1e-3)
+    return len(started), round(visited), row["candidates"], row["backfilled"]
+
+
+def _whole(sched, now, count, runtime=3600.0):
+    """``count`` jobs that each take a node whole."""
+    return [sched.submit(JobSpec(res=_res(CPU), time_limit=600,
+                                 sim_runtime=runtime), now=now)
+            for _ in range(count)]
+
+
+def _nudge(sched, now):
+    """A held job's arrival: no candidate, but an event, so that the next
+    cycle is a cycle and not a fingerprint skip."""
+    assert sched.submit(JobSpec(res=_res(1.0), held=True), now=now)
+
+
+@pytest.mark.parametrize("route", sorted(VISIT_ROUTES))
+def test_standing_backlog_is_visited_once(route):
+    sched, sim = _visit_cluster(route)
+    head = 1 if route == "backfill-split" else 0
+    _whole(sched, 0.0, NODES)
+    assert _visited(sched, sim, 1.0) == (NODES, NODES, NODES, 0)
+    _whole(sched, 1.0, 12)
+    # the first cycle tells every candidate its reason (commit_visited_pct
+    # 100) ...
+    assert _visited(sched, sim, 2.0) == (0, 12, 12, head)
+    # ... and the second nobody (0) but the head's reservation
+    _nudge(sched, 2.5)
+    assert _visited(sched, sim, 3.0) == (0, head, 12, head)
+
+
+@pytest.mark.parametrize("route", sorted(VISIT_ROUTES))
+def test_a_cycle_visits_what_it_places_and_what_arrived(route):
+    sched, sim = _visit_cluster(route)
+    head = 1 if route == "backfill-split" else 0
+    _whole(sched, 0.0, 2, runtime=1.5)          # gone by t = 2.5
+    _whole(sched, 0.0, NODES - 2)
+    assert _visited(sched, sim, 1.0)[0] == NODES
+    _whole(sched, 1.0, 12)
+    assert _visited(sched, sim, 2.0) == (0, 12, 12, head)
+    # m = 2 nodes come free and k = 3 jobs arrive: the cycle visits the
+    # m it places and the k it has not told yet (the head's job is one
+    # of the m, so nobody holds a reservation in this cycle)
+    _whole(sched, 2.5, 3)
+    assert _visited(sched, sim, 3.0) == (2, 2 + 3, 15, 0)
+    # what is left stands again
+    _nudge(sched, 3.5)
+    assert _visited(sched, sim, 4.0) == (0, head, 13, head)
+
+
+@pytest.mark.parametrize("route", sorted(VISIT_ROUTES))
+def test_a_flood_shaped_cycle_visits_what_it_places(route):
+    sched, sim = _visit_cluster(route)
+    for _ in range(16):
+        assert sched.submit(JobSpec(res=_res(2.0), time_limit=600), now=0.0)
+    assert _visited(sched, sim, 1.0) == (16, 16, 16, 0)

@@ -62,16 +62,24 @@ def _cluster(wal=None, **config):
     return meta, sched, cluster
 
 
-def _slow_solve(sched, entered=None):
+def _slow_solve(sched, entered=None, until=None, left=None):
     """A sleep INSIDE the immediate solve, i.e. inside a yielded closure:
-    cycles long enough that the loop's glue is a small share of them."""
+    cycles long enough that the loop's glue is a small share of them.
+    ``entered`` is set as the solve begins; it does not end before
+    ``until`` is set, and sets ``left`` as it ends."""
     inner = sched._immediate_solve
 
     def slow(*a, **kw):
         if entered is not None:
             entered.set()
         time.sleep(SOLVE_DELAY)
-        return inner(*a, **kw)
+        try:
+            return inner(*a, **kw)
+        finally:
+            if until is not None:
+                until.wait(10.0)
+            if left is not None:
+                left.set()
 
     sched._immediate_solve = slow
 
@@ -245,21 +253,28 @@ def test_idle_loop_wakes_for_the_sim_planes_next_completion():
 # ---------------------------------------------------------------------------
 
 def test_lock_wait_is_split_from_commit():
-    hold_s = 0.05
+    hold_s = 0.1
     meta, sched, cluster = _cluster(backfill=False)
-    entered = threading.Event()
-    _slow_solve(sched, entered)
+    entered, holding, left = (threading.Event() for _ in range(3))
+    _slow_solve(sched, entered, holding, left)
     server, client = _served(sched, cluster)
     held = []
 
     def handler():
         # what a submit handler does to the cycle: takes the lock while
         # the solve runs with it released, and still holds it when the
-        # closure ends and the cycle thread wants it back
+        # closure ends and the cycle thread wants it back.  The solve
+        # does not end before the lock is held here, and the hold ends
+        # ``hold_s`` after the solve does, however long the real solve
+        # took beyond its injected delay (a first call builds or loads
+        # the native library) and however late this thread woke: the
+        # wait is hold_s less a closure's tail
         assert entered.wait(10.0)
         with server._lock:
             t0 = time.perf_counter()
-            time.sleep(SOLVE_DELAY + hold_s)
+            holding.set()
+            assert left.wait(10.0)
+            time.sleep(hold_s)
             held.append(time.perf_counter() - t0)
 
     thread = threading.Thread(target=handler)
@@ -272,12 +287,13 @@ def test_lock_wait_is_split_from_commit():
         row = _closed_rows(sched, "native")[0]
     finally:
         server.stop()
-    assert held and held[0] >= SOLVE_DELAY + hold_s
+    assert held and held[0] >= hold_s
     assert row["lock_wait_ms"] >= 45.0
     assert row["lock_wait_max_ms"] >= 45.0
     assert row["lock_wait_max_ms"] <= row["lock_wait_ms"]
-    # the commit itself took what a one-job commit takes
-    assert row["commit_apply_ms"] < 20.0
+    # the commit itself took what a one-job commit takes: a small part
+    # of the wait it used to be booked with, whatever the machine's load
+    assert row["commit_apply_ms"] < 0.25 * row["lock_wait_ms"]
     assert row["lock_held_work_ms"] < row["lock_held_ms"] - 40.0
     # commit_ms and lock_held_ms keep their old arithmetic: the remainder
     # still books the wait (accepted metrics read them)
