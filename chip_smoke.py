@@ -59,7 +59,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 
 NUM_PARTITIONS = 4
-GANG_BOUNDS = (1, 2, 4, 8)      # _bucket(max node_num) under MaxNodesPerJob 8
+GANG_BOUNDS = (1, 2, 4, 8)      # _bucket(max node_num) under the default
+                                # MaxNodesPerJob 8 (16, 32, 64 compile for
+                                # a v5e in tests/test_pallas_lowering.py)
 BACKFILL_MAX_JOBS = 1024        # SchedulerConfig default: head of a split cycle
 WALL_LIMIT_S = 1150             # the contract allows 1200
 

@@ -995,6 +995,9 @@ def cmd_cstats(args) -> int:
                  # the Pallas kernel ran
                  t.get("gang_bound", "-"), t.get("tail_pass_pct", "-"),
                  t.get("placed"),
+                 # NODES: the nodes of the jobs the cycle started and
+                 # of the head's reservations
+                 t.get("nodes_selected", "-"),
                  t.get("backfilled"), t.get("preempted"),
                  # SKIP: coalesced short-circuit count (+ reason);
                  # DIRTY: jobs/nodes patched since the last cycle
@@ -1015,7 +1018,7 @@ def cmd_cstats(args) -> int:
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
             "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "K", "PASS%", "PLACED",
-            "BACKFILL", "PREEMPT", "SKIP", "DIRTY", "PRELUDE_MS",
+            "NODES", "BACKFILL", "PREEMPT", "SKIP", "DIRTY", "PRELUDE_MS",
             "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS", "LOCK_MS",
             "TOTAL_MS", "LOCK_WAIT_MS", "PERIOD_MS", "FSYNC", "FRAG")))
         return 0

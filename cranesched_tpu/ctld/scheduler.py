@@ -25,6 +25,7 @@ import collections
 import dataclasses
 from typing import Callable, Iterable
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -142,6 +143,35 @@ _MET_OVERLAP = _OBS.gauge(
     "crane_resident_patch_overlap_share",
     "share of resident patch cycles whose delta upload was pre-staged "
     "(double-buffered) by the previous cycle")
+
+#: the rows of a solve's ``nodes`` that ``_commit`` gathers on the
+#: device before it pulls them: ONE compiled shape a [J, K], padded;
+#: and the cells (1 MiB of int32) up to which it pulls the array whole
+_COMMIT_PULL_ROWS = 1024
+_COMMIT_PULL_WHOLE = 1 << 18
+
+
+_take_rows = introspect.instrument_jit(
+    "commit_take_rows", jax.jit(lambda nodes, idx: nodes[idx]))
+
+
+def _pull_rows(nodes, idx: np.ndarray) -> np.ndarray:
+    """``nodes[idx]`` on the host.  A solve's [J, K] node lists stay on
+    the device but for the rows a cycle placed: at K = 64 and 131,072
+    candidates the whole array is 33.5 MB, pulled and compared under
+    the server lock every cycle, for a few dozen rows that hold
+    anything.  An array that is on the host already, a small one (a
+    flood's few thousand candidates at K = 1: the pull is cheaper than
+    a gather, and a new J bucket compiles nothing more) and a cycle
+    that placed more rows than the gather holds (the first after a
+    start) are indexed whole."""
+    if (isinstance(nodes, np.ndarray) or len(idx) > _COMMIT_PULL_ROWS
+            or nodes.size <= _COMMIT_PULL_WHOLE):
+        return np.asarray(nodes)[idx]
+    padded = np.zeros(_COMMIT_PULL_ROWS, np.int32)
+    padded[:len(idx)] = idx
+    return np.asarray(_take_rows(nodes, padded))[:len(idx)]
+
 
 _REASON_MAP = {
     REASON_RESOURCE: PendingReason.RESOURCE,
@@ -2877,6 +2907,7 @@ class JobScheduler:
         tail_bound = self._cur_trace.pop("_tail_bound", 0)
         commit_visited = self._cur_trace.pop("_commit_visited", 0)
         commit_scan_s = self._cur_trace.pop("_commit_scan_s", 0.0)
+        nodes_selected = self._cur_trace.pop("_nodes_selected", 0)
         # commit = everything after the prelude that ran under the
         # lock, i.e. total minus prelude minus the lock-released solves.
         # Dispatch is NOT in here: the ring drains post-lock and its
@@ -2930,6 +2961,9 @@ class JobScheduler:
             commit_visited_pct=round(
                 100.0 * commit_visited / len(candidates), 3),
             commit_scan_ms=round(commit_scan_s * 1e3, 3),
+            # the nodes of the jobs the cycle started and of the head's
+            # reservations: what the selection passes were run FOR
+            nodes_selected=nodes_selected,
             placed=len(started),
             dirty_jobs=self._ptable.last_dirty,
             dirty_nodes=self.meta.last_snapshot_dirty,
@@ -4041,10 +4075,10 @@ class JobScheduler:
                                self.config.max_nodes_per_job))
         # bucket the static gang bound too (it is a jit static arg)
         max_nodes = self._bucket(max_nodes, floor=1)
-        # max_nodes is the static bound of the cycle's solves, and what
-        # the head's scan pays for every job whatever its width; the
-        # share of those passes a job needed (the Pallas tail runs only
-        # the passes a slot can use: tail_pass_pct)
+        # max_nodes is the static bound of the cycle's solves: the width
+        # of their [J, K] node lists, and the share of them the
+        # candidates can fill (the head picks by one sort, the Pallas
+        # tail runs only the passes a slot can use: tail_pass_pct)
         if ordered:
             self._cur_trace.update(
                 gang_bound=max_nodes,
@@ -4100,10 +4134,20 @@ class JobScheduler:
         events = self.meta.stop_logging()
         dirty_nodes = {ev.node_id for ev in events}
 
+        n = len(ordered)
         placed = np.asarray(placements.placed)
-        nodes_mat = np.asarray(placements.nodes)
         reasons = np.asarray(placements.reason)
-        valid_nodes = nodes_mat >= 0
+        # the node lists of the rows the solve placed (the head's
+        # reservations among them), and of no other: an unplaced row's
+        # list is all -1.  ``at[i]`` is row i's place in ``nodes_mat``
+        placed_idx = np.flatnonzero(placed[:n])
+        nodes_mat = _pull_rows(placements.nodes, placed_idx)
+        at = {i: k for k, i in enumerate(placed_idx.tolist())}
+
+        def nodes_of(i: int) -> list[int]:
+            row = nodes_mat[at[i]]
+            return row[row >= 0].tolist()
+
         # vectorized pre-pass: one gather flags every placement row
         # touching a node some mid-cycle event dirtied, replacing a
         # per-job set intersection
@@ -4113,7 +4157,7 @@ class JobScheduler:
             dirty_vec = np.zeros(size, dtype=bool)
             dirty_vec[list(dirty_nodes)] = True
             dirty_row = (dirty_vec[np.clip(nodes_mat, 0, size - 1)]
-                         & valid_nodes).any(axis=1)
+                         & (nodes_mat >= 0)).any(axis=1)
         started: list[int] = []
         admitted: list[Job] = []
         admitted_rows: list[int] = []
@@ -4123,7 +4167,6 @@ class JobScheduler:
         # them to the resident state so it force-patches them next cycle
         rejected_rows: list[int] = []
         future_start: list[tuple[Job, list[int]]] = []
-        n = len(ordered)
         pt = self._ptable
         if (rows is not None and len(rows) == n
                 and self._rows_gen == pt.generation):
@@ -4168,10 +4211,9 @@ class JobScheduler:
                 # per-job loop interleaved it with earlier jobs'
                 # mallocs), so it is DEFERRED until after the batch
                 # malloc below.
-                future_start.append(
-                    (job, nodes_mat[i][valid_nodes[i]].tolist()))
+                future_start.append((job, nodes_of(i)))
                 continue
-            if dirty_row is not None and dirty_row[i]:
+            if dirty_row is not None and dirty_row[at[i]]:
                 job.pending_reason = PendingReason.RESOURCE
                 rejected_rows.append(i)
                 continue
@@ -4185,9 +4227,9 @@ class JobScheduler:
                 job.pending_reason = PendingReason.QOS_LIMIT
                 rejected_rows.append(i)
                 continue
-            job.node_ids = nodes_mat[i][valid_nodes[i]].tolist()
+            job.node_ids = nodes_of(i)
             job.task_layout = ([int(t) for t, n in
-                                zip(tasks[i], nodes_mat[i]) if n >= 0]
+                                zip(tasks[i], nodes_mat[at[i]]) if n >= 0]
                                if tasks is not None else [])
             admitted.append(job)
             admitted_rows.append(i)
@@ -4221,6 +4263,10 @@ class JobScheduler:
             self.running[job.job_id] = job
             started_jobs.append(job)
             started.append(job.job_id)
+        cur["_nodes_selected"] = (
+            cur.get("_nodes_selected", 0)
+            + sum(len(job.node_ids) for job in started_jobs)
+            + sum(len(node_ids) for _, node_ids in future_start))
         for job, node_ids in future_start:
             req = job.spec.res.encode(self.meta.layout)
             fits_now = all(
@@ -4229,7 +4275,7 @@ class JobScheduler:
             job.pending_reason = (PendingReason.PRIORITY if fits_now
                                   else PendingReason.RESOURCE)
         if rejected_rows:
-            bad = nodes_mat[rejected_rows]
+            bad = nodes_mat[[at[i] for i in rejected_rows]]
             self._resident.mark_diverged(np.unique(bad[bad >= 0]))
         self._ledger_add_batch(started_jobs, now)
         _MET_COMMIT_BATCH.observe(len(started_jobs))

@@ -21,9 +21,14 @@ TPU-native fix is to run the WHOLE job loop inside a single kernel:
   ``node_num`` among the slot's valid streams and at the first infinite
   minimum: the minima come out ascending, so a job whose first one is
   infinite (no feasible node: nearly every job of a standing backlog) is
-  decided after ONE pass, as is a padded or invalidated slot.
-  Only a job that is placed pays its row writes and the masked
-  subtract/add of the resource/cost update.  The kernel counts the
+  decided after ONE pass, as is a padded or invalidated slot.  Pass 0
+  is straight-line code; the passes after it are ONE loop whose trip
+  count the device computes, so neither the depth nor the size of the
+  traced kernel follows K (a branch nested a pass overflowed the stack
+  of Mosaic's layout inference at K = 16), and K is only the width of
+  the ``chosen`` output, which leaves VMEM a grid step at a time.
+  Only a job that is placed pays the masked subtract/add of the
+  resource/cost update.  The kernel counts the
   passes it ran (``Placements.passes``; the cycle trace's
   ``tail_pass_pct``).  No dynamic-index gathers or scatters at all:
   selection and update are both expressed as elementwise ops against a
@@ -119,7 +124,7 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
                avail_in, cost_in, elig_in, cputot_in,    # VMEM cluster in
                placed_o, chosen_o, reason_o, avail_o, cost_o,  # outputs
                passes_o,                                 # SMEM counter out
-               avail_s, cost_s, placed_s, chosen_s, reason_s):  # scratch
+               avail_s, cost_s, placed_s, reason_s, mcost_s):  # scratch
         nb = pl.num_programs(0)
         step = pl.program_id(0)
 
@@ -139,68 +144,110 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
 
         placed_s[...] = jnp.zeros((S, BJ), jnp.int32)
         reason_s[...] = jnp.zeros((S, BJ), jnp.int32)
-        chosen_s[...] = jnp.full((S, K, BJ), -1, jnp.int32)
+        # this grid step's block of the chosen ids; it leaves VMEM when
+        # the step ends (at K = 64 the whole output would not fit there)
+        chosen_o[...] = jnp.full((1, S, K, BJ), -1, jnp.int32)
+
+        def lowest(mcosts):
+            """One selection pass for all S streams side by side: the
+            minimum of each masked cost, then the lowest node id that
+            holds it.  All S minima are asked for before the first id:
+            the reduce chains of one pass are mutually independent, and
+            in the order stream by stream they ran one behind the other
+            (the streamed kernel a third slower, PERF.md, PR 35)."""
+            ms = [jnp.min(mcost) for mcost in mcosts]
+            return ms, [jnp.min(jnp.where(mcost == m, nid, npad))
+                        for mcost, m in zip(mcosts, ms)]
 
         def job_body(j, passes):
             nns = [job_s[c, R, j] for c in range(S)]
             valids = [job_s[c, R + 2, j] != 0 for c in range(S)]
             clss = [job_s[c, R + 3, j] for c in range(S)]
             # the selection passes a stream's job can use: its own
-            # width; none beyond pass 0 where it is refused whatever the
-            # minima read (padding, an invalidated row, a gang wider
-            # than K)
+            # width; none where it is refused whatever the minima read
+            # (padding, an invalidated row, a gang wider than K)
             wants = [jnp.where(valids[c] & (nns[c] <= K), nns[c], 0)
                      for c in range(S)]
 
-            def select(k, mcosts):
-                """Pass k for all S streams side by side (the
-                latency-heavy reduce chains of one pass are mutually
-                independent), then the passes after it, which run only
-                while some stream that still wants a node found one:
-                the minima come out ascending, so after an infinite one
-                every later one is infinite.  A minimum a stream gets
-                only because a neighbour needed the pass changes
-                nothing: admission counts finite minima up to nn.
-                Returns (ms[k:], idxs[k:], passes run), [pass][stream]."""
-                ms = [jnp.min(mcost) for mcost in mcosts]
-                idxs = [jnp.min(jnp.where(mcost == m, nid, npad))
-                        for mcost, m in zip(mcosts, ms)]
-                if k + 1 == K:
-                    return [ms], [idxs], jnp.int32(1)
-                more = functools.reduce(jnp.logical_or, [
+            def put(c, k, idx, take):
+                """Gang member k of stream c's job: its row of the
+                block, this job's lane."""
+                row = chosen_o.at[0, c, pl.ds(k, 1), :]
+                row[...] = jnp.where((jlane == j) & take, idx, row[...])
+
+            def more_after(k, ms):
+                """Does any stream that still wants a node after pass k
+                have a chance of one?  The minima come out ascending,
+                so after an infinite one every later one is infinite."""
+                return functools.reduce(jnp.logical_or, [
                     (wants[c] > k + 1) & (ms[c] < inf) for c in range(S)])
 
-                def rest():
-                    # mask the winners for the next gang member
-                    return select(k + 1, [
-                        jnp.where(nid == idx, inf, mcost)
-                        for mcost, idx in zip(mcosts, idxs)])
-
-                def skip():
-                    # what the passes that do not run read: no finite
-                    # minimum (an id is only taken beside one)
-                    left = range(k + 1, K)
-                    return ([[inf] * S for _ in left],
-                            [[jnp.int32(0)] * S for _ in left],
-                            jnp.int32(0))
-
-                r_ms, r_idxs, ran = jax.lax.cond(more, rest, skip)
-                return [ms] + r_ms, [idxs] + r_idxs, ran + 1
-
-            # --- selection phase: reads pre-update state for all S
-            # streams, which is exact because no other stream can touch
-            # the nodes a stream sees.  Pass 0 runs for every slot: a
-            # branch round it for the few slots with no valid job
-            # (padding, the head's rows) cost a sixth of the kernel on
-            # the chip (PERF.md, PR 32), and K = 1 stays the
-            # straight-line code it was. ---
+            # --- selection, pass 0: reads pre-update state for all S
+            # streams side by side (the latency-heavy reduce chains of
+            # one pass are mutually independent), which is exact because
+            # no other stream can touch the nodes a stream sees.  It
+            # runs for every slot: a branch round it for the few slots
+            # with no valid job (padding, the head's rows) cost a sixth
+            # of the kernel on the chip (PERF.md, PR 32). ---
             mcosts = []
             for c in range(S):
                 feas = elig_in[clss[c]] != 0             # [SUB, W]
                 for r in range(R):
                     feas = feas & (avail_s[r] >= job_s[c, r, j])
                 mcosts.append(jnp.where(feas, cost_s[0], inf))
-            ms, idxs, ran = select(0, mcosts)
+            ms0, idxs0 = lowest(mcosts)
+            takes0 = [(wants[c] > 0) & (ms0[c] < inf) for c in range(S)]
+            # a winner is masked only for the stream that takes it, so
+            # that what the passes masked IS the set of nodes won
+            wins0 = [jnp.where(takes0[c], idxs0[c], -1) for c in range(S)]
+            got0 = tuple(t.astype(jnp.int32) for t in takes0)
+
+            # --- the passes after it: ONE loop whose trip count the
+            # device computes.  It runs while some stream that still
+            # wants a node found one, so it ends at the slot's widest
+            # min(node_num, K) and at the first infinite minimum, and
+            # its depth and its size do not follow K.  A job whose first
+            # minimum is infinite (no feasible node: nearly every job of
+            # a standing backlog) is decided after pass 0, as is a
+            # padded or invalidated slot.  The ids go to their rows as
+            # the loop runs (a gang that then falls short takes them
+            # back below), the finite minima a stream took are counted
+            # as it runs. ---
+            def later():
+                for c in range(S):
+                    mcost_s[c] = mcosts[c]
+
+                def body(carry):
+                    k, _, wins, got = carry
+                    # mask the last winners for the next gang members
+                    masked = [jnp.where(nid == wins[c], inf, mcost_s[c])
+                              for c in range(S)]
+                    for c in range(S):
+                        mcost_s[c] = masked[c]
+                    ms, idxs = lowest(masked)
+                    takes = [(k < wants[c]) & (ms[c] < inf)
+                             for c in range(S)]
+                    for c in range(S):
+                        put(c, k, idxs[c], takes[c])
+                    return (k + 1, more_after(k, ms),
+                            tuple(jnp.where(takes[c], idxs[c], -1)
+                                  for c in range(S)),
+                            tuple(got[c] + takes[c].astype(jnp.int32)
+                                  for c in range(S)))
+
+                k, _, wins, got = jax.lax.while_loop(
+                    lambda carry: carry[1], body,
+                    (jnp.int32(1), jnp.bool_(True), tuple(wins0), got0))
+                for c in range(S):
+                    mcost_s[c] = jnp.where(nid == wins[c], inf, mcost_s[c])
+                return k, got
+
+            if K > 1:
+                more = more_after(0, ms0)
+                ran, got = jax.lax.cond(
+                    more, later, lambda: (jnp.int32(1), got0))
+            else:
+                more, ran, got = False, jnp.int32(1), got0
 
             # --- decide + update phase.  Updates touch only the
             # stream's own (disjoint) nodes, so stream order here is
@@ -211,14 +258,10 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
 
                 # admission (decide_job): the masked minima are sorted
                 # ascending, so "at least nn feasible nodes" is
-                # exactly "at least nn finite minima" — no O(N)
-                # popcount.  The eligible count is solve-invariant and
-                # precomputed per class host-side.
-                cnt_finite = jnp.int32(0)
-                for k in range(K):
-                    cnt_finite = (cnt_finite
-                                  + (ms[k][c] < inf).astype(jnp.int32))
-                ok = valid & (nn > 0) & (nn <= K) & (cnt_finite >= nn)
+                # exactly "nn finite minima" — no O(N) popcount.  The
+                # eligible count is solve-invariant and precomputed per
+                # class host-side.
+                ok = (wants[c] > 0) & (got[c] >= nn)
                 bad = jnp.logical_not(valid) | (nn <= 0)
                 never = bad | (nelig_s[cls, 0] < nn)
                 reason = jnp.where(ok, REASON_NONE,
@@ -231,19 +274,29 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
                 reason_s[c:c + 1, :] = jnp.where(
                     jlane == j, reason, reason_s[c:c + 1, :])
 
-                # the chosen rows (they start a block at -1) and one
-                # combined state update for all gang members, both only
-                # for a job that is placed: the jobs that fail skip the
-                # row writes and the whole masked-subtract/cost pass
+                if K > 1:
+                    # a gang that found some nodes and not enough: its
+                    # rows go back to -1 (never partly placed)
+                    @pl.when(jnp.logical_not(ok) & (got[c] > 1))
+                    def _(c=c):
+                        chosen_o[0, c] = jnp.where(
+                            jlane == j, -1, chosen_o[0, c])
+
+                # row 0 of the block and one combined state update for
+                # all gang members, both only for a job that is placed:
+                # the jobs that fail skip the row write and the whole
+                # masked-subtract/cost pass
                 @pl.when(ok)
-                def _(c=c, nn=nn, tl=tl):
-                    win = jnp.zeros((SUB, W), bool)
-                    for k in range(K):
-                        take = (k < nn) & (ms[k][c] < inf)
-                        chosen_s[c, k:k + 1, :] = jnp.where(
-                            (jlane == j) & take, idxs[k][c],
-                            chosen_s[c, k:k + 1, :])
-                        win = win | ((nid == idxs[k][c]) & take)
+                def _(c=c, tl=tl):
+                    put(c, 0, idxs0[c], True)
+                    win = nid == idxs0[c]
+                    if K > 1:
+                        # and, where later passes ran, the nodes they
+                        # masked: feasible before, infinite after
+                        # (nothing is below ``lim`` where none ran)
+                        lim = jnp.where(more, inf, -inf)
+                        win = win | ((mcost_s[c] == inf)
+                                     & (mcosts[c] < lim))
                     # MinCpuTimeRatioFirst increment, elementwise over
                     # nodes with this job's scalars — identical f32
                     # expression (and associativity) to
@@ -265,11 +318,10 @@ def _make_kernel(BJ: int, K: int, R: int, W: int, S: int = 1):
         passes_o[0, step] = jax.lax.fori_loop(0, BJ, job_body,
                                               jnp.int32(0))
 
-        # per-job outputs live whole in VMEM (tiny); write this block's
+        # placed / reason live whole in VMEM (tiny); write this block's
         # row at a dynamic offset — blocked specs would need a
         # sublane-divisible leading block dim the (NB, S, BJ) shape lacks
         placed_o[pl.ds(step, 1)] = placed_s[...][None]
-        chosen_o[pl.ds(step, 1)] = chosen_s[...][None]
         reason_o[pl.ds(step, 1)] = reason_s[...][None]
 
         @pl.when(step == nb - 1)
@@ -343,14 +395,19 @@ def _launch(job_p, nelig, avail3, cost2, elig3, cputot3,
                                memory_space=pltpu.SMEM),
                   vmem_full(), vmem_full(), vmem_full(), vmem_full()],
         out_shape=out_shapes,
-        out_specs=(*(vmem_full() for _ in out_shapes[:-1]),
+        out_specs=(vmem_full(),
+                   # the chosen ids leave VMEM block by block: whole,
+                   # [NB, S, K, BJ] is 35.7 MB at the north-star shape
+                   # and K = 64
+                   pl.BlockSpec((1, S, K, BJ), lambda i: (i, 0, 0, 0)),
+                   vmem_full(), vmem_full(), vmem_full(),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
         scratch_shapes=[
             pltpu.VMEM((R, SUB, W), jnp.int32),
             pltpu.VMEM((1, SUB, W), jnp.int32),
             pltpu.VMEM((S, BJ), jnp.int32),
-            pltpu.VMEM((S, K, BJ), jnp.int32),
             pltpu.VMEM((S, BJ), jnp.int32),
+            pltpu.VMEM((S, SUB, W), jnp.int32),     # masked costs
         ],
         interpret=interpret,
         name=name,

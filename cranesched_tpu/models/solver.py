@@ -88,19 +88,18 @@ def cheapest_k(masked_cost, k: int):
     """The k smallest entries of an int32 cost vector, ascending, ties to
     the lowest index.  Returns (values, indices).
 
-    Replaces ``lax.top_k(-cost, k)``: XLA's int32 top_k lowers to a path
-    ~100× slower than float32 on CPU (measured), while argmin on int32 is
-    fast — so for the small k of a placement step, k iterative argmins
-    (masking each winner to the sentinel) win by a wide margin and keep
-    identical tie semantics (argmin returns the first occurrence)."""
-    vals, idxs = [], []
-    c = masked_cost
-    for _ in range(k):
-        i = jnp.argmin(c)
-        vals.append(c[i])
-        idxs.append(i.astype(jnp.int32))
-        c = c.at[i].set(COST_INF)
-    return jnp.stack(vals), jnp.stack(idxs)
+    ONE stable sort of (cost, index), whatever k is, so that a
+    placement step's cost does not follow the static gang bound.  k
+    argmin passes, each masking its winner, did, and unrolled k deep:
+    on a v5e at 10k nodes the backfill head's 1,024 steps took 160.3 /
+    185.2 ms at k = 2 / 8 and take 159.3 / 159.3 / 162.8 ms at k = 2 /
+    8 / 64 (PERF.md, PR 35).  Stable over an ascending index is the tie
+    order argmin's first occurrence gave."""
+    n = masked_cost.shape[0]
+    vals, idxs = jax.lax.sort(
+        (masked_cost, jnp.arange(n, dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    return vals[:k], idxs[:k]
 
 
 # Pending-reason codes (subset of the reference's pending reasons,

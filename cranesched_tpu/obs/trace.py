@@ -33,11 +33,13 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
     candidates       int     jobs considered this cycle
     gang_bound       int     the static gang bound K the cycle's solves
                              ran with: the bucket of its widest
-                             candidate, capped at MaxNodesPerJob.  The
-                             head's scan pays K selection passes a job
+                             candidate, capped at MaxNodesPerJob; the
+                             width of their [J, K] node lists (neither
+                             the head's sort nor the tail's passes
+                             cost K a job any more)
     gang_fill_pct    float   100 * sum of the candidates' node_num /
                              (candidates * gang_bound): the share of
-                             those passes a job needed
+                             those node lists a job can fill
     tail_pass_pct    float   100 * selection passes the cycle's Pallas
                              kernel ran / (its slots * gang_bound):
                              after pass 0 a slot stops at its widest
@@ -58,6 +60,10 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
                              array pulls and that visit; the rest is
                              the ledger batch, WAL records and the
                              dispatch queue of the jobs that start
+    nodes_selected   int     sum of node_num over the jobs the cycle
+                             started and the backfill head's
+                             reservations: the nodes its solves chose
+                             and its commits carried
     placed           int     jobs started (incl. backfill tail)
     preempted        int     victims killed by this cycle
     backfilled       int     placed with start_bucket > 0 (future start)

@@ -206,3 +206,58 @@ def test_a_flood_shaped_cycle_visits_what_it_places(route):
     for _ in range(16):
         assert sched.submit(JobSpec(res=_res(2.0), time_limit=600), now=0.0)
     assert _visited(sched, sim, 1.0) == (16, 16, 16, 0)
+
+
+# ---------------------------------------------------------------------------
+# what a commit pulls (ISSUE 35): the node lists of the rows the cycle
+# placed, gathered on the device, and not the solve's [J, K] whole
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("case", ["gathered", "too_many_rows", "small_array",
+                                  "on_the_host", "none_placed"])
+def test_pull_rows_is_nodes_at_idx(case, monkeypatch):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cranesched_tpu.ctld import scheduler as sch
+
+    gathers = []
+    monkeypatch.setattr(sch, "_take_rows", lambda nodes, idx: (
+        gathers.append(nodes.shape) or nodes[idx]))
+    cap, width = sch._COMMIT_PULL_ROWS, 64
+    rows = (sch._COMMIT_PULL_WHOLE // width if case == "small_array"
+            else 2 * sch._COMMIT_PULL_WHOLE // width)
+    host = np.arange(rows * width, dtype=np.int32).reshape(rows, width) - 5
+    idx = {"gathered": np.arange(0, rows, 7)[:cap],
+           "too_many_rows": np.arange(cap + 1),
+           "small_array": np.array([3, rows - 1]),
+           "on_the_host": np.array([0, 9, rows - 1]),
+           "none_placed": np.zeros(0, np.intp)}[case]
+    nodes = host if case == "on_the_host" else jnp.asarray(host)
+    got = sch._pull_rows(nodes, idx)
+    assert isinstance(got, np.ndarray) and got.dtype == np.int32
+    np.testing.assert_array_equal(got, host[idx])
+    assert gathers == ([(rows, width)] if case in ("gathered", "none_placed")
+                       else [])
+
+
+def test_a_cycle_over_more_candidates_than_the_gather_holds(monkeypatch):
+    """1,100 candidates (a 2,048-row solve) of which 8 start, with the
+    size up to which an array is pulled whole set below it: the commit
+    takes the gather's path and gives each its own node."""
+    sched = JobScheduler(_meta(), SchedulerConfig(
+        backfill=False, solver="device"))    # the scan: device arrays
+    sim = SimCluster(sched)
+    sim.wire(sched)
+    pulled = []
+    from cranesched_tpu.ctld import scheduler as sched_mod
+    monkeypatch.setattr(sched_mod, "_take_rows", lambda nodes, idx: (
+        pulled.append(nodes.shape) or nodes[idx]))
+    monkeypatch.setattr(sched_mod, "_COMMIT_PULL_WHOLE", 1024)
+    ids = _whole(sched, 0.0, 1100)
+    started, visited, candidates, _ = _visited(sched, sim, 1.0)
+    assert (started, visited, candidates) == (NODES, 1100, 1100)
+    nodes = sorted(sched.job_info(j).node_ids[0] for j in ids[:NODES])
+    assert nodes == list(range(NODES))
+    assert sched.cycle_trace.snapshot()[-1]["nodes_selected"] == NODES
+    assert pulled == [(2048, 1)]

@@ -1,18 +1,22 @@
-"""Gangs up to the site's 8-node bound (ISSUE 30, `northstar-10k`).
+"""Gangs up to the site's bound: 8 nodes (ISSUE 30, `northstar-10k`) and
+64 (ISSUE 35, `widegang-10k`).
 
-Both accepted deployments are configured ``MaxNodesPerJob: 8`` and the
-older parity tests stop at 4, so the widths 5-8 met the solvers only in
-``chip_smoke.py``.  Here, on seeded random clusters and queues at a small
-size on the CPU, parametrised over the static gang bound K in {4, 8}:
+The older parity tests stop at 4, so the widths 5-8 met the solvers only
+in ``chip_smoke.py``, and nothing wider compiled at all before PR 35.
+Here, on seeded random clusters and queues at a small size on the CPU,
+parametrised over the static gang bound K in {4, 8, 16, 32, 64}:
 
 * ``solve_greedy`` and ``solve_greedy_pallas_auto`` (interpreted) against
   ``solve_greedy_oracle``, ``solve_backfill`` against
   ``solve_backfill_oracle``: placed / nodes / reason / avail / cost, bit
   for bit;
-* one ``JobScheduler`` cycle of the default block over widths 1-8: its
-  placements pass the benchmark's own replay (``benchmark/lib/check.py``)
-  and its trace row says which K the cycle paid and what share of the K
-  selection passes a job needed;
+* a gang that needs K nodes and finds K - 1 is refused whole by every
+  solver;
+* one ``JobScheduler`` cycle of the default block over widths 1-8 and
+  1-64: its placements pass the benchmark's own replay
+  (``benchmark/lib/check.py``) and its trace row says which K the cycle
+  paid and what share of the K selection passes a job needed; a submit
+  one node wider than ``MaxNodesPerJob`` is refused;
 * the benchmark side of the cell: the loader takes it, the replay counts
   a 7-node answer to an 8-wide job, and the reader of a device op's time
   reads a hand-made reduction.
@@ -67,7 +71,7 @@ from lib.traffic import Ack, Job                 # noqa: E402
 from readers import device_op                    # noqa: E402
 
 LAY = ResourceLayout()
-WIDTHS = [4, 8]
+WIDTHS = [4, 8, 16, 32, 64]
 CELL = "northstar10k-gangs"
 
 
@@ -87,11 +91,16 @@ def _problem(rng, num_jobs, num_nodes, max_nodes, num_parts=3):
         for _ in range(num_jobs)])
     node_part = np.arange(num_nodes) % num_parts
     part_mask = rng.integers(0, num_parts, num_jobs)[:, None] == node_part
+    node_num = rng.integers(1, max_nodes + 2, num_jobs).astype(np.int32)
+    valid = rng.random(num_jobs) > 0.05
+    # the first job uses the bound: as wide as it allows, small enough
+    # for every node
+    req[0] = LAY.encode(cpu=1.0, mem_bytes=1 << 30)
+    node_num[0], valid[0] = max_nodes, True
     return dict(
         total=total, alive=alive, cost=cost, req=req, part_mask=part_mask,
-        node_num=rng.integers(1, max_nodes + 2, num_jobs).astype(np.int32),
-        time_limit=rng.integers(60, 86400, num_jobs).astype(np.int32),
-        valid=rng.random(num_jobs) > 0.05)
+        node_num=node_num, valid=valid,
+        time_limit=rng.integers(60, 86400, num_jobs).astype(np.int32))
 
 
 def _greedy_oracle(p, max_nodes):
@@ -115,7 +124,8 @@ def _assert_greedy(placements, state, oracle):
 
 @pytest.mark.parametrize("max_nodes", WIDTHS)
 def test_greedy_scan_matches_the_oracle(max_nodes):
-    p = _problem(np.random.default_rng(300 + max_nodes), 120, 72, max_nodes)
+    p = _problem(np.random.default_rng(300 + max_nodes), 120,
+                 max(72, 8 * max_nodes), max_nodes)
     state = make_cluster_state(p["total"].copy(), p["total"], p["alive"],
                                p["cost"])
     jobs = JobBatch(req=jnp.asarray(p["req"]),
@@ -131,7 +141,8 @@ def test_greedy_scan_matches_the_oracle(max_nodes):
 def test_pallas_auto_matches_the_oracle(max_nodes):
     """The streamed kernel (disjoint partitions: the plan is taken), in
     interpret mode."""
-    p = _problem(np.random.default_rng(310 + max_nodes), 96, 72, max_nodes)
+    p = _problem(np.random.default_rng(310 + max_nodes), 96,
+                 max(72, 8 * max_nodes), max_nodes)
     state = make_cluster_state(p["total"].copy(), p["total"], p["alive"],
                                p["cost"])
     job_class, masks = classes_from_part_mask(p["part_mask"])
@@ -150,9 +161,10 @@ def test_backfill_matches_the_oracle(max_nodes):
     the horizon, so wide gangs reserve a future start."""
     rng = np.random.default_rng(320 + max_nodes)
     T, M = 16, 14
-    p = _problem(rng, 40, 24, max_nodes, num_parts=2)
+    N = max(24, 5 * max_nodes)
+    p = _problem(rng, 40, N, max_nodes, num_parts=2)
     p["time_limit"] = rng.integers(1, T + 2, 40).astype(np.int32)
-    run_nodes = rng.integers(0, 24, size=(M, 1)).astype(np.int32)
+    run_nodes = rng.integers(0, N, size=(M, 1)).astype(np.int32)
     run_req = np.stack([
         LAY.encode(cpu=int(rng.integers(1, 5)),
                    mem_bytes=int(rng.integers(1, 9)) << 30)
@@ -187,6 +199,61 @@ def test_backfill_matches_the_oracle(max_nodes):
     np.testing.assert_array_equal(np.asarray(new_state.cost), o_cost)
     assert (o_nodes[o_placed] >= 0).all(axis=1).any()
     assert (o_start[o_placed] > 0).any()
+
+
+def _one_short(max_nodes):
+    """One partition of exactly max_nodes - 1 nodes that fit a gang
+    max_nodes wide (and one node too small for it), then a job one
+    narrower that is placed on all of them."""
+    n = max_nodes + 3
+    total = np.tile(LAY.encode(cpu=8, mem_bytes=16 << 30,
+                               is_capacity=True), (n, 1))
+    total[max_nodes - 1:] = LAY.encode(cpu=1, mem_bytes=16 << 30,
+                                       is_capacity=True)
+    req = np.tile(LAY.encode(cpu=2.0, mem_bytes=1 << 30), (2, 1))
+    return dict(
+        total=total, alive=np.ones(n, bool),
+        cost=np.arange(n, dtype=np.float32)[::-1].copy(), req=req,
+        part_mask=np.ones((2, n), bool),
+        node_num=np.array([max_nodes, max_nodes - 1], np.int32),
+        time_limit=np.array([600, 600], np.int32),
+        valid=np.ones(2, bool))
+
+
+@pytest.mark.parametrize("solver", ["scan", "serial", "backfill"])
+@pytest.mark.parametrize("max_nodes", [8, 64])
+def test_a_gang_one_node_short_is_refused_whole(max_nodes, solver):
+    """A gang that needs K and finds K - 1: no node, no subtraction, the
+    reason `resource`; the next job takes exactly those K - 1, dearest
+    index first (the cost falls with the index here)."""
+    p = _one_short(max_nodes)
+    cols = dict(req=jnp.asarray(p["req"]),
+                node_num=jnp.asarray(p["node_num"]),
+                time_limit=jnp.asarray(p["time_limit"]),
+                part_mask=jnp.asarray(p["part_mask"]),
+                valid=jnp.asarray(p["valid"]))
+    state = make_cluster_state(p["total"].copy(), p["total"], p["alive"],
+                               p["cost"])
+    if solver == "scan":
+        got, _ = solve_greedy(state, JobBatch(**cols), max_nodes=max_nodes)
+    elif solver == "serial":
+        job_class, masks = classes_from_part_mask(p["part_mask"])
+        got, _ = solve_greedy_pallas_auto(
+            state, cols["req"], cols["node_num"], cols["time_limit"],
+            cols["valid"], jnp.asarray(job_class), jnp.asarray(masks),
+            max_nodes=max_nodes, interpret=True)
+    else:
+        timed = make_timed_state(
+            p["total"].copy(), p["total"], p["alive"],
+            np.zeros((0, 1), np.int32),
+            np.zeros((0, p["total"].shape[1]), np.int32),
+            np.zeros(0, np.int32), 4, p["cost"])
+        got, _ = solve_backfill(timed, TimedJobBatch(**cols),
+                                max_nodes=max_nodes)
+    placed, nodes = np.asarray(got.placed), np.asarray(got.nodes)
+    assert placed.tolist() == [False, True]
+    assert (nodes[0] == -1).all() and int(got.reason[0]) == 1
+    assert nodes[1].tolist() == list(range(max_nodes - 2, -1, -1)) + [-1]
 
 
 def test_the_head_scan_carries_its_scope():
@@ -256,22 +323,48 @@ def _cycle(widths, nodes=48, parts=2, config=None, interpret=False):
     return cluster, acks, rows, started, sched.cycle_trace.snapshot()[-1]
 
 
-def test_a_cycle_of_widths_1_to_8_passes_the_replay_and_says_its_bound():
-    widths = [1, 2, 3, 4, 5, 6, 7, 8] * 3
-    cluster, acks, rows, started, trace = _cycle(widths)
+@pytest.mark.parametrize("bound", [8, 64])
+def test_a_cycle_of_widths_1_to_the_bound_passes_the_replay_and_says_it(
+        bound):
+    widths = list(range(1, bound + 1)) * (3 if bound == 8 else 1)
+    cluster, acks, rows, started, trace = _cycle(
+        widths, nodes=6 * bound,
+        config=SchedulerConfig(max_nodes_per_job=bound))
     placed = [r for r in rows if r.node_names]
-    assert len(placed) == len(started) >= 8
-    assert {len(r.node_names) for r in placed} == set(range(1, 9))
+    assert len(placed) == len(started) == len(widths)
+    assert {len(r.node_names) for r in placed} == set(range(1, bound + 1))
     assert check.misplaced_jobs(cluster, acks, rows, t_drained=0.0) == 0
     assert check.overcommitted_nodes(cluster, acks, rows) == 0
-    assert trace["gang_bound"] == 8
-    # by hand: 3 x (1 + ... + 8) = 108 nodes asked for, 24 jobs x 8 passes
-    assert trace["candidates"] == 24
-    assert trace["gang_fill_pct"] == pytest.approx(100.0 * 108 / (24 * 8))
+    assert trace["gang_bound"] == bound
+    # by hand: (1 + ... + bound) nodes asked for each round, a job x
+    # bound passes
+    asked = sum(widths)
+    assert trace["candidates"] == len(widths)
+    assert trace["gang_fill_pct"] == pytest.approx(
+        100.0 * asked / (len(widths) * bound), abs=1e-3)
+    assert trace["nodes_selected"] == asked
     # both sides are rounded (one decimal; solve_ms three): a cold
     # cycle's few decisions a second need the absolute slack
     assert trace["decisions_per_s"] == pytest.approx(
-        24 * 1e3 / trace["solve_ms"], rel=1e-3, abs=0.06)
+        len(widths) * 1e3 / trace["solve_ms"], rel=1e-3, abs=0.06)
+
+
+def test_a_submit_wider_than_the_bound_is_refused():
+    meta = MetaContainer()
+    for i in range(70):
+        meta.add_node(f"cn{i:05d}", meta.layout.encode(
+            cpu=16, mem_bytes=32 << 30, memsw_bytes=32 << 30,
+            is_capacity=True), partitions=("batch0",))
+        meta.craned_up(i)
+    sched = JobScheduler(meta, SchedulerConfig(max_nodes_per_job=64))
+
+    def submit(width):
+        return sched.submit(JobSpec(
+            partition="batch0", node_num=width, time_limit=60,
+            res=ResourceSpec(cpu=1.0, mem_bytes=1 << 30,
+                             memsw_bytes=1 << 30)), now=0.0)
+
+    assert submit(64) and not submit(65)
 
 
 def test_the_tail_says_what_share_of_its_passes_it_ran():
@@ -293,6 +386,25 @@ def test_the_tail_says_what_share_of_its_passes_it_ran():
     # a cycle that ran no Pallas kernel left no pass out: 100, and not a
     # 0 for a reader of a "lower is better" share to take for the best
     assert _cycle(widths)[4]["tail_pass_pct"] == 100.0
+
+
+def test_the_tail_s_pass_count_at_k_64_by_hand():
+    """The same split at the bound 64: the loop over the later passes
+    runs a slot's own width and no further, so the count is 256 first
+    passes and one more for every further node of a tail job; of the
+    256 x 64 the static bound allows that is 3.1%."""
+    widths = [1, 2, 3, 4] + [64, 33, 17, 9, 5, 64, 1, 48]
+    _, _, rows, started, trace = _cycle(
+        widths, nodes=256, parts=1, interpret=True,
+        config=SchedulerConfig(solver="pallas", backfill_max_jobs=4,
+                               max_nodes_per_job=64))
+    assert len(started) == 12 and trace["solver"] == "backfill-split"
+    assert trace["gang_bound"] == 64
+    tail = widths[4:]
+    assert trace["tail_pass_pct"] == pytest.approx(
+        100.0 * (256 + sum(tail) - len(tail)) / (256 * 64), abs=1e-3)
+    assert sorted(len(r.node_names) for r in rows) == sorted(widths)
+    assert trace["nodes_selected"] == sum(widths)
 
 
 def test_the_bound_is_the_bucket_of_the_widest_candidate():

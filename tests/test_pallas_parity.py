@@ -235,16 +235,24 @@ def _partitioned(jobs, num_nodes, parts, rng):
         job_part[:, None] == node_part[None, :]))
 
 
-@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("max_nodes,seed", [
+    (4, 0), (4, 1), (4, 2), (4, 3), (16, 0), (32, 0), (64, 0)])
 @pytest.mark.parametrize("kernel", ["serial", "streamed"])
-def test_used_nodes_fractional_costs_k4(kernel, seed):
+def test_used_nodes_fractional_costs(kernel, seed, max_nodes):
+    """Widths 1 to the bound mixed in one batch, on nodes that are
+    partly used; ten nodes a partition at K = 4, 2.5 K beyond it, so
+    that the widest gangs are placed as well as refused."""
     from test_sharded_parity import _random_problem as used_problem
     rng = np.random.default_rng(seed)
-    state, jobs = used_problem(rng, num_jobs=100, num_nodes=40,
-                               max_nodes=4)
+    num_nodes = max(40, 10 * max_nodes)
+    state, jobs = used_problem(rng, num_jobs=100, num_nodes=num_nodes,
+                               max_nodes=max_nodes)
     if kernel == "streamed":
-        jobs = _partitioned(jobs, 40, 4, rng)
-    _assert_kernel_bit_identical(state, jobs, 4, kernel)
+        jobs = _partitioned(jobs, num_nodes, 4, rng)
+    got = _assert_kernel_bit_identical(state, jobs, max_nodes, kernel)
+    wide = np.asarray(jobs.node_num) > max_nodes // 2
+    assert np.asarray(got.placed)[wide].any()
+    assert not np.asarray(got.placed)[wide].all()
 
 
 @pytest.mark.parametrize("kernel", ["serial", "streamed"])
@@ -311,47 +319,55 @@ def test_classes_from_part_mask_roundtrip():
 # ---------------------------------------------------------------------------
 
 EDGE_PARTS = 4          # one stream a partition at S = 4
-EDGE_PER = 10           # nodes a partition: node i of it has i + 1 cpus free
-EDGE_BLOCK = 8          # jobs a block; a stream is two blocks long
-EDGE_LEN = 16
+EDGE_BLOCK = 8          # jobs a block
+
+
+def _edge_sizes(K):
+    """(nodes a partition: node i of it has i + 1 cpus free, so at least
+    K + 2 of them; a stream's length in jobs: whole blocks that hold the
+    K - 1 jobs of the longest case).  10 and 16 up to K = 8."""
+    return max(10, K + 2), max(16, -(-K // EDGE_BLOCK) * EDGE_BLOCK)
 
 
 def _edge_case(name, K):
     """(jobs, passes run AFTER pass 0, (placed, reason) a job).  A job is
     (partition, cpus, node_num, valid); a job of c cpus has
-    EDGE_PER - c + 1 feasible nodes in an untouched partition, and
+    per - c + 1 feasible nodes in an untouched partition, one of `big`
+    cpus none, and
     partition 3 has ONE node alive.  The streamed kernel puts the n-th
     job of every partition into slot n, the serial kernel one job a
     slot; in every case here the jobs that share a slot run as many
     further passes together as they would alone, so the count is one
     number.  Every slot, padding too, runs pass 0: the test adds one a
     slot."""
+    per, _ = _edge_sizes(K)
+    big = per + 6
     yes, no = (True, REASON_NONE), (False, REASON_RESOURCE)
     never = (False, REASON_CONSTRAINT)
     if name == "one_to_nn_minus_1_feasible":
         # nn = K, f = 1 .. K-1 feasible nodes: passes 0 .. f run, pass f
         # reads the first infinite minimum.  One partition, so a slot
         # holds one job in either kernel
-        jobs = [(0, EDGE_PER - f + 1, K, True) for f in range(1, K)]
+        jobs = [(0, per - f + 1, K, True) for f in range(1, K)]
         count = sum(f for f in range(1, K))
         return jobs, count, [no] * (K - 1)
     if name == "nn_equals_K_all_feasible":
         return [(1, 1, K, True)], K - 1, [yes]
     if name == "no_job_of_the_block_feasible":
-        # 8 jobs, two a partition, widths mixed, 16 cpus each: pass 0
+        # 8 jobs, two a partition, widths mixed, `big` cpus each: pass 0
         # says so; the last asks partition 3 for K of its one live node
-        jobs = [(j % 3, 16, 1 + j % K, True) for j in range(6)]
-        jobs += [(3, 16, 1, True), (3, 16, K, True)]
+        jobs = [(j % 3, big, 1 + j % K, True) for j in range(6)]
+        jobs += [(3, big, 1, True), (3, big, K, True)]
         return jobs, 0, [no] * 7 + [never]
     if name == "wide_feasible_beside_narrow_infeasible":
-        return [(0, 1, K, True), (1, 16, 1, True)], K - 1, [yes, no]
+        return [(0, 1, K, True), (1, big, 1, True)], K - 1, [yes, no]
     if name == "narrow_feasible_beside_wide_infeasible":
-        return [(0, 1, 1, True), (1, 16, K, True)], 0, [yes, no]
+        return [(0, 1, 1, True), (1, big, K, True)], 0, [yes, no]
     if name == "invalid_slot_and_padded_stream":
         # slot 0: three invalidated rows (two of them K wide, one of
         # those feasible had it been valid) and a stream with no job;
         # then a gang of 2 that fits, in slot 1 of its stream
-        jobs = [(0, 1, K, False), (1, 1, 1, False), (2, 16, K, False),
+        jobs = [(0, 1, K, False), (1, 1, 1, False), (2, big, K, False),
                 (0, 1, 2, True)]
         return jobs, 1, [never] * 3 + [yes]
     if name == "fewer_eligible_nodes_than_nn":
@@ -372,21 +388,22 @@ EDGE_CASES = [
     "wider_than_the_bound"]
 
 
-@pytest.mark.parametrize("K", [2, 4, 8])
+@pytest.mark.parametrize("K", [2, 4, 8, 16, 32, 64])
 @pytest.mark.parametrize("kernel", ["serial", "streamed"])
 @pytest.mark.parametrize("case", EDGE_CASES)
 def test_selection_pass_edges(case, kernel, K):
     jobs, want_passes, want_jobs = _edge_case(case, K)
+    per, stream_len = _edge_sizes(K)
     lay = ResourceLayout()
-    N = EDGE_PARTS * EDGE_PER
-    node_part = np.arange(N) // EDGE_PER
-    total = np.tile(lay.encode(cpu=16, mem_bytes=64 << 30,
+    N = EDGE_PARTS * per
+    node_part = np.arange(N) // per
+    total = np.tile(lay.encode(cpu=per + 6, mem_bytes=64 << 30,
                                is_capacity=True), (N, 1))
-    avail = np.stack([lay.encode(cpu=int(i % EDGE_PER) + 1,
+    avail = np.stack([lay.encode(cpu=int(i % per) + 1,
                                  mem_bytes=64 << 30, is_capacity=True)
                       for i in range(N)])
     alive = np.ones(N, bool)
-    alive[3 * EDGE_PER + 1:] = False
+    alive[3 * per + 1:] = False
     cost = (np.arange(N) % 3).astype(np.float32)   # ties inside a partition
     part, cpus, node_num, valid = (np.asarray(x) for x in zip(*jobs))
     req = np.stack([lay.encode(cpu=float(c), mem_bytes=1 << 30)
@@ -402,13 +419,13 @@ def test_selection_pass_edges(case, kernel, K):
     if kernel == "serial":
         got, new_state = solve_greedy_pallas(
             *args, max_nodes=K, block_jobs=EDGE_BLOCK, interpret=True)
-        slots = EDGE_BLOCK
+        slots = -(-len(jobs) // EDGE_BLOCK) * EDGE_BLOCK
     else:
         got, new_state = _solve_streamed(
             *args, jnp.arange(EDGE_PARTS, dtype=jnp.int32), max_nodes=K,
             block_jobs=EDGE_BLOCK, num_streams=EDGE_PARTS,
-            stream_len=EDGE_LEN, interpret=True)
-        slots = EDGE_LEN
+            stream_len=stream_len, interpret=True)
+        slots = stream_len
 
     o_placed, o_nodes, o_reason, o_avail, o_cost = solve_greedy_oracle(
         avail.copy(), total, alive, cost, req, node_num, time_limit,
