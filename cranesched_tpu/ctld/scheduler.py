@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import threading
 from typing import Callable, Iterable
 
 import jax
@@ -664,6 +665,11 @@ class JobScheduler:
         # profiler window is armed by the CaptureProfile RPC and ticked
         # at cycle boundaries
         self._cycle_compile_base = introspect.total_compiles()
+        # cycle_compiling(): the fresh-call count at the running cycle's
+        # opening (None between cycles), and what its end notifies
+        self._cycle_fresh_base = None
+        self._cycle_thread = None
+        self._cycle_end = threading.Condition()
         self.profiler_window = introspect.ProfilerWindow(
             event_sink=lambda type, sev, detail="": self.events.emit(
                 type, sev, detail=detail),
@@ -2281,6 +2287,8 @@ class JobScheduler:
         self.flight.stamp("cycle_begin")
         clock.mark("drain")
         self._wal_begin()
+        self._cycle_thread = threading.get_ident()
+        self._cycle_fresh_base = introspect.fresh_calls()
         try:
             started = yield from self._cycle_body(now)
             return started
@@ -2289,11 +2297,45 @@ class JobScheduler:
             # phases: no WAL event may sit buffered across cycles, and
             # a job committed to RUNNING must still get its dispatch
             # (drained inline here; the normal path drained lock-free)
-            clock.mark("wal")
-            self._wal_flush()
-            self._drain_dispatch_ring()
-            self.flight.stamp("cycle_end")
+            try:
+                clock.mark("wal")
+                self._wal_flush()
+                self._drain_dispatch_ring()
+                self.flight.stamp("cycle_end")
+            finally:
+                with self._cycle_end:
+                    self._cycle_fresh_base = None
+                    self._cycle_end.notify_all()
             self._close_cycle_ledger()
+
+    def cycle_compiling(self) -> bool:
+        """True from the first jit call of the running cycle that meets
+        a signature new to the process (it is about to compile, or to
+        load from the persistent cache) until that cycle has ended."""
+        base = self._cycle_fresh_base
+        return base is not None and introspect.fresh_calls() > base
+
+    def wait_out_compiling_cycle(self) -> None:
+        """Batch ingest's back-pressure; call it with NO lock held.
+
+        A cycle that compiles lasts seconds to a minute, most of it with
+        the server lock released (the solve).  Whatever a batch caller
+        pushes meanwhile is the NEXT cycle's candidates: a larger J
+        bucket, so another compile, behind which still more piles up:
+        a cold daemon under a flood walked the ladder to 131,072 and
+        down again, minutes of compiles for shapes no later cycle
+        meets.  Waiting here bounds what a compiling cycle leaves
+        behind to about one RPC's specs.  A cycle that compiles nothing
+        costs two reads; the wait ends with the cycle, however it ends
+        (``cycle_phases``' ``finally``).  The thread that drives the
+        cycle never waits for it (a single-threaded driver ingests
+        between the phases)."""
+        if (not self.cycle_compiling()
+                or self._cycle_thread == threading.get_ident()):
+            return
+        with self._cycle_end:
+            while self.cycle_compiling():
+                self._cycle_end.wait(1.0)
 
     def _close_cycle_ledger(self) -> None:
         """The period ends here, under the lock: its parts go into the

@@ -347,6 +347,12 @@ class WriteAheadLog:
         if self._group_depth == 0:
             self._flush_group()
 
+    @property
+    def group_open(self) -> bool:
+        """A group is begun and not yet committed, or records are
+        buffered: what nobody may find while the server lock is free."""
+        return self._group_depth > 0 or bool(self._group_buf)
+
     @contextlib.contextmanager
     def group(self):
         self.begin_batch()

@@ -27,9 +27,14 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
     lock_held_ms     float   prelude_ms + commit_ms: an upper bound
                              too, it books the retakes' WAITING as
                              holding (lock_held_work_ms is the holding)
-    wal_fsyncs       int     durability barriers this cycle (== WAL
-                             groups when group commit is active)
-    wal_groups       int     WAL groups flushed this cycle (<= 3)
+    wal_fsyncs       int     durability barriers between this cycle's
+                             opening and its record: its own groups
+                             and the RPC handlers' that ran beside it
+                             (a SubmitBatchJobs chunk is one group; a
+                             single SubmitBatchJob a barrier outside
+                             any group)
+    wal_groups       int     WAL groups flushed in the same span (the
+                             cycle's own <= 3)
     candidates       int     jobs considered this cycle
     gang_bound       int     the static gang bound K the cycle's solves
                              ran with: the bucket of its widest

@@ -18,6 +18,7 @@ Two clock modes:
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from concurrent import futures
@@ -379,10 +380,24 @@ class CtldServer:
         # chunked insert: batch submit is not atomic (every spec gets
         # its own reply), so release the lock between chunks — a
         # whole-batch hold kept readers waiting for the full insert
-        # (~75ms for 250 specs) and set the query-plane p99
+        # (~75ms for 250 specs) and set the query-plane p99.  Each lock
+        # hold is ONE WAL group: the chunk's submit records are written
+        # with one write and one fsync before the lock goes (the group
+        # closes first, an exception included), so no job is visible to
+        # the cycle, a query or a follower before it is durable, and the
+        # reply below leaves after every chunk's barrier.  A batch
+        # waits at the door, with no lock held, for a cycle that
+        # compiles to end (scheduler.wait_out_compiling_cycle: what is
+        # pushed behind a compile is the next cycle's larger J bucket
+        # and its compile); once in, it is not held up between chunks,
+        # so a cycle never meets a part of it as a bucket of its own
         chunk = 32
+        wal = self.scheduler.wal
+        group = wal.group if wal is not None else contextlib.nullcontext
+        if local:
+            self.scheduler.wait_out_compiling_cycle()
         for start in range(0, len(local), chunk):
-            with self._lock:
+            with self._lock, group():
                 for i, spec in local[start:start + chunk]:
                     job_id = self.scheduler.submit(spec, now=now)
                     replies[i] = pb.SubmitJobReply(

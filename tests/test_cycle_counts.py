@@ -71,8 +71,7 @@ def _drive(sched, wal, now):
     try:
         fn = next(gen)
         while True:
-            assert wal._group_depth == 0, "a WAL group open across a yield"
-            assert not wal._group_buf, "records buffered across a yield"
+            assert not wal.group_open, "a WAL group open across a yield"
             yields += 1
             fn = gen.send(fn())
     except StopIteration as stop:
@@ -115,7 +114,7 @@ def test_wal_counts_of_a_cycle(tmp_path, route, places):
     # the solves, and one more yield for the dispatch ring where a job started
     assert yields == solves + (1 if places else 0)
     # the cycle's last act left nothing behind it either
-    assert wal._group_depth == 0 and not wal._group_buf
+    assert not wal.group_open
     assert wal.durable_seq == wal.seq
     wal.close()
 
