@@ -990,6 +990,9 @@ def cmd_cstats(args) -> int:
                  t.get("mesh", "-"),
                  t.get("queue_depth"),
                  t.get("candidates"),
+                 # TOUCHED: Job objects the prelude looked up (about 0
+                 # on the default route: the cycle carries table rows)
+                 t.get("prelude_jobs_touched", "-"),
                  # K: the static gang bound of the cycle's solves;
                  # PASS%: the share of its slots x K selection passes
                  # the Pallas kernel ran
@@ -1017,9 +1020,10 @@ def cmd_cstats(args) -> int:
                  t.get("wal_fsyncs"), t.get("topo_frag", "-"))
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
-            "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "K", "PASS%", "PLACED",
-            "NODES", "BACKFILL", "PREEMPT", "SKIP", "DIRTY", "PRELUDE_MS",
-            "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS", "LOCK_MS",
+            "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "TOUCHED", "K",
+            "PASS%", "PLACED", "NODES", "BACKFILL", "PREEMPT", "SKIP",
+            "DIRTY", "PRELUDE_MS", "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS",
+            "LOCK_MS",
             "TOTAL_MS", "LOCK_WAIT_MS", "PERIOD_MS", "FSYNC", "FRAG")))
         return 0
     if getattr(args, "slo", False):

@@ -36,6 +36,25 @@ only the rows a cycle placed or whose reason changed.  The invariant:
 what the commit wrote for that code.  Every event that rewrites a row
 (``upsert``), every gate that flips (``candidates``) and every other
 writer of a pending job's reason (``forget``) resets it to unknown.
+
+Three columns let a cycle carry ROWS from the candidate scan to the
+commit and look a ``Job`` up only for a row Python really visits:
+
+``priority``: the display priority ``_priority_sort`` computed last for
+the row, scattered in one write a cycle.  While a job has a row its
+priority lives HERE (``priority_of``: what a query's reply shows);
+``upsert`` seeds a new row from ``Job.priority`` and leaves an existing
+row's value alone, and ``remove`` hands the value back for the Job.
+
+``eligible``: whether the row's jobtrace "eligible" edge was stamped.
+Cleared when ``upsert`` MAKES the row, which every submit and requeue
+does (one row an incarnation); a hold or a modify rewrites the row in
+place and keeps it, so ``first_sight`` returns a row once.
+
+``written``: the table ``epoch`` of the row's last ``upsert``.  A cycle
+notes the epoch in its prelude, and its commit voids a row written
+since: submitted, held, released, modified or dep-triggered while the
+solve ran with the lock released.
 """
 
 from __future__ import annotations
@@ -61,7 +80,7 @@ STAMP_NONE = -1
 _COLUMNS = ("job_id", "live", "template", "held", "begin", "dep",
             "dep_never", "lic", "gate", "stamped", "submit", "qos", "part",
             "nnum", "cpus", "mem", "acct", "tlimit", "packed", "req", "cls",
-            "cls_gen")
+            "cls_gen", "priority", "eligible", "written")
 
 
 class PendingTable:
@@ -118,6 +137,11 @@ class PendingTable:
         # mask table's generation (derived state: no epoch bump)
         self.cls = np.zeros(cap, np.int32)
         self.cls_gen = np.full(cap, -1, np.int64)
+        # the cycle's own columns (module docstring): display priority,
+        # "eligible" edge stamped, epoch of the last upsert
+        self.priority = np.zeros(cap, np.float64)
+        self.eligible = np.zeros(cap, bool)
+        self.written = np.zeros(cap, np.int64)
 
     def __len__(self) -> int:
         return len(self._row)
@@ -155,7 +179,7 @@ class PendingTable:
 
     def upsert(self, job_id: int, *, template, held, begin, dep,
                dep_never, lic, submit, qos, part, nnum, cpus, mem,
-               acct, tlimit, packed, req) -> None:
+               acct, tlimit, packed, req, priority=0.0) -> None:
         row = self._row.get(job_id)
         if row is None:
             if self._n == len(self.job_id):
@@ -165,6 +189,8 @@ class PendingTable:
             self._row[job_id] = row
             self.job_id[row] = job_id
             self.live[row] = True
+            self.priority[row] = priority
+            self.eligible[row] = False
         self.template[row] = template
         self.held[row] = held
         self.begin[row] = begin
@@ -185,18 +211,46 @@ class PendingTable:
         self.req[row] = req
         self.cls_gen[row] = -1
         self.epoch += 1
+        self.written[row] = self.epoch
         self._dirty += 1
 
-    def remove(self, job_id: int) -> None:
+    def remove(self, job_id: int) -> float | None:
+        """Drop the job's row; returns the priority it held (None: the
+        job had no row)."""
         row = self._row.pop(job_id, None)
         if row is None:
-            return
+            return None
+        priority = float(self.priority[row])
         self.live[row] = False
         self._dead += 1
         self.epoch += 1
         self._dirty += 1
         if self._dead > 64 and self._dead * 2 > self._n:
             self._compact()
+        return priority
+
+    def priority_of(self, job_id: int) -> float | None:
+        """The priority of a job that has a row (None: it has none)."""
+        row = self._row.get(job_id)
+        return None if row is None else float(self.priority[row])
+
+    def written_since(self, job_id: int, epoch: int) -> bool:
+        """Has the job's row been written (or removed) since the table
+        stood at ``epoch``?"""
+        row = self._row.get(job_id)
+        return row is None or bool(self.written[row] > epoch)
+
+    def rows_of(self, job_ids) -> np.ndarray:
+        """The rows of jobs that all have one, in the order given."""
+        row = self._row
+        return np.fromiter((row[j] for j in job_ids), np.int64)
+
+    def first_sight(self, rows: np.ndarray) -> np.ndarray:
+        """Those of ``rows`` no earlier call returned since the row was
+        made (``eligible``), in order."""
+        fresh = rows[~self.eligible[rows]]
+        self.eligible[fresh] = True
+        return fresh
 
     def forget(self, job_id: int) -> None:
         """A pending job's reason was written by someone other than the

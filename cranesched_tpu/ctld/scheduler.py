@@ -66,6 +66,7 @@ from cranesched_tpu.models.priority import (
 from cranesched_tpu.models.solver import (
     COST_SCALE,
     REASON_CONSTRAINT,
+    REASON_PRIORITY,
     REASON_RESOURCE,
     ClusterState,
     FactoredJobBatch,
@@ -177,6 +178,8 @@ def _pull_rows(nodes, idx: np.ndarray) -> np.ndarray:
 _REASON_MAP = {
     REASON_RESOURCE: PendingReason.RESOURCE,
     REASON_CONSTRAINT: PendingReason.CONSTRAINT,
+    # never a solver's answer: the batch cut's own stamp (_cut_batch)
+    REASON_PRIORITY: PendingReason.PRIORITY,
 }
 
 # PendingTable gate code -> the pending reason the old Python candidate
@@ -367,6 +370,55 @@ class _ObservedDict(dict):
         return super().__getitem__(key)
 
 
+class _CycleJobs:
+    """One cycle's candidates, in order, without a list of ``Job``s.
+
+    On the trusted route (``incremental``) they are PendingTable
+    ``rows`` with the job ``ids`` read from them while the prelude held
+    the lock: a compaction during a solve moves rows, never ids.  A Job
+    is looked up only at an index Python really visits (``job``), and
+    ``jobs`` stays None unless a route walks every job (packed,
+    topology, reservations: ``JobScheduler._materialise``).  The rebuild
+    route, the tests' oracle, starts from the list: ``jobs`` is given
+    and ``rows`` is None."""
+
+    __slots__ = ("pending", "rows", "ids", "jobs")
+
+    def __init__(self, pending, rows=None, ids=None, jobs=None):
+        self.pending = pending
+        self.rows = rows
+        self.ids = ids
+        self.jobs = jobs
+
+    def __len__(self) -> int:
+        return len(self.ids) if self.jobs is None else len(self.jobs)
+
+    def __getitem__(self, cut):
+        """A slice, or the rows an index array picks, in its order."""
+        if self.jobs is None:
+            jobs = None
+        elif isinstance(cut, slice):
+            jobs = self.jobs[cut]
+        else:
+            jobs = [self.jobs[i] for i in cut]
+        if self.rows is None:
+            return _CycleJobs(self.pending, jobs=jobs)
+        return _CycleJobs(self.pending, self.rows[cut], self.ids[cut], jobs)
+
+    def job(self, i: int) -> Job | None:
+        """The i-th job; None once it has left ``pending``."""
+        if self.jobs is not None:
+            return self.jobs[i]
+        return self.pending.get(int(self.ids[i]))
+
+    def still_pending(self) -> list[Job]:
+        """The jobs that have not left ``pending``, in order."""
+        if self.jobs is not None:
+            return [j for j in self.jobs if j.job_id in self.pending]
+        get = self.pending.get
+        return [j for j in map(get, self.ids.tolist()) if j is not None]
+
+
 class _MaskTable:
     """Device-resident ``[C, N]`` eligibility-row table — the factored
     form of the per-job ``part_mask``.
@@ -548,13 +600,12 @@ class JobScheduler:
         self._cycle_fp0: tuple | None = None
         self._cycle_usage_denied0: int = 0
         self._skip_trace: dict | None = None
-        # PendingTable row indexes aligned with the in-flight cycle's
-        # candidates/ordered lists (the vectorized row-build gathers)
-        self._cand_rows: np.ndarray | None = None
-        self._ordered_rows: np.ndarray | None = None
-        # PendingTable.generation those rows were taken under: a
-        # compaction since (a cancel while a solve ran) moved them
+        # PendingTable.generation the in-flight cycle's rows were taken
+        # under (a compaction since, a cancel while a solve ran, moved
+        # them) and the table epoch its prelude read (a row written
+        # since is void at the commit)
         self._rows_gen = -1
+        self._plan_epoch = 0
         # running-set priority attrs: rebuilt only when running-set
         # MEMBERSHIP changes (the dict hooks bump _run_epoch on
         # start/finish/requeue) — per cycle only run_time is recomputed
@@ -802,7 +853,10 @@ class JobScheduler:
         self._kick()
 
     def _on_pending_del(self, job_id: int, job: Job) -> None:
-        self._ptable.remove(job_id)
+        priority = self._ptable.remove(job_id)
+        if priority is not None:
+            # the table held it while the job had a row (job_priority)
+            job.priority = priority
         self._array_templates.discard(job_id)
         _MET_PENDING.set(len(self.pending))
         self._kick()
@@ -881,7 +935,8 @@ class JobScheduler:
             acct=self._account_id(spec.account),
             tlimit=time_limit,
             packed=packed,
-            req=req)
+            req=req,
+            priority=job.priority)
 
     def _table_refresh(self, job: Job) -> None:
         """Re-derive a pending job's row after an IN-PLACE mutation
@@ -890,6 +945,14 @@ class JobScheduler:
         if job.job_id in self.pending:
             self._table_upsert(job)
             self._kick()
+
+    def job_priority(self, job: Job) -> float:
+        """The priority a reply shows for ``job``.  A pending job's
+        lives in its PendingTable row, where ``_priority_sort`` scatters
+        a whole cycle's in one write; ``Job.priority`` is brought up to
+        date when the job leaves the table."""
+        priority = self._ptable.priority_of(job.job_id)
+        return job.priority if priority is None else priority
 
     def _set_reason(self, job: Job, reason: PendingReason) -> None:
         """Write a PENDING job's reason from outside ``_commit``'s
@@ -2450,6 +2513,7 @@ class JobScheduler:
             "now": now, "queue_depth": len(self.pending),
             "solver": "", "solve_ms": 0.0,
             "preempted": 0, "backfilled": 0, "num_streams": 1,
+            "prelude_jobs_touched": 0,
         }
         _MET_PENDING.set(len(self.pending))
         self._cycle_now = now
@@ -2485,18 +2549,7 @@ class JobScheduler:
         clock.mark("candidates")
         candidates = self._pending_candidates(now)
         if self.jobtrace is not None and candidates:
-            # first-sight "eligible" stamp per incarnation; the Job
-            # attribute guard keeps repeat cycles at one attr probe per
-            # candidate (the recorder's set probe would already be
-            # cheap, but this avoids even its lock on the common path)
-            fresh = []
-            for job in candidates:
-                if getattr(job, "_trace_eligible", -1) != \
-                        job.requeue_count:
-                    job._trace_eligible = job.requeue_count
-                    fresh.append((job.job_id, job.requeue_count))
-            if fresh:
-                self.jobtrace.stamp_many("eligible", fresh, now)
+            self._stamp_eligible(candidates, now)
         if not candidates:
             # empty cycles still refresh the liveness timestamp (the
             # watchdog's stall detection keys off it) but don't enter
@@ -2513,12 +2566,8 @@ class JobScheduler:
             return []
         limit = self.config.schedule_batch_size
         if len(candidates) > limit:
-            for job in candidates[limit:]:
-                job.pending_reason = PendingReason.PRIORITY
+            self._cut_batch(candidates[limit:])
             candidates = candidates[:limit]
-            if self._cand_rows is not None:
-                self._ptable.stamped[self._cand_rows[limit:]] = STAMP_NONE
-                self._cand_rows = self._cand_rows[:limit]
 
         # snapshot + event capture window (cpp:1437)
         clock.mark("snapshot")
@@ -2528,14 +2577,13 @@ class JobScheduler:
         clock.mark("priority")
         ordered = self._priority_sort(candidates, now)
         clock.mark("build")
-        for job in ordered:
-            # spec epoch for the lock-free solve window: modify_job
-            # REPLACES job.spec (dataclasses.replace), so object
-            # identity detects any mid-solve modification — _commit
-            # voids the placement of a job whose spec changed (e.g. a
-            # partition move validated against the NEW partition while
-            # the solve placed it in the OLD one)
-            job._plan_spec = job.spec
+        # the table epoch of the lock-free solve window: every writer of
+        # a pending job's spec, hold flag or dependencies re-upserts its
+        # row (modify_job REPLACES job.spec, then _table_refresh), so
+        # _commit voids the placement of a row written since (e.g. a
+        # partition move validated against the NEW partition while the
+        # solve placed it in the OLD one)
+        self._plan_epoch = self._ptable.epoch
         jobs_batch, max_nodes = self._build_batch(ordered, avail.shape[0],
                                                   now)
         cost0 = self._ledger.cost0(now, total.shape[0])
@@ -2543,9 +2591,7 @@ class JobScheduler:
         # cycles containing packed/exclusive jobs route to the
         # full-fidelity packed solver (immediate-fit; such jobs don't get
         # backfill reservations this round)
-        orows = self._ordered_rows
-        if orows is not None and len(orows) != len(ordered):
-            orows = None
+        orows = ordered.rows
         if orows is not None:
             packed = bool(self._ptable.packed[orows].any())
         else:
@@ -2553,16 +2599,16 @@ class JobScheduler:
                          or (j.spec.ntasks is not None
                              and j.spec.ntasks != j.spec.node_num)
                          or j.spec.ntasks_per_node_max > 1
-                         for j in ordered)
+                         for j in ordered.jobs)
         if packed:
             state = make_cluster_state(avail, total, alive, cost0)
-            pbatch = self._packed_batch(jobs_batch.dense, ordered)
+            pbatch = self._packed_batch(jobs_batch.dense,
+                                        self._materialise(ordered))
             placements = yield from self._solve_phase(
                 "packed", lambda: solve_packed(
                     state, pbatch, max_nodes=max_nodes)[0])
             started = self._commit(ordered, placements, now,
-                                   tasks=np.asarray(placements.tasks),
-                                   rows=orows)
+                                   tasks=np.asarray(placements.tasks))
             started += self._try_preemption(ordered, now)
             clock.mark("wal")
             self._wal_flush()
@@ -2578,7 +2624,7 @@ class JobScheduler:
         if topo is not None and (
                 bool((self._ptable.nnum[orows] > 1).any())
                 if orows is not None
-                else any(j.spec.node_num > 1 for j in ordered)):
+                else any(j.spec.node_num > 1 for j in ordered.jobs)):
             # gang cycle with a topology configured: route through the
             # best-fit-block solve (topo/place.py).  Backfill is skipped
             # for this cycle — locality dominates reservation lookahead
@@ -2590,11 +2636,14 @@ class JobScheduler:
                      if isinstance(jobs_batch, FactoredJobBatch)
                      else jobs_batch)
             levels = topo.jnp_levels
+            # _note_topo writes a verdict on every job: look them up
+            # here, while no cancel can have taken one away
+            self._materialise(ordered)
             placements, _, topo_info = yield from self._solve_phase(
                 "topo", lambda: solve_greedy_topo(
                     state, dense, levels, max_nodes=max_nodes))
-            self._note_topo(topo, ordered, topo_info)
-            started = self._commit(ordered, placements, now, rows=orows)
+            self._note_topo(topo, ordered.jobs, topo_info)
+            started = self._commit(ordered, placements, now)
             started += self._try_preemption(ordered, now)
             clock.mark("wal")
             self._wal_flush()
@@ -2608,7 +2657,7 @@ class JobScheduler:
             bf_max = max(1, self.config.backfill_max_jobs)
             if len(ordered) > bf_max:
                 started = yield from self._split_backfill_phases(
-                    ordered, orows, jobs_batch, avail, total, alive,
+                    ordered, jobs_batch, avail, total, alive,
                     cost0, max_nodes, now)
                 started += self._try_preemption(ordered, now)
                 clock.mark("wal")
@@ -2621,7 +2670,7 @@ class JobScheduler:
                     self._note_dispatch((yield self._dispatch_phase()))
                 return started
             state = self._timed_state(now, avail, total, alive, cost0)
-            tbatch = self._timed_batch(jobs_batch.dense, ordered)
+            tbatch = self._timed_batch(jobs_batch.dense)
             placements = yield from self._solve_phase(
                 "backfill", lambda: solve_backfill(
                     state, tbatch, edges=self._grid.jnp_edges,
@@ -2636,8 +2685,7 @@ class JobScheduler:
                     resident_ok=True))
             start_buckets = None
 
-        started = self._commit(ordered, placements, now, start_buckets,
-                               rows=orows)
+        started = self._commit(ordered, placements, now, start_buckets)
         started += self._try_preemption(ordered, now)
         clock.mark("wal")
         self._wal_flush()
@@ -2798,7 +2846,7 @@ class JobScheduler:
                 if in_b[i] and blocks[i] >= 0
                 else ("spanning" if crs[i] else ""))
 
-    def _split_backfill_phases(self, ordered, orows, jobs_batch, avail,
+    def _split_backfill_phases(self, ordered, jobs_batch, avail,
                                total, alive, cost0, max_nodes, now):
         """Bounded backfill lookahead (Slurm's sched/bf split): the
         timed solve with full reservation semantics covers only the top
@@ -2809,10 +2857,6 @@ class JobScheduler:
         strictly conservative, like the rest of the grid design)."""
         bf_max = max(1, self.config.backfill_max_jobs)
         head, tail = ordered[:bf_max], ordered[bf_max:]
-        # ``orows``: the table rows of ``ordered`` (or None), cut like it
-        # for the two commits' visit masks
-        head_rows, tail_rows = ((orows[:bf_max], orows[bf_max:])
-                                if orows is not None else (None, None))
 
         # slice the already-built batch — rebuilding it would pay the
         # prelude twice per cycle in exactly the regime this split
@@ -2833,7 +2877,7 @@ class JobScheduler:
         tail_batch = jobs_batch.with_valid(tail_valid)
 
         state = self._timed_state(now, avail, total, alive, cost0)
-        tb = self._timed_batch(head_batch, head)
+        tb = self._timed_batch(head_batch)
         placements, tstate = yield from self._solve_phase(
             "backfill", lambda: solve_backfill(
                 state, tb, edges=self._grid.jnp_edges,
@@ -2841,8 +2885,7 @@ class JobScheduler:
         head_start = np.asarray(placements.start_bucket)
         self._cur_trace["backfilled"] = int(np.sum(
             np.asarray(placements.placed) & (head_start > 0)))
-        started = self._commit(head, placements, now, head_start,
-                               rows=head_rows)
+        started = self._commit(head, placements, now, head_start)
 
         # pass 2: the tail against the tightest bucket of the horizon
         clock = self.cycle_clock
@@ -2862,7 +2905,7 @@ class JobScheduler:
             placed=placements2.placed[bf_max:],
             nodes=placements2.nodes[bf_max:],
             reason=placements2.reason[bf_max:])
-        started += self._commit(tail, tail_placements, now, rows=tail_rows)
+        started += self._commit(tail, tail_placements, now)
         return started
 
     def _solve_phase(self, backend, fn):
@@ -3315,8 +3358,7 @@ class JobScheduler:
             time_limit=batch.time_limit, part_mask=batch.part_mask,
             exclusive=jnp.asarray(exclusive), valid=batch.valid)
 
-    def _timed_batch(self, batch: JobBatch, ordered: list[Job]
-                     ) -> TimedJobBatch:
+    def _timed_batch(self, batch: JobBatch) -> TimedJobBatch:
         # time_limit stays in seconds; the solver derives occupancy
         # windows from the grid edges passed alongside the batch
         return TimedJobBatch(req=batch.req, node_num=batch.node_num,
@@ -3413,7 +3455,8 @@ class JobScheduler:
         task = spec.task_res.encode(self.meta.layout).astype(np.int64)
         return base + task * hi, layout
 
-    def _try_preemption(self, ordered: list[Job], now: float) -> list[int]:
+    def _try_preemption(self, ordered: _CycleJobs, now: float
+                        ) -> list[int]:
         """Device-side what-if (models/preempt.solve_preempt — the
         prefix-sum formulation of the reference's PreemptSegTree) +
         host-authoritative commit.  Runs after the normal solve, so a
@@ -3426,9 +3469,7 @@ class JobScheduler:
         # blocked preemptor candidates, in priority order
         cands = []
         prey_sets = []
-        for job in ordered:
-            if job.job_id not in self.pending:
-                continue  # it placed normally
+        for job in ordered.still_pending():  # the rest placed normally
             if job.pending_reason not in (PendingReason.RESOURCE,
                                           PendingReason.PRIORITY):
                 continue
@@ -3722,30 +3763,75 @@ class JobScheduler:
                     and now - node.last_ping > self.config.craned_timeout):
                 self.on_craned_down(node.node_id, now)
 
-    def _pending_candidates(self, now: float) -> list[Job]:
+    def _pending_candidates(self, now: float) -> _CycleJobs:
         """Candidate scan: one vectorized pass over the PendingTable
-        (incremental mode) or the legacy per-job Python walk.  Both
-        produce the identical candidate list and pending_reason writes
-        (oracle: tests/test_delta_cycle.py)."""
+        (incremental mode), which hands on ROWS, or the legacy per-job
+        Python walk, which hands on the jobs.  Both produce the
+        identical candidates and pending_reason writes (oracle:
+        tests/test_delta_cycle.py, tests/test_prelude_rows.py)."""
+        pending = self.pending
+        self._rows_gen = self._ptable.generation
         if not self.config.incremental:
-            self._cand_rows = None
-            return self._pending_candidates_rebuild(now)
+            jobs = self._pending_candidates_rebuild(now)
+            self._cur_trace["prelude_jobs_touched"] += len(jobs)
+            return _CycleJobs(pending, jobs=jobs)
         pt = self._ptable
         lic_ok = pt.license_mask(self.licenses.sufficient)
         cand_rows, changed, gates = pt.candidates(now, lic_ok)
+        # candidates never get a reason write here — the old loop left
+        # stale reasons on runnable jobs too, and the solve/batch-cut
+        # paths overwrite them downstream
+        blocked = gates != GATE_CANDIDATE
+        for jid, gate in zip(pt.job_id[changed[blocked]].tolist(),
+                             gates[blocked].tolist()):
+            job = pending.get(jid)
+            if job is not None:
+                job.pending_reason = _GATE_REASON[gate]
+        self._cur_trace["prelude_jobs_touched"] += int(blocked.sum())
+        return _CycleJobs(pending, cand_rows, pt.job_id[cand_rows])
+
+    def _materialise(self, cycle_jobs: _CycleJobs) -> list[Job]:
+        """The Job of every row, for a route that walks them all: paid
+        once a cycle, counted in ``prelude_jobs_touched``.  Only while
+        the prelude holds the lock (every id is pending then)."""
+        if cycle_jobs.jobs is None:
+            pending = self.pending
+            cycle_jobs.jobs = [pending[j] for j in cycle_jobs.ids.tolist()]
+            self._cur_trace["prelude_jobs_touched"] += len(cycle_jobs)
+        return cycle_jobs.jobs
+
+    def _stamp_eligible(self, candidates: _CycleJobs, now: float) -> None:
+        """First-sight jobtrace "eligible" stamp, once an incarnation:
+        the table remembers which rows had theirs (``eligible``), so a
+        repeat cycle pays one vectorized compare, not a probe a job."""
+        pt = self._ptable
+        rows = candidates.rows
+        if rows is None:
+            rows = pt.rows_of(job.job_id for job in candidates.jobs)
+        fresh = pt.job_id[pt.first_sight(rows)].tolist()
+        if fresh:
+            pending = self.pending
+            self._cur_trace["prelude_jobs_touched"] += len(fresh)
+            self.jobtrace.stamp_many(
+                "eligible",
+                [(jid, pending[jid].requeue_count) for jid in fresh], now)
+
+    def _cut_batch(self, cut: _CycleJobs) -> None:
+        """The candidates past ``schedule_batch_size`` wait on
+        "Priority".  With rows, the reason is written only where the
+        stamp says the job carries another (as ``_commit`` does)."""
+        if cut.rows is None:
+            for job in cut.jobs:
+                job.pending_reason = PendingReason.PRIORITY
+            return
+        pt = self._ptable
+        told = pt.job_id[
+            cut.rows[pt.stamped[cut.rows] != REASON_PRIORITY]].tolist()
         pending = self.pending
-        jid = pt.job_id
-        for row, gate in zip(changed.tolist(), gates.tolist()):
-            job = pending.get(int(jid[row]))
-            if job is None or gate == GATE_CANDIDATE:
-                # candidates never get a reason write here — the old
-                # loop left stale reasons on runnable jobs too, and the
-                # solve/batch-cut paths overwrite them downstream
-                continue
-            job.pending_reason = _GATE_REASON[gate]
-        self._cand_rows = cand_rows
-        self._rows_gen = pt.generation
-        return [pending[int(j)] for j in jid[cand_rows]]
+        for jid in told:
+            pending[jid].pending_reason = PendingReason.PRIORITY
+        pt.stamped[cut.rows] = REASON_PRIORITY
+        self._cur_trace["prelude_jobs_touched"] += len(told)
 
     def _pending_candidates_rebuild(self, now: float) -> list[Job]:
         """Skip held / future-begin-time jobs (cpp:1374-1413); dependency
@@ -3780,20 +3866,20 @@ class JobScheduler:
             self._account_index[account] = len(self._account_index)
         return self._account_index[account]
 
-    def _priority_sort(self, candidates: list[Job], now: float
-                       ) -> list[Job]:
+    def _priority_sort(self, candidates: _CycleJobs, now: float
+                       ) -> _CycleJobs:
         if self.config.priority_type == "basic" or not candidates:
-            self._ordered_rows = self._cand_rows
             return candidates  # FIFO: id order (JobScheduler.h:183-201)
 
         # vectorized path: gather priority attrs straight from the
         # PendingTable columns (O(1) numpy gathers) instead of touching
         # every Job object; priority output is invariant to the account
         # index permutation so upsert-time registration is parity-safe
-        prows = self._cand_rows
-        vec = prows is not None and len(prows) == len(candidates)
+        pt = self._ptable
+        prows = candidates.rows
+        vec = prows is not None
         if not vec:
-            for job in candidates:
+            for job in candidates.jobs:
                 self._account_id(job.spec.account)
 
         def job_row(job: Job):
@@ -3844,7 +3930,6 @@ class JobScheduler:
         p_valid = np.zeros(JP, bool)
         p_valid[: len(candidates)] = True
         if vec:
-            pt = self._ptable
             kN = len(candidates)
 
             def pcol(src, dt):
@@ -3864,10 +3949,10 @@ class JobScheduler:
                 account=pcol(pt.acct, np.int32),
                 valid=jnp.asarray(p_valid))
         else:
-            p_rows = [job_row(j) for j in candidates]
+            p_rows = [job_row(j) for j in candidates.jobs]
             age = np.zeros(JP, np.int32)
             age[: len(candidates)] = [max(now - j.submit_time, 0.0)
-                                      for j in candidates]
+                                      for j in candidates.jobs]
             pending = PendingPriorityAttrs(
                 age=jnp.asarray(age),
                 qos_prio=col(p_rows, 0, np.int32, JP),
@@ -3912,10 +3997,16 @@ class JobScheduler:
             extra_service=extra_service))
         order = np.asarray(priority_order(jnp.asarray(pri)))
         order = order[order < len(candidates)]  # drop -inf padding rows
-        for job, p in zip(candidates, pri):
-            job.priority = float(p)
-        self._ordered_rows = prows[order] if vec else None
-        return [candidates[i] for i in order]
+        if not vec:
+            # the oracle writes it on every Job too: what the rows
+            # route's scatter is compared with (test_prelude_rows)
+            for job, p in zip(candidates.jobs, pri):
+                job.priority = float(p)
+            prows = pt.rows_of(job.job_id for job in candidates.jobs)
+        # a pending job's priority lives in its row (job_priority): one
+        # scatter, not a write on every Job
+        pt.priority[prows] = pri[:len(candidates)]
+        return candidates[order]
 
     @staticmethod
     def _bucket(n: int, floor: int = 16) -> int:
@@ -4075,8 +4166,11 @@ class JobScheduler:
         return self._mask_table.class_for(
             self._class_key(job, now), lambda: self._mask_for(job, now))
 
-    def _build_batch(self, ordered: list[Job], num_nodes: int,
-                     now: float = 0.0) -> tuple[FactoredJobBatch, int]:
+    def _build_batch(self, ordered: _CycleJobs | list[Job],
+                     num_nodes: int, now: float = 0.0
+                     ) -> tuple[FactoredJobBatch, int]:
+        if isinstance(ordered, list):
+            ordered = _CycleJobs(self.pending, jobs=ordered)
         lay = self.meta.layout
         J = self._bucket(len(ordered))
         req = np.zeros((J, lay.num_dims), np.int32)
@@ -4087,8 +4181,8 @@ class JobScheduler:
         job_class = np.zeros(J, np.int32)
         valid = np.zeros(J, bool)
         self._refresh_mask_table()
-        orows = self._ordered_rows
-        if orows is not None and len(orows) == len(ordered):
+        orows = ordered.rows
+        if orows is not None:
             pt = self._ptable
             kN = len(ordered)
             req[:kN] = pt.req[orows]
@@ -4098,18 +4192,19 @@ class JobScheduler:
             if self.meta.reservations:
                 # reservation-scoped class keys depend on now — can't
                 # cache per mask-table generation
-                for i, job in enumerate(ordered):
+                for i, job in enumerate(self._materialise(ordered)):
                     job_class[i] = self._class_for(job, now)
             else:
                 gen = self._mask_table.generation
                 stale = np.nonzero(pt.cls_gen[orows] != gen)[0]
                 for i in stale.tolist():
                     r = int(orows[i])
-                    pt.cls[r] = self._class_for(ordered[i], now)
+                    pt.cls[r] = self._class_for(ordered.job(i), now)
                     pt.cls_gen[r] = gen
                 job_class[:kN] = pt.cls[orows]
+                self._cur_trace["prelude_jobs_touched"] += len(stale)
         else:
-            for i, job in enumerate(ordered):
+            for i, job in enumerate(ordered.jobs):
                 req[i], node_num[i], time_limit[i] = self._job_row(job)
                 job_class[i] = self._class_for(job, now)
                 valid[i] = True
@@ -4121,7 +4216,7 @@ class JobScheduler:
         # of their [J, K] node lists, and the share of them the
         # candidates can fill (the head picks by one sort, the Pallas
         # tail runs only the passes a slot can use: tail_pass_pct)
-        if ordered:
+        if len(ordered):
             self._cur_trace.update(
                 gang_bound=max_nodes,
                 gang_fill_pct=round(100.0 * int(node_num.sum())
@@ -4136,9 +4231,9 @@ class JobScheduler:
             node_class_np=self._mask_table.node_class())
         return batch, max_nodes
 
-    def _commit(self, ordered: list[Job], placements: Placements,
-                now: float, start_buckets=None, tasks=None,
-                rows=None) -> list[int]:
+    def _commit(self, ordered: _CycleJobs, placements: Placements,
+                now: float, start_buckets=None,
+                tasks=None) -> list[int]:
         """Host authoritative commit + dispatch (cpp:1557-1839): re-check
         against the live ledger and the cycle's reduce events; jobs whose
         nodes died mid-cycle simply stay pending for the next cycle.
@@ -4149,18 +4244,19 @@ class JobScheduler:
         dispatch.
 
         The commit scales with the rows a cycle PLACED or whose reason
-        CHANGED, not with its candidates.  ``rows`` are the PendingTable
-        rows of ``ordered``: an unplaced row whose job already carries
-        the reason the solve returns for it (``stamped``) is not visited
-        in Python at all, since a visit would rewrite the value already
-        there or skip the row as void.  Every row that is visited goes
-        through the whole body below.  Without a row map that can be
-        trusted (none given, another length, a compaction since the
-        rows were taken) every row is visited and every stamp
-        forgotten.  The stamps are written BEFORE the started jobs
-        leave ``pending``: each removal can compact the table and move
-        the rows.  ``commit_visited_pct`` and ``commit_scan_ms`` (cycle
-        trace) say what the pass cost.
+        CHANGED, not with its candidates.  ``ordered.rows`` are the
+        PendingTable rows of the solve's batch: an unplaced row whose
+        job already carries the reason the solve returns for it
+        (``stamped``) is not visited in Python at all, since a visit
+        would rewrite the value already there or skip the row as void,
+        and a row not visited has no Job looked up for it.  Every row
+        that is visited goes through the whole body below.  Without a
+        row map that can be trusted (the rebuild route has none; a
+        compaction since the rows were taken moved them) every row is
+        visited, by its id, and every stamp forgotten.  The stamps are
+        written BEFORE the started jobs leave ``pending``: each removal
+        can compact the table and move the rows.  ``commit_visited_pct``
+        and ``commit_scan_ms`` (cycle trace) say what the pass cost.
 
         Admission checks that are pure array functions (placed/reason
         rows, the mid-cycle dirty-node flag) run as one vectorized
@@ -4210,8 +4306,8 @@ class JobScheduler:
         rejected_rows: list[int] = []
         future_start: list[tuple[Job, list[int]]] = []
         pt = self._ptable
-        if (rows is not None and len(rows) == n
-                and self._rows_gen == pt.generation):
+        rows = ordered.rows
+        if rows is not None and self._rows_gen == pt.generation:
             took, why = placed[:n], reasons[:n]
             visit = np.flatnonzero(
                 took | (why != pt.stamped[rows])).tolist()
@@ -4223,16 +4319,17 @@ class JobScheduler:
             rows = None
             visit = range(n)
             pt.stamped.fill(STAMP_NONE)
+        since = self._plan_epoch
         for i in visit:
-            job = ordered[i]
-            if (job.job_id not in self.pending or job.held
-                    or job.spec is not getattr(job, "_plan_spec",
-                                               job.spec)):
-                # canceled / finalized / held / modified while the
-                # solve ran outside the lock (cycle_phases): its
-                # placement is void; resources were never committed
-                # so nothing to undo.  The job stays pending for the
-                # next cycle, which sees the new spec.
+            job = ordered.job(i)
+            if (job is None or job.job_id not in self.pending or job.held
+                    or pt.written_since(job.job_id, since)):
+                # canceled / finalized / held / modified (its row
+                # written since the prelude) while the solve ran
+                # outside the lock (cycle_phases): its placement is
+                # void; resources were never committed so nothing to
+                # undo.  The job stays pending for the next cycle,
+                # which sees the new spec.
                 if placed[i]:
                     rejected_rows.append(i)
                 elif rows is not None:
@@ -4472,13 +4569,13 @@ class JobScheduler:
         # caches are cleared FIRST so _table_upsert re-encodes rows
         # against the fresh layout; the incremental caches themselves
         # restart cold (the old leader's epochs mean nothing here)
+        for job in self.pending.values():
+            job.priority = self.job_priority(job)   # seeds the new row
         self._ptable = PendingTable(self.meta.layout.num_dims)
         for job in self.pending.values():
             self._table_upsert(job)
         self.meta._snap = None
         self._noop_fp = None
-        self._cand_rows = None
-        self._ordered_rows = None
         self._rows_gen = -1
         self._run_attrs = None
         # the resident ClusterState mirrors the OLD leader's ledger —

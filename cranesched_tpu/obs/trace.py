@@ -65,6 +65,16 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
                              array pulls and that visit; the rest is
                              the ledger batch, WAL records and the
                              dispatch queue of the jobs that start
+    prelude_jobs_touched int Job objects the prelude looked up: the
+                             rows whose gate flipped, the first-sight
+                             "eligible" stamps, the batch cut's newly
+                             cut rows, the rows whose mask class went
+                             stale; every candidate on a route that
+                             walks the jobs (packed, topology,
+                             reservations, the non-incremental
+                             rebuild).  About 0 on a steady cycle of
+                             the default route, whatever the backlog:
+                             the cycle carries PendingTable rows
     nodes_selected   int     sum of node_num over the jobs the cycle
                              started and the backfill head's
                              reservations: the nodes its solves chose

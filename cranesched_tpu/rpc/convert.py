@@ -202,7 +202,10 @@ def step_to_pb(job_id: int, step: Step, node_names) -> pb.StepInfo:
     )
 
 
-def job_to_pb(job: Job, node_names) -> pb.JobInfo:
+def job_to_pb(job: Job, node_names, priority: float | None = None
+              ) -> pb.JobInfo:
+    """``priority``: what ``JobScheduler.job_priority`` says (a pending
+    job's lives in its PendingTable row, not on the Job)."""
     return pb.JobInfo(
         job_id=job.job_id,
         name=job.spec.name,
@@ -219,7 +222,7 @@ def job_to_pb(job: Job, node_names) -> pb.JobInfo:
         exit_code=job.exit_code or 0,
         requeue_count=job.requeue_count,
         qos=job.qos_name,
-        priority=job.priority,
+        priority=job.priority if priority is None else priority,
         array_parent_id=job.array_parent_id or 0,
         array_task_id=(job.array_task_id
                        if job.array_task_id is not None else -1),

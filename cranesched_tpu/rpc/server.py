@@ -622,13 +622,14 @@ class CtldServer:
         self._require_authenticated(self._ident(context), context)
         self._staleness_guard(request.max_staleness, context)
         limit = request.limit or 0
+        priority_of = self.scheduler.job_priority
         with self._lock:
             jobs, names = self._job_snapshot(request)
             truncated = bool(limit) and len(jobs) > limit
             if truncated:
                 jobs = jobs[:limit]
             return pb.QueryJobsReply(
-                jobs=[job_to_pb(j, names) for j in jobs],
+                jobs=[job_to_pb(j, names, priority_of(j)) for j in jobs],
                 truncated=truncated,
                 durable_seq=self._durable_seq(), shard=self.shard_name)
 
@@ -639,6 +640,7 @@ class CtldServer:
         the scheduling cycle for its whole duration."""
         self._require_authenticated(self._ident(context), context)
         self._staleness_guard(request.max_staleness, context)
+        priority_of = self.scheduler.job_priority
         with self._lock:
             jobs, names = self._job_snapshot(request)
         remaining = request.limit or len(jobs)
@@ -650,7 +652,8 @@ class CtldServer:
             # re-take the lock per chunk: Job objects are mutable and
             # the cycle runs between chunks
             with self._lock:
-                chunk = [job_to_pb(j, names) for j in batch]
+                chunk = [job_to_pb(j, names, priority_of(j))
+                         for j in batch]
             yield pb.QueryJobsReply(jobs=chunk,
                                     truncated=truncated and hi == end)
 

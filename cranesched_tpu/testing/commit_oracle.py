@@ -15,7 +15,11 @@ from __future__ import annotations
 
 from cranesched_tpu.ctld.defs import PendingReason
 from cranesched_tpu.ctld.pending_table import STAMP_NONE
-from cranesched_tpu.ctld.scheduler import _REASON_MAP, JobScheduler
+from cranesched_tpu.ctld.scheduler import (
+    _REASON_MAP,
+    JobScheduler,
+    _CycleJobs,
+)
 
 
 def visit_every_row(sched: JobScheduler) -> JobScheduler:
@@ -24,9 +28,9 @@ def visit_every_row(sched: JobScheduler) -> JobScheduler:
     commit = sched._commit
 
     def full_range(ordered, placements, now, start_buckets=None,
-                   tasks=None, rows=None):
-        return commit(ordered, placements, now, start_buckets, tasks,
-                      rows=None)
+                   tasks=None):
+        plain = _CycleJobs(ordered.pending, None, ordered.ids, ordered.jobs)
+        return commit(plain, placements, now, start_buckets, tasks)
 
     sched._commit = full_range
     return sched
