@@ -1016,7 +1016,13 @@ def cmd_cstats(args) -> int:
                  # whole period; a long PERIOD with a long LOCK_WAIT is
                  # the cycle starving behind handlers ("-": a row read
                  # before its cycle closed)
-                 t.get("lock_wait_ms", "-"), t.get("period_ms", "-"),
+                 t.get("lock_wait_ms", "-"),
+                 # the lock ledger: BEHIND, the class that held the
+                 # lock as the cycle's longest wait began ("-": free, or
+                 # a site with no class); RPC_HELD_MS, the handlers' and
+                 # the snapshotter's classed holds in the period
+                 t.get("lock_wait_max_behind") or "-",
+                 t.get("lock_held_rpc_ms", "-"), t.get("period_ms", "-"),
                  t.get("wal_fsyncs"), t.get("topo_frag", "-"))
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
@@ -1024,7 +1030,8 @@ def cmd_cstats(args) -> int:
             "PASS%", "PLACED", "NODES", "BACKFILL", "PREEMPT", "SKIP",
             "DIRTY", "PRELUDE_MS", "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS",
             "LOCK_MS",
-            "TOTAL_MS", "LOCK_WAIT_MS", "PERIOD_MS", "FSYNC", "FRAG")))
+            "TOTAL_MS", "LOCK_WAIT_MS", "BEHIND", "RPC_HELD_MS",
+            "PERIOD_MS", "FSYNC", "FRAG")))
         return 0
     if getattr(args, "slo", False):
         rows = []
@@ -1174,6 +1181,12 @@ def _render_flight(fl: dict, tail: int = 32) -> list[str]:
     if stall:
         out.append(f"LAST STALL label={stall.get('label')!r} "
                    f"t={stall.get('time')}")
+        if "lock_holder" in stall:
+            # the lock ledger's witness: who held the server lock as the
+            # sentry fired ('' = free, or a site with no class)
+            out.append(f"  server lock held by "
+                       f"{stall['lock_holder'] or '-'} for "
+                       f"{stall.get('lock_held_s', 0.0)} s")
         for p in stall.get("phases") or ():
             out.append(f"  phase {p.get('phase')} t={p.get('t')} "
                        f"{p.get('detail', '')}")

@@ -92,6 +92,7 @@ from cranesched_tpu.obs.slo import SloEngine
 from cranesched_tpu.obs.trace import (
     CycleClock,
     CycleTraceRing,
+    LockLedger,
     solve_span,
 )
 from cranesched_tpu.topo.place import solve_greedy_topo
@@ -694,8 +695,12 @@ class JobScheduler:
         # the cycle thread's ledger (obs/trace.py CycleClock): the
         # server's loop and this module mark phase boundaries on it,
         # and cycle_phases' last act writes the period's parts into the
-        # row this cycle ringed (_ledger_row; None when it ringed none)
-        self.cycle_clock = CycleClock()
+        # row this cycle ringed (_ledger_row; None when it ringed none).
+        # Beside it the lock ledger (LockLedger): the classed takes of
+        # the server lock by handlers and the snapshotter, drained into
+        # the same row
+        self.lock_ledger = LockLedger()
+        self.cycle_clock = CycleClock(self.lock_ledger)
         self._ledger_row: dict | None = None
         # per-job lifecycle tracing + SLO plane (obs/jobtrace.py,
         # obs/slo.py): None when JobTrace is off — every stamp site
@@ -732,7 +737,8 @@ class JobScheduler:
         # a silent hang
         self.flight = FlightRecorder(
             event_sink=lambda type, sev, detail="": self.events.emit(
-                type, sev, detail=detail))
+                type, sev, detail=detail),
+            lock_ledger=self.lock_ledger)
         # the in-flight cycle's ``now``: the dispatch-ring drain runs
         # lock-released and stamps committed_durable/dispatched on the
         # same clock the cycle used (virtual in sims, wall in daemons)
@@ -2346,7 +2352,8 @@ class JobScheduler:
         clock.mark("record")
         self._ledger_row = None
         self.profiler_window.tick()
-        clock.annotate = self.profiler_window.capturing
+        clock.annotate = self.lock_ledger.annotate = \
+            self.profiler_window.capturing
         self.flight.stamp("cycle_begin")
         clock.mark("drain")
         self._wal_begin()
@@ -2401,12 +2408,22 @@ class JobScheduler:
                 self._cycle_end.wait(1.0)
 
     def _close_cycle_ledger(self) -> None:
-        """The period ends here, under the lock: its parts go into the
-        row this cycle ringed, in place (QueryStats serialises the ring
-        under the same lock).  dispatch_ms stays _note_dispatch's."""
+        """The period ends here, under the lock: its parts, and what
+        the lock ledger's classes booked since the last close, go into
+        the row this cycle ringed, in place (QueryStats serialises the
+        ring under the same lock).  dispatch_ms stays _note_dispatch's.
+        A cycle that ringed no row (no candidate) still closes its
+        period and drains the ledger: its classes' seconds go to
+        crane_server_lock_seconds_total alone."""
         fields = self.cycle_clock.close()
+        fields.update(self.lock_ledger.drain(fields))
         row = self._ledger_row
         if row is not None:
+            if row is self._skip_trace:
+                # the coalesced skip row carries its LATEST period: a
+                # class that took the lock in an earlier one goes
+                for key in [k for k in row if k.startswith("rpc_")]:
+                    del row[key]
             row.update(fields)
             if self.cycle_clock.annotate:
                 row["profiled"] = True
@@ -2866,7 +2883,7 @@ class JobScheduler:
         # is exactly the path the [C, N] table exists for.
         import jax
 
-        hb = self._bucket(len(head))
+        hb = self._job_bucket(len(head))
         head_batch = jax.tree.map(lambda x: x[:hb], jobs_batch.dense)
         # rows past len(head) in the bucketed slice are REAL tail jobs —
         # invalidate them or they would place in both passes
@@ -3925,7 +3942,7 @@ class JobScheduler:
 
         # pad both batches to bucketed shapes (same rationale as
         # _build_batch: keep the jit cache small)
-        JP = self._bucket(len(candidates))
+        JP = self._job_bucket(len(candidates))
 
         p_valid = np.zeros(JP, bool)
         p_valid[: len(candidates)] = True
@@ -4018,60 +4035,27 @@ class JobScheduler:
             b *= 2
         return b
 
-    def warm_jit_buckets(self, max_pending: int,
-                         max_running: int = 0) -> int:
-        """Pre-trace the jitted priority model for every padded-shape
-        bucket steady-state traffic is expected to hit.
-
-        Boot-time only, no lock needed.  Without this, the per-bucket
-        XLA compile (~0.5s on a CPU backend) fires inside the first
-        cycle whose queue crosses the bucket — in the prelude, under
-        the server lock, where it stalls every reader for the length of
-        the compile and the query-plane p99 becomes the compiler's
-        latency rather than the server's.
-
-        Warms (pending, running) bucket pairs: every pending bucket up
-        to ``max_pending`` crossed with running buckets {16,
-        bucket(max_running)} — after the first full cycle the running
-        bucket jumps straight to the cluster's slot count, so the
-        intermediate running buckets are rarely seen in steady state.
-        Returns the number of shape variants traced."""
-        if self.config.priority_type == "basic":
-            return 0  # FIFO path has no jitted priority solve
-        num_accounts = self._bucket(len(self._account_index))
-        rps = {16}
-        if max_running > 0:
-            rps.add(self._bucket(max_running))
-        jps = [16]
-        while jps[-1] < max_pending:
-            jps.append(jps[-1] * 2)
-        traced = 0
-        for rp in sorted(rps):
-            running = RunningPriorityAttrs(
-                qos_prio=jnp.zeros(rp, jnp.int32),
-                part_prio=jnp.zeros(rp, jnp.int32),
-                node_num=jnp.zeros(rp, jnp.int32),
-                cpus=jnp.zeros(rp, jnp.float32),
-                mem=jnp.zeros(rp, jnp.float32),
-                account=jnp.zeros(rp, jnp.int32),
-                run_time=jnp.zeros(rp, jnp.int32),
-                valid=jnp.zeros(rp, bool))
-            for jp in jps:
-                pending = PendingPriorityAttrs(
-                    age=jnp.zeros(jp, jnp.int32),
-                    qos_prio=jnp.zeros(jp, jnp.int32),
-                    part_prio=jnp.zeros(jp, jnp.int32),
-                    node_num=jnp.zeros(jp, jnp.int32),
-                    cpus=jnp.zeros(jp, jnp.float32),
-                    mem=jnp.zeros(jp, jnp.float32),
-                    account=jnp.zeros(jp, jnp.int32),
-                    valid=jnp.zeros(jp, bool))
-                pri = multifactor_priority(
-                    pending, running, self.config.priority_weights,
-                    num_accounts)
-                priority_order(pri).block_until_ready()
-                traced += 1
-        return traced
+    @staticmethod
+    def _job_bucket(n: int) -> int:
+        """The row count a cycle's candidates are padded to: 256, then
+        1,024, then by twos.  A step of this ladder is a compile of the
+        priority model and of the solve on the cycle thread, with batch
+        ingest held at the door: 15 s cold at 5,000 nodes, 3 s from a
+        warm cache, and nothing drains or starts meanwhile.  A closed
+        loop of 250-spec batches stands at 500 candidates; a period
+        half as long again (the snapshot's hold, once a minute) leaves
+        750, and the cycle that runs between two 32-spec chunks of the
+        stream's LAST batch leaves 26 to 218 behind.  By twos from 16
+        each of those was a step first met minutes into a run or at
+        its very end, and met cold it outlasted the 5 s a job that
+        fits may wait.  So every cycle under 257 candidates is one
+        program and every cycle under 1,025 another, both compiled
+        with the first two batches; the price is the scan's 1,024
+        steps where 500 jobs wait (ARCHITECTURE.md, the ladder of J)."""
+        b = 256
+        while b < n:
+            b *= 4 if b < 1024 else 2
+        return b
 
     def _mask_for(self, job: Job, now: float = 0.0) -> np.ndarray:
         if self._mask_cache_epoch != self.meta.resv_epoch:
@@ -4172,7 +4156,7 @@ class JobScheduler:
         if isinstance(ordered, list):
             ordered = _CycleJobs(self.pending, jobs=ordered)
         lay = self.meta.layout
-        J = self._bucket(len(ordered))
+        J = self._job_bucket(len(ordered))
         req = np.zeros((J, lay.num_dims), np.int32)
         node_num = np.zeros(J, np.int32)
         time_limit = np.zeros(J, np.int32)

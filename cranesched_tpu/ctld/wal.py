@@ -34,6 +34,7 @@ import dataclasses
 import glob
 import json
 import os
+import time
 from typing import IO
 
 from cranesched_tpu.obs import REGISTRY as _OBS
@@ -275,6 +276,9 @@ class WriteAheadLog:
         self._group_depth = 0
         self._group_buf: list[tuple[int, str]] = []
         self.fsync_total = 0    # actual os.fsync calls (fsync=True only)
+        # seconds inside those calls: what it grows by over a hold of the
+        # server lock is the hold's wal part (obs/trace.py LockLedger)
+        self.fsync_seconds = 0.0
         self.groups_total = 0   # non-empty group flushes
         self._fh: IO[str] = open(path, "a", encoding="utf-8")
 
@@ -324,7 +328,9 @@ class WriteAheadLog:
         self._fh.write(line + "\n")
         self._fh.flush()
         if self.fsync:
+            t0 = time.perf_counter()
             os.fsync(self._fh.fileno())
+            self.fsync_seconds += time.perf_counter() - t0
             self.fsync_total += 1
             _MET_WAL_FSYNC.inc()
         self.durable_seq = self.seq
@@ -369,7 +375,9 @@ class WriteAheadLog:
         self._fh.write("".join(line + "\n" for _seq, line in buf))
         self._fh.flush()
         if self.fsync:
+            t0 = time.perf_counter()
             os.fsync(self._fh.fileno())
+            self.fsync_seconds += time.perf_counter() - t0
             self.fsync_total += 1
             _MET_WAL_FSYNC.inc()
         # the tail buffer feeds HaFetchWal: records enter it only after

@@ -192,14 +192,18 @@ class Snapshotter(threading.Thread):
         """One capture+rotate+persist+prune pass.  Returns the snapshot
         seq (0 = skipped, nothing new)."""
         from cranesched_tpu import ha as _ha
+        ledger = self.scheduler.lock_ledger
         t0 = time.perf_counter()
         with self.lock:
-            t_locked = time.perf_counter()
-            seq = self.wal.durable_seq
-            if seq - self.last_seq < self.min_records:
-                return 0
-            doc = capture_snapshot(self.scheduler, seq)
-            self.wal.rotate()
+            t_locked = ledger.enter(ledger.SNAPSHOT, t0)
+            try:
+                seq = self.wal.durable_seq
+                if seq - self.last_seq < self.min_records:
+                    return 0
+                doc = capture_snapshot(self.scheduler, seq)
+                self.wal.rotate()
+            finally:
+                ledger.leave()
         # what every handler and the cycle waited behind (the wait FOR
         # the lock is not in it), then the whole pass with its save
         held = time.perf_counter() - t_locked

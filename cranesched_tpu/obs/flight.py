@@ -35,9 +35,6 @@ from typing import Callable, Optional
 
 from cranesched_tpu.obs.metrics import REGISTRY as _OBS
 
-_MET_STAMPS = _OBS.counter(
-    "crane_flight_stamps_total",
-    "phase stamps appended to the flight-recorder ring")
 _MET_STALLS = _OBS.counter(
     "crane_flight_stalls_total",
     "stall-sentry firings (armed deadline passed; stacks captured)")
@@ -50,8 +47,6 @@ _MET_XLA_MISSES = _OBS.counter(
 _MET_XLA_ENTRIES = _OBS.gauge(
     "crane_xla_cache_entries",
     "executables in the persistent XLA cache directory")
-
-_STAMPS_CELL = _MET_STAMPS.labels()
 
 
 def dump_all_stacks() -> dict[str, list[str]]:
@@ -75,15 +70,20 @@ class FlightRecorder:
     The scheduler owns one instance and stamps its cycle phases; the
     server's cycle loop arms the sentry before each cycle and disarms
     after.  A deadline that passes while armed fires ONCE: the sentry
-    snapshots every thread's stack plus the ring tail into
+    snapshots every thread's stack plus the ring tail (and the lock
+    ledger's holder of the server lock, with the age of its hold) into
     :attr:`last_stall`, bumps ``crane_flight_stalls_total``, emits a
     ``flight_stall`` event through ``event_sink``, and disarms (the
     next cycle re-arms).  Nothing here ever raises into the loop."""
 
     def __init__(self, capacity: int = 256,
-                 event_sink: Optional[Callable] = None):
+                 event_sink: Optional[Callable] = None,
+                 lock_ledger=None):
         self.capacity = max(int(capacity), 16)
         self.event_sink = event_sink
+        # obs/trace.py LockLedger or None: who holds the server lock,
+        # and since when, as the sentry fires
+        self.lock_ledger = lock_ledger
         self._ring: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
         self.stalls_total = 0
@@ -105,7 +105,6 @@ class FlightRecorder:
             rec["detail"] = detail
         with self._lock:
             self._ring.append(rec)
-        _STAMPS_CELL.inc()
 
     # -- the stall sentry --
 
@@ -160,6 +159,11 @@ class FlightRecorder:
             phases = list(self._ring)[-16:]
         stall = {"time": time.time(), "label": label,
                  "phases": phases, "stacks": stacks}
+        holder = ""
+        if self.lock_ledger is not None:
+            holder, held_s = self.lock_ledger.current()
+            stall["lock_holder"] = holder
+            stall["lock_held_s"] = round(held_s, 3)
         with self._lock:
             self.last_stall = stall
             self.stalls_total += 1
@@ -169,7 +173,8 @@ class FlightRecorder:
             self.event_sink(
                 "flight_stall", "error",
                 detail=f"{label} stalled; last phase {last}; "
-                       f"{len(stacks)} thread stacks captured")
+                       + (f"lock held by {holder}; " if holder else "")
+                       + f"{len(stacks)} thread stacks captured")
 
     # -- reading --
 

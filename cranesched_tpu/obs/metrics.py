@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import bisect
 import http.server
-import json
 import math
 import threading
 from typing import Optional
@@ -350,11 +349,3 @@ def stats_doc(registry: Optional[MetricsRegistry] = None) -> dict:
     """The dict merged under ``"metrics"`` in QueryStats replies."""
     return (registry or REGISTRY).snapshot()
 
-
-if __name__ == "__main__":  # tiny smoke: python -m cranesched_tpu.obs.metrics
-    c = REGISTRY.counter("crane_demo_total", "demo")
-    c.inc(3, kind="x")
-    h = REGISTRY.histogram("crane_demo_seconds", "demo latency")
-    h.observe(0.004)
-    print(REGISTRY.expose())
-    print(json.dumps(REGISTRY.snapshot(), indent=1))

@@ -105,6 +105,15 @@ period: with dispatch_ms and unnamed_ms they sum to period_ms.
     lock_wait_ms     waiting for the server lock, over every take of
                      the cycle (the first and one after each closure)
     lock_wait_max_ms the longest single one of those waits
+    lock_wait_max_behind  the lock ledger's holder tag as that longest
+                     wait began: "submit", "submit_batch", "query",
+                     "stats", "snapshot", or "" (the lock was free, or
+                     held at a site with no class).  The lock is not
+                     fair: it can pass through other holders before the
+                     cycle gets it, so read a long wait together with
+                     the row's rpc_<c>_held_max_ms (a 2 s wait that
+                     began behind "submit_batch" beside a 2 s
+                     rpc_snapshot_held_max_ms was the snapshot's)
     sim_ms           first lock take to cycle_phases: the sim node
                      plane's advance_to (0 on a real node plane) and
                      the federation's lease expiry
@@ -134,14 +143,48 @@ period: with dispatch_ms and unnamed_ms they sum to period_ms.
     gc_ms            growth over the period of the process-wide
                      collector-pause accumulator (``GC_PAUSES``); lies
                      INSIDE the parts above, on whichever thread paid
+    cpu_ms           the process's CPU time over the period (one
+                     time.process_time() read a cycle, every thread):
+                     a period of seconds with a cpu_ms of tens is a
+                     process that did not run or slept in a syscall,
+                     cpu_ms about the period a thread that kept the
+                     interpreter
     profiled         bool, only on rows of cycles that ran inside a
                      ProfilerWindow capture
+
+The lock ledger (``LockLedger``; all ms, the handlers' side of the
+server lock, drained by the cycle thread into the same row as the
+period closes).  A class ``<c>`` is one of submit (SubmitBatchJob),
+submit_batch (each 32-spec hold of SubmitBatchJobs), query
+(QueryJobsInfo, each hold of QueryJobsStream), stats (QueryStats),
+snapshot (Snapshotter.snap_once); its fields are written only on rows
+of periods in which it took the lock.
+
+    rpc_<c>_n            takes of the lock that ENDED in the period
+    rpc_<c>_wait_ms      sum of their waits for the lock
+    rpc_<c>_wait_max_ms  the longest single one
+    rpc_<c>_held_ms      sum of their holds
+    rpc_<c>_held_max_ms  the longest single one
+    rpc_submit_wal_ms, rpc_submit_batch_wal_ms
+                         the part of the holds inside os.fsync (growth
+                         of the WAL's fsync_seconds over each hold)
+    rpc_submit_batch_door_ms  OUTSIDE any hold: batches waiting out a
+                         cycle that compiles (wait_out_compiling_cycle)
+    rpc_query_snapshot_ms    _job_snapshot: name map, queue copy,
+                         filters, sort
+    rpc_query_convert_ms     job_to_pb over the rows
+    lock_held_rpc_ms     sum of rpc_<c>_held_ms over the classes
+    lock_unaccounted_ms  period_ms - lock_held_work_ms -
+                         lock_held_rpc_ms: the lock free, or held at a
+                         site with no class (by subtraction, so that a
+                         holder nobody named shows); on every row
 
 ``solve_span`` wraps a solve closure in ``jax.profiler.TraceAnnotation``
 so a captured device trace lines up with cycle phases; it degrades to a
 no-op when the profiler is unavailable (CPU CI containers).  Inside a
 capture (and only then) ``CycleClock`` puts each phase on the
-profiler's clock too, as ``crane:cycle:<phase>``.
+profiler's clock too, as ``crane:cycle:<phase>``, and ``LockLedger``
+each classed hold, as ``crane:rpc:<class>`` on the handler's thread.
 """
 
 from __future__ import annotations
@@ -152,6 +195,8 @@ import gc
 import threading
 import time
 from typing import Iterator
+
+from cranesched_tpu.obs.metrics import REGISTRY
 
 
 class CycleTraceRing:
@@ -207,6 +252,139 @@ LOCKED_PARTS = ("sim", "record", "drain", "candidates", "snapshot",
 PARTS = ("sleep", "lock_wait") + LOCKED_PARTS + (
     "solve_enqueue", "solve_device_wait", "solve_host")
 
+_MET_LOCK_SECONDS = REGISTRY.counter(
+    "crane_server_lock_seconds_total",
+    "seconds threads waited for (kind=wait) and held (kind=held) the "
+    "server lock, by holder class; fed once a cycle from the lock "
+    "ledger, holder=cycle from the cycle thread's own clock")
+
+
+class LockLedger:
+    """The handlers' side of the server lock.  A thread that takes the
+    lock at a classed site reads the clock before its own plain
+    ``with lock:``, calls ``enter`` as the first statement inside and
+    ``leave`` as the last (a ``finally``); both run WHILE THE THREAD
+    HOLDS THE SERVER LOCK, so the accumulators need no lock of their
+    own: one fixed slot a class in a flat list of floats.  The lock
+    stays the caller's bare ``threading.Lock``; nothing here stands
+    between a thread and ``acquire``.  The cycle thread drains the
+    sums into its row as the period closes (``drain``, under the lock
+    too, so no hold straddles a row's edge).
+
+    ``holder`` names the class that holds the lock now ("" for none, or
+    for a site with no class): what the cycle thread reads as it starts
+    to wait, and the stall sentry when it fires.  While ``annotate`` is
+    set (the scheduler sets it once a cycle, as it sets
+    ``CycleClock.annotate``) each hold is also a ``crane:rpc:<class>``
+    TraceAnnotation on the holder's thread."""
+
+    HOLDERS = ("submit", "submit_batch", "query", "stats", "snapshot")
+    SUBMIT, SUBMIT_BATCH, QUERY, STATS, SNAPSHOT = range(5)
+    #: a class's slot: n, wait sum, wait max, held sum, held max
+    _SLOT = 5
+    #: sums booked within a class's holds (door: beside them), in the
+    #: slots after the classes': (the class, <class>_<part>)
+    PARTS = ((SUBMIT, "submit_wal"), (SUBMIT_BATCH, "submit_batch_wal"),
+             (SUBMIT_BATCH, "submit_batch_door"),
+             (QUERY, "query_snapshot"), (QUERY, "query_convert"))
+    _SIZE = _SLOT * len(HOLDERS) + len(PARTS)
+    (SUBMIT_WAL, SUBMIT_BATCH_WAL, SUBMIT_BATCH_DOOR, QUERY_SNAPSHOT,
+     QUERY_CONVERT) = range(_SIZE - len(PARTS), _SIZE)
+
+    def __init__(self):
+        self.annotate = False
+        self.holder = ""
+        self._acc = [0.0] * self._SIZE
+        self._slot = 0
+        self._t_enter = 0.0
+        self._span = None
+        self._cells = [
+            (_MET_LOCK_SECONDS.labels(holder=name, kind="wait"),
+             _MET_LOCK_SECONDS.labels(holder=name, kind="held"))
+            for name in self.HOLDERS + ("cycle",)]
+
+    def enter(self, holder: int, t0: float) -> float:
+        """First statement inside the hold: ``t0`` is the caller's clock
+        read before its ``with``.  Returns the instant the hold began."""
+        t1 = time.perf_counter()
+        acc = self._acc
+        i = holder * self._SLOT
+        wait = t1 - t0
+        acc[i] += 1.0
+        acc[i + 1] += wait
+        if wait > acc[i + 2]:
+            acc[i + 2] = wait
+        self._slot = i
+        self._t_enter = t1
+        self.holder = self.HOLDERS[holder]
+        if self.annotate:
+            from jax.profiler import TraceAnnotation
+            self._span = TraceAnnotation("crane:rpc:" + self.holder)
+            self._span.__enter__()
+        return t1
+
+    def add(self, part: int, seconds: float) -> None:
+        """Book ``seconds`` to a part; call it inside the hold."""
+        self._acc[part] += seconds
+
+    def leave(self) -> None:
+        """Last act inside the hold."""
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        self.holder = ""
+        acc = self._acc
+        i = self._slot
+        held = time.perf_counter() - self._t_enter
+        acc[i + 3] += held
+        if held > acc[i + 4]:
+            acc[i + 4] = held
+
+    def current(self) -> tuple[str, float]:
+        """The holder now and how long its hold has lasted (the stall
+        sentry's read, from another thread: a torn pair is possible and
+        harmless)."""
+        holder = self.holder
+        if not holder:
+            return "", 0.0
+        return holder, time.perf_counter() - self._t_enter
+
+    def drain(self, clock_fields: dict) -> dict:
+        """The period's fields, given the clock's, and fresh sums; under
+        the lock.  Also feeds crane_server_lock_seconds_total, here, off
+        every handler's path."""
+        acc, self._acc = self._acc, [0.0] * self._SIZE
+        fields: dict = {}
+        held_rpc = 0.0
+        for k, name in enumerate(self.HOLDERS):
+            i = k * self._SLOT
+            if not acc[i]:
+                continue
+            held_ms = round(acc[i + 3] * 1e3, 3)
+            held_rpc += held_ms
+            prefix = "rpc_" + name
+            fields[prefix + "_n"] = int(acc[i])
+            fields[prefix + "_wait_ms"] = round(acc[i + 1] * 1e3, 3)
+            fields[prefix + "_wait_max_ms"] = round(acc[i + 2] * 1e3, 3)
+            fields[prefix + "_held_ms"] = held_ms
+            fields[prefix + "_held_max_ms"] = round(acc[i + 4] * 1e3, 3)
+            wait_cell, held_cell = self._cells[k]
+            wait_cell.inc(acc[i + 1])
+            held_cell.inc(acc[i + 3])
+        for k, (holder, name) in enumerate(self.PARTS, self.SUBMIT_WAL):
+            if acc[holder * self._SLOT]:
+                fields["rpc_" + name + "_ms"] = round(acc[k] * 1e3, 3)
+        wait_cell, held_cell = self._cells[-1]
+        wait_cell.inc(clock_fields["lock_wait_ms"] / 1e3)
+        held_cell.inc(clock_fields["lock_held_work_ms"] / 1e3)
+        # from the rounded fields, so that the three sum to period_ms on
+        # the row as it is read
+        fields["lock_held_rpc_ms"] = round(held_rpc, 3)
+        fields["lock_unaccounted_ms"] = round(
+            clock_fields["period_ms"] - clock_fields["lock_held_work_ms"]
+            - fields["lock_held_rpc_ms"], 3)
+        return fields
+
 
 class CycleClock:
     """The cycle thread's partitioning clock: ``mark(name)`` reads the
@@ -221,19 +399,26 @@ class CycleClock:
     ProfilerWindow once per cycle) each mark also closes the previous
     and opens the next ``crane:cycle:<phase>`` TraceAnnotation, so the
     host phases land in the profiler's trace beside the device ops.
-    Outside a capture no profiler object is touched."""
+    Outside a capture no profiler object is touched.
+
+    With a ``LockLedger`` beside it, a mark that opens a ``lock_wait``
+    reads the ledger's ``holder`` tag once: who the cycle thread began
+    to wait behind."""
 
     GLUE = "unnamed"
 
-    def __init__(self):
+    def __init__(self, ledger: LockLedger | None = None):
         GC_PAUSES.install()
         self.annotate = False
+        self.ledger = ledger
         self._span = None
         self._phase = self.GLUE
         self._parts: dict[str, float] = {}
         self._lock_wait_max = 0.0
+        self._behind = self._behind_max = ""
         self._t = self._t_open = time.perf_counter()
         self._gc_open = GC_PAUSES.total_s
+        self._cpu_open = time.process_time()
 
     def mark(self, name: str) -> None:
         t = time.perf_counter()
@@ -243,6 +428,9 @@ class CycleClock:
         self._parts[phase] = self._parts.get(phase, 0.0) + dt
         if phase == "lock_wait" and dt > self._lock_wait_max:
             self._lock_wait_max = dt
+            self._behind_max = self._behind
+        if name == "lock_wait" and self.ledger is not None:
+            self._behind = self.ledger.holder
         self._phase = name
         if self._span is not None or self.annotate:
             self._annotate(name)
@@ -263,9 +451,12 @@ class CycleClock:
         ms = {name: parts.get(name, 0.0) * 1e3 for name in PARTS}
         fields = {name + "_ms": round(v, 3) for name, v in ms.items()}
         period_ms = (self._t - self._t_open) * 1e3
+        cpu = time.process_time()
         fields.update(
             period_ms=round(period_ms, 3),
+            cpu_ms=round((cpu - self._cpu_open) * 1e3, 3),
             lock_wait_max_ms=round(self._lock_wait_max * 1e3, 3),
+            lock_wait_max_behind=self._behind_max,
             lock_held_work_ms=round(
                 sum(ms[name] for name in LOCKED_PARTS), 3),
             # by subtraction, so a phase no field names shows up here
@@ -275,7 +466,9 @@ class CycleClock:
         )
         self._t_open = self._t
         self._lock_wait_max = 0.0
+        self._behind_max = ""
         self._gc_open = GC_PAUSES.total_s
+        self._cpu_open = cpu
         return fields
 
 

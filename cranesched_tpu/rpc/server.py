@@ -321,6 +321,12 @@ class CtldServer:
                                  shard=owner, redirect_address=address,
                                  map_epoch=self._map_epoch())
 
+    def _wal_fsync_seconds(self) -> float:
+        """The WAL's running total of seconds in ``os.fsync``: what it
+        grows by over a hold is the hold's ``wal`` part."""
+        wal = self.scheduler.wal
+        return wal.fsync_seconds if wal is not None else 0.0
+
     def SubmitBatchJob(self, request, context):
         try:
             spec = spec_from_pb(request.spec)
@@ -338,18 +344,31 @@ class CtldServer:
             return self._forward_submit(request.spec, spec.partition,
                                         *owner, request.forwarded)
         now = self._now()
+        # the lock ledger (obs/trace.py LockLedger): the clock is read
+        # either side of this thread's own plain take of the lock, the
+        # wait and the hold are booked under it
+        ledger = self.scheduler.lock_ledger
+        t0 = time.perf_counter()
         with self._lock:
-            job_id = self.scheduler.submit(spec, now=now)
-            if (request.forwarded and job_id
-                    and self.scheduler.jobtrace is not None):
-                # span the shard hop on the fresh (job_id, 0) timeline:
-                # t = when the forward LEFT the misrouted shard, so the
-                # submit->fed_forwarded segment shows the hop latency
-                # (clocks are the federation's, skew rides as detail)
-                t_fwd = request.forwarded_at or now
-                self.scheduler.jobtrace.stamp(
-                    job_id, 0, "fed_forwarded", t_fwd,
-                    skew=round(now - t_fwd, 6))
+            ledger.enter(ledger.SUBMIT, t0)
+            fsync_s = self._wal_fsync_seconds()
+            try:
+                job_id = self.scheduler.submit(spec, now=now)
+                if (request.forwarded and job_id
+                        and self.scheduler.jobtrace is not None):
+                    # span the shard hop on the fresh (job_id, 0)
+                    # timeline: t = when the forward LEFT the misrouted
+                    # shard, so the submit->fed_forwarded segment shows
+                    # the hop latency (clocks are the federation's, skew
+                    # rides as detail)
+                    t_fwd = request.forwarded_at or now
+                    self.scheduler.jobtrace.stamp(
+                        job_id, 0, "fed_forwarded", t_fwd,
+                        skew=round(now - t_fwd, 6))
+            finally:
+                ledger.add(ledger.SUBMIT_WAL,
+                           self._wal_fsync_seconds() - fsync_s)
+                ledger.leave()
         return pb.SubmitJobReply(
             job_id=job_id, error="" if job_id else "rejected",
             shard=self.shard_name, map_epoch=self._map_epoch())
@@ -394,16 +413,33 @@ class CtldServer:
         chunk = 32
         wal = self.scheduler.wal
         group = wal.group if wal is not None else contextlib.nullcontext
+        ledger = self.scheduler.lock_ledger
+        door_s = 0.0
         if local:
+            t0 = time.perf_counter()
             self.scheduler.wait_out_compiling_cycle()
+            door_s = time.perf_counter() - t0
         for start in range(0, len(local), chunk):
-            with self._lock, group():
-                for i, spec in local[start:start + chunk]:
-                    job_id = self.scheduler.submit(spec, now=now)
-                    replies[i] = pb.SubmitJobReply(
-                        job_id=job_id,
-                        error="" if job_id else "rejected",
-                        shard=self.shard_name)
+            t0 = time.perf_counter()
+            with self._lock:
+                ledger.enter(ledger.SUBMIT_BATCH, t0)
+                fsync_s = self._wal_fsync_seconds()
+                try:
+                    with group():
+                        for i, spec in local[start:start + chunk]:
+                            job_id = self.scheduler.submit(spec, now=now)
+                            replies[i] = pb.SubmitJobReply(
+                                job_id=job_id,
+                                error="" if job_id else "rejected",
+                                shard=self.shard_name)
+                finally:
+                    # the wait at the door was outside any hold: booked
+                    # under the batch's first, where the sums are safe
+                    ledger.add(ledger.SUBMIT_BATCH_DOOR, door_s)
+                    door_s = 0.0
+                    ledger.add(ledger.SUBMIT_BATCH_WAL,
+                               self._wal_fsync_seconds() - fsync_s)
+                    ledger.leave()
         return pb.SubmitJobsReply(replies=replies)
 
     def CancelJob(self, request, context):
@@ -623,15 +659,24 @@ class CtldServer:
         self._staleness_guard(request.max_staleness, context)
         limit = request.limit or 0
         priority_of = self.scheduler.job_priority
+        ledger = self.scheduler.lock_ledger
+        t0 = time.perf_counter()
         with self._lock:
-            jobs, names = self._job_snapshot(request)
-            truncated = bool(limit) and len(jobs) > limit
-            if truncated:
-                jobs = jobs[:limit]
-            return pb.QueryJobsReply(
-                jobs=[job_to_pb(j, names, priority_of(j)) for j in jobs],
-                truncated=truncated,
-                durable_seq=self._durable_seq(), shard=self.shard_name)
+            t1 = ledger.enter(ledger.QUERY, t0)
+            try:
+                jobs, names = self._job_snapshot(request)
+                t2 = time.perf_counter()
+                ledger.add(ledger.QUERY_SNAPSHOT, t2 - t1)
+                truncated = bool(limit) and len(jobs) > limit
+                if truncated:
+                    jobs = jobs[:limit]
+                rows = [job_to_pb(j, names, priority_of(j)) for j in jobs]
+                ledger.add(ledger.QUERY_CONVERT, time.perf_counter() - t2)
+                return pb.QueryJobsReply(
+                    jobs=rows, truncated=truncated,
+                    durable_seq=self._durable_seq(), shard=self.shard_name)
+            finally:
+                ledger.leave()
 
     def QueryJobsStream(self, request, context):
         """Server-streaming query (reference Crane.proto:1576-1590):
@@ -641,8 +686,15 @@ class CtldServer:
         self._require_authenticated(self._ident(context), context)
         self._staleness_guard(request.max_staleness, context)
         priority_of = self.scheduler.job_priority
+        ledger = self.scheduler.lock_ledger
+        t0 = time.perf_counter()
         with self._lock:
-            jobs, names = self._job_snapshot(request)
+            t1 = ledger.enter(ledger.QUERY, t0)
+            try:
+                jobs, names = self._job_snapshot(request)
+                ledger.add(ledger.QUERY_SNAPSHOT, time.perf_counter() - t1)
+            finally:
+                ledger.leave()
         remaining = request.limit or len(jobs)
         end = min(len(jobs), remaining)
         truncated = len(jobs) > remaining
@@ -651,9 +703,16 @@ class CtldServer:
             batch = jobs[lo:hi]
             # re-take the lock per chunk: Job objects are mutable and
             # the cycle runs between chunks
+            t0 = time.perf_counter()
             with self._lock:
-                chunk = [job_to_pb(j, names, priority_of(j))
-                         for j in batch]
+                t1 = ledger.enter(ledger.QUERY, t0)
+                try:
+                    chunk = [job_to_pb(j, names, priority_of(j))
+                             for j in batch]
+                    ledger.add(ledger.QUERY_CONVERT,
+                               time.perf_counter() - t1)
+                finally:
+                    ledger.leave()
             yield pb.QueryJobsReply(jobs=chunk,
                                     truncated=truncated and hi == end)
 
@@ -758,80 +817,86 @@ class CtldServer:
         import json as _json
 
         from cranesched_tpu.obs import REGISTRY
+        ledger = self.scheduler.lock_ledger
+        t0 = time.perf_counter()
         with self._lock:
-            doc = dict(self.scheduler.stats)
-            doc["licenses"] = {
-                name: {"total": lic.total, "in_use": lic.in_use,
-                       "external_used": lic.external_used,
-                       "free": lic.free, "remote": lic.remote}
-                for name, lic in
-                self.scheduler.licenses.licenses.items()}
-            # obs layer: full metric snapshot + the cycle-trace ring +
-            # liveness, so `cstats --metrics/--cycles` needs no extra
-            # RPC and can flag "scheduler stalled" client-side
-            doc["metrics"] = REGISTRY.snapshot()
-            doc["cycle_trace"] = self.scheduler.cycle_trace.snapshot()
-            # per-job tracing + SLO plane (cstats --slo): evaluating on
-            # query refreshes the burn-rate gauges, so /metrics scraped
-            # right after a cstats --slo shows the same numbers
-            if self.scheduler.jobtrace is not None:
-                doc["jobtrace"] = self.scheduler.jobtrace.stats()
-            if self.scheduler.slo_engine is not None:
-                doc["slo"] = self.scheduler.slo_engine.evaluate(
-                    time.time())
-            topo = getattr(self.scheduler.meta, "topology", None)
-            if topo is not None:
-                from cranesched_tpu.topo.model import topology_doc
-                avail_np, total_np, alive_np = \
-                    self.scheduler.meta.snapshot()
-                free = alive_np & (avail_np == total_np).all(axis=1)
-                doc["topology"] = topology_doc(topo, free)
-            # stall forensics (cflight): recent phase ring + the last
-            # sentry-captured stall with its all-thread stacks
-            doc["flight"] = self.scheduler.flight.report()
-            doc["watchdog"] = {
-                "now": time.time(),
-                "cycle_interval": self.cycle_interval,
-                "idle_sleep": float(getattr(
-                    self.scheduler.config, "cycle_idle_sleep", 0.0)),
-                "tick_mode": self.tick_mode,
-                "last_cycle_walltime":
-                    self.scheduler.stats.get("last_cycle_walltime", 0.0),
-                "cycle_crashes_total":
-                    self.scheduler.stats.get("cycle_crashes_total", 0),
-                "last_crash": self.scheduler.stats.get("last_crash"),
-            }
-            wal = self.scheduler.wal
-            lag = 0
-            if self.ha_follower is not None:
-                lag = max(0, self.ha_follower.leader_seq
-                          - self.ha_follower.applied_seq)
-            doc["ha"] = {
-                "role": self.ha_role,
-                "fencing_epoch": self.scheduler.fencing_epoch,
-                "wal_seq": (self.ha_follower.applied_seq
-                            if self.ha_follower is not None
-                            else (wal.durable_seq
-                                  if wal is not None else 0)),
-                "replication_lag": lag,
-                "failovers_total": self.failovers,
-                "peer": self.ha_peer,
-            }
-            if self.shard_name or self.shard_map is not None:
-                doc["fed"] = {
-                    "shard": self.shard_name,
-                    "map_epoch": self._map_epoch(),
-                    "shards": (self.shard_map.doc()
-                               if self.shard_map is not None else []),
+            ledger.enter(ledger.STATS, t0)
+            try:
+                doc = dict(self.scheduler.stats)
+                doc["licenses"] = {
+                    name: {"total": lic.total, "in_use": lic.in_use,
+                           "external_used": lic.external_used,
+                           "free": lic.free, "remote": lic.remote}
+                    for name, lic in
+                    self.scheduler.licenses.licenses.items()}
+                # obs layer: full metric snapshot + the cycle-trace ring +
+                # liveness, so `cstats --metrics/--cycles` needs no extra
+                # RPC and can flag "scheduler stalled" client-side
+                doc["metrics"] = REGISTRY.snapshot()
+                doc["cycle_trace"] = self.scheduler.cycle_trace.snapshot()
+                # per-job tracing + SLO plane (cstats --slo): evaluating on
+                # query refreshes the burn-rate gauges, so /metrics scraped
+                # right after a cstats --slo shows the same numbers
+                if self.scheduler.jobtrace is not None:
+                    doc["jobtrace"] = self.scheduler.jobtrace.stats()
+                if self.scheduler.slo_engine is not None:
+                    doc["slo"] = self.scheduler.slo_engine.evaluate(
+                        time.time())
+                topo = getattr(self.scheduler.meta, "topology", None)
+                if topo is not None:
+                    from cranesched_tpu.topo.model import topology_doc
+                    avail_np, total_np, alive_np = \
+                        self.scheduler.meta.snapshot()
+                    free = alive_np & (avail_np == total_np).all(axis=1)
+                    doc["topology"] = topology_doc(topo, free)
+                # stall forensics (cflight): recent phase ring + the last
+                # sentry-captured stall with its all-thread stacks
+                doc["flight"] = self.scheduler.flight.report()
+                doc["watchdog"] = {
+                    "now": time.time(),
+                    "cycle_interval": self.cycle_interval,
+                    "idle_sleep": float(getattr(
+                        self.scheduler.config, "cycle_idle_sleep", 0.0)),
+                    "tick_mode": self.tick_mode,
+                    "last_cycle_walltime":
+                        self.scheduler.stats.get("last_cycle_walltime", 0.0),
+                    "cycle_crashes_total":
+                        self.scheduler.stats.get("cycle_crashes_total", 0),
+                    "last_crash": self.scheduler.stats.get("last_crash"),
                 }
-                if self.scheduler.fed is not None:
-                    doc["fed"].update(self.scheduler.fed.stats())
-                if self.scheduler.global_usage is not None:
-                    doc["fed"]["usage"] = \
-                        self.scheduler.global_usage.stats()
-            return pb.StatsReply(json=_json.dumps(doc),
-                                 durable_seq=self._durable_seq(),
-                                 shard=self.shard_name)
+                wal = self.scheduler.wal
+                lag = 0
+                if self.ha_follower is not None:
+                    lag = max(0, self.ha_follower.leader_seq
+                              - self.ha_follower.applied_seq)
+                doc["ha"] = {
+                    "role": self.ha_role,
+                    "fencing_epoch": self.scheduler.fencing_epoch,
+                    "wal_seq": (self.ha_follower.applied_seq
+                                if self.ha_follower is not None
+                                else (wal.durable_seq
+                                      if wal is not None else 0)),
+                    "replication_lag": lag,
+                    "failovers_total": self.failovers,
+                    "peer": self.ha_peer,
+                }
+                if self.shard_name or self.shard_map is not None:
+                    doc["fed"] = {
+                        "shard": self.shard_name,
+                        "map_epoch": self._map_epoch(),
+                        "shards": (self.shard_map.doc()
+                                   if self.shard_map is not None else []),
+                    }
+                    if self.scheduler.fed is not None:
+                        doc["fed"].update(self.scheduler.fed.stats())
+                    if self.scheduler.global_usage is not None:
+                        doc["fed"]["usage"] = \
+                            self.scheduler.global_usage.stats()
+                return pb.StatsReply(json=_json.dumps(doc),
+                                     durable_seq=self._durable_seq(),
+                                     shard=self.shard_name)
+            finally:
+                ledger.leave()
 
     def AcctMgr(self, request, context):
         """Accounting CRUD (reference cacctmgr -> AccountManager RPC
@@ -1778,13 +1843,11 @@ class CtldServer:
             # arm the stall sentry around the cycle: a cycle that
             # neither finishes nor raises (a wedged solve, a stuck
             # fsync) fires the flight recorder — all-thread stacks into
-            # flight.last_stall — instead of hanging silently.  The
-            # deadline mirrors the cstats staleness heuristic.
-            stall_after = max(3.0 * self.cycle_interval,
-                              2.0 * float(getattr(
-                                  self.scheduler.config,
-                                  "cycle_idle_sleep", 0.0)),
-                              5.0)
+            # flight.last_stall — instead of hanging silently.  It is
+            # armed round ONE _cycle_once, inside which the loop never
+            # idles, so the idle sleep is no term of the deadline (the
+            # cstats staleness rule, a reader's between cycles, keeps it)
+            stall_after = max(3.0 * self.cycle_interval, 2.0)
             self.scheduler.flight.arm(stall_after, label="cycle")
             try:
                 self._cycle_once(now)

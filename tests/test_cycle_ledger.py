@@ -132,7 +132,7 @@ def test_clock_partitions_exactly():
     fields = clock.close()
     assert set(fields) == ({p + "_ms" for p in PARTS} | {
         "period_ms", "lock_wait_max_ms", "lock_held_work_ms",
-        "unnamed_ms", "gc_ms"})
+        "unnamed_ms", "gc_ms", "cpu_ms", "lock_wait_max_behind"})
     assert fields["sleep_ms"] >= 4.0
     assert fields["lock_wait_ms"] >= 11.0
     # the longest SINGLE wait, not the sum
@@ -147,7 +147,7 @@ def test_clock_partitions_exactly():
     # the ledger starts afresh
     again = clock.close()
     assert again["lock_wait_ms"] == 0.0 and again["lock_wait_max_ms"] == 0.0
-    assert again["period_ms"] < 1.0
+    assert again["period_ms"] < 5.0   # not the 17 ms before; a loaded host
 
 
 def test_a_phase_no_field_names_lands_in_unnamed():

@@ -113,6 +113,154 @@ def test_disarm_before_deadline_never_fires():
     fr.close()
 
 
+def _stalled(fr):
+    deadline = time.monotonic() + 5.0
+    while fr.stalls_total == 0 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return fr.last_stall
+
+
+@pytest.mark.parametrize("holder", ["query", ""])
+def test_stall_records_who_holds_the_server_lock_and_for_how_long(holder):
+    from cranesched_tpu.cli import _render_flight
+    from cranesched_tpu.obs.trace import LockLedger
+
+    events = []
+    ledger = LockLedger()
+    fr = FlightRecorder(lock_ledger=ledger,
+                        event_sink=lambda type, sev, detail="":
+                        events.append(detail))
+    if holder:
+        ledger.enter(ledger.QUERY, time.perf_counter())
+    fr.arm(0.1, label="cycle")
+    stall = _stalled(fr)
+    assert stall["lock_holder"] == holder
+    text = "\n".join(_render_flight(fr.report()))
+    if holder:
+        # the hold began before the sentry was armed
+        assert stall["lock_held_s"] >= 0.1
+        assert "lock held by query" in events[0]
+        assert "server lock held by query for" in text
+        ledger.leave()
+    else:
+        assert stall["lock_held_s"] == 0.0
+        assert "lock held by" not in events[0]
+        assert "server lock held by - for" in text
+    fr.close()
+
+
+def test_a_recorder_without_a_ledger_records_no_holder():
+    fr = FlightRecorder()
+    fr.arm(0.05)
+    assert "lock_holder" not in _stalled(fr)
+    fr.close()
+
+
+@pytest.mark.parametrize("interval,idle,expect", [
+    (1.0, 30.0, 3.0),       # every benchmark cell: 3 s, no longer 60
+    (0.05, 0.06, 2.0),      # the floor
+    (2.0, 0.0, 6.0),
+])
+def test_sentry_deadline_leaves_out_the_idle_sleep(interval, idle, expect):
+    """The sentry is armed round ONE _cycle_once, inside which the loop
+    never idles: the deadline is max(3 x cycle_interval, 2 s)."""
+    from cranesched_tpu.craned import SimCluster
+
+    meta = MetaContainer()
+    meta.add_node("cn0", meta.layout.encode(
+        cpu=4, mem_bytes=8 << 30, memsw_bytes=8 << 30, is_capacity=True))
+    meta.craned_up(0)
+    sched = JobScheduler(meta, SchedulerConfig(cycle_idle_sleep=idle))
+    armed = []
+    arm = sched.flight.arm
+    sched.flight.arm = lambda timeout_s, label="cycle": (
+        armed.append((timeout_s, label)), arm(timeout_s, label))
+    server, port = serve(sched, sim=SimCluster(sched),
+                         address="127.0.0.1:0", cycle_interval=interval)
+    try:
+        server._cycle_kick.set()
+        deadline = time.monotonic() + 10.0
+        while not armed and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        server.stop()
+    assert armed and set(armed) == {(expect, "cycle")}
+    assert sched.flight.stalls_total == 0
+
+
+def test_a_cycle_kept_from_the_lock_for_seconds_fires_the_sentry():
+    """What the 60 s deadline never saw: a cycle that waits over 2 s for
+    the server lock behind a classed hold.  last_stall names the holder
+    and the age of its hold, the cycle thread's stack shows it waiting
+    in _cycle_once, and the row of that cycle says who it waited behind."""
+    from cranesched_tpu.craned import SimCluster
+
+    meta = MetaContainer()
+    meta.add_node("cn0", meta.layout.encode(
+        cpu=4, mem_bytes=8 << 30, memsw_bytes=8 << 30, is_capacity=True),
+        partitions=("default",))
+    meta.craned_up(0)
+    # a second job stays a candidate and the no-op fingerprint is off:
+    # every cycle rings a row
+    sched = JobScheduler(meta, SchedulerConfig(backfill=False,
+                                               incremental=False))
+    cluster = SimCluster(sched)
+    sched.dispatch = cluster.dispatch
+    sched.dispatch_terminate = cluster.terminate
+    server, port = serve(sched, sim=cluster, address="127.0.0.1:0",
+                         cycle_interval=0.05)
+    client = CtldClient(f"127.0.0.1:{port}")
+    ledger = sched.lock_ledger
+    spec = pb.JobSpec(res=pb.ResourceSpec(
+        cpu=4.0, mem_bytes=1 << 30, memsw_bytes=1 << 30),
+        time_limit=600, partition="default", user="alice",
+        sim_runtime=300.0)
+
+    def closed():
+        return [r for r in sched.cycle_trace.snapshot() if "period_ms" in r]
+
+    try:
+        assert all(r.job_id for r in
+                   client.submit_many([spec] * 2).replies)
+        deadline = time.monotonic() + 10.0
+        while len(closed()) < 2 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        t0 = time.perf_counter()
+        with server._lock:
+            ledger.enter(ledger.SNAPSHOT, t0)
+            try:
+                # the next cycle arms the sentry (2 s) and waits here
+                stall = _stalled(sched.flight)
+                held_s = time.perf_counter() - t0
+            finally:
+                ledger.leave()
+        assert stall is not None and 2.0 <= held_s < 4.5
+        assert stall["lock_holder"] == "snapshot"
+        assert 1.9 <= stall["lock_held_s"] <= held_s
+        waiting = [frames for name, frames in stall["stacks"].items()
+                   if any("_cycle_once" in f for f in frames)]
+        assert len(waiting) == 1
+        assert sched.flight.stalls_total == 1
+        events = [e for e in sched.events.since(0)
+                  if e["type"] == "flight_stall"]
+        assert len(events) == 1
+        assert "lock held by snapshot" in events[0]["detail"]
+        # and the row of the cycle that waited says the same from inside
+        deadline = time.monotonic() + 10.0
+        rows = []
+        while not rows and time.monotonic() < deadline:
+            rows = [r for r in closed() if r["lock_wait_max_ms"] >= 1900.0]
+            time.sleep(0.02)
+    finally:
+        server.stop()
+    assert len(rows) == 1
+    row = rows[0]
+    assert row["lock_wait_max_behind"] == "snapshot"
+    assert row["rpc_snapshot_held_ms"] >= 2000.0
+    # the process slept through it: a stall with the CPU idle
+    assert row["cpu_ms"] < 0.5 * row["period_ms"]
+
+
 def test_dump_all_stacks_sees_this_thread():
     stacks = dump_all_stacks()
     me = [k for k in stacks if k.startswith("MainThread")]

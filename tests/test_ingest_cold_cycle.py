@@ -195,3 +195,40 @@ def test_a_batch_waits_out_a_compiling_cycle_and_nobody_else_does():
         release.set()
         client.close()
         server.stop()
+
+
+# ---------------------------------------------------------------------------
+# the ladder of J: a closed loop of 250-spec batches stands at 500
+# candidates and a long period leaves 750: one program; what the cycle
+# between two chunks of the stream's last batch leaves (250 - 32 k) is
+# the program of a whole batch
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n, rows", [
+    (0, 256), (26, 256), (58, 256), (122, 256), (250, 256), (257, 1024),
+    (500, 1024), (750, 1024), (1024, 1024), (1025, 2048), (51_200, 65_536),
+    (101_376, 131_072)])
+def test_candidates_pad_to_256_to_1024_then_by_twos(n, rows):
+    assert JobScheduler._job_bucket(n) == rows
+    # never tighter than the power-of-two ladder the other shapes keep
+    assert rows >= JobScheduler._bucket(n)
+
+
+def test_a_third_batch_or_a_batch_in_part_meets_no_new_program():
+    """500 and 750 candidates build batches of one shape, and so do 26
+    and 250: the cycle after a long period, and the one that finds the
+    rest of the stream's last batch, call no jit under a signature new
+    to the process."""
+    sched, _sim = _sched()
+    _submit(sched, 500)
+    ordered = list(sched.pending.values())
+    two, _ = sched._build_batch(ordered, len(sched.meta.nodes))
+    _submit(sched, 250)
+    three, _ = sched._build_batch(list(sched.pending.values()),
+                                  len(sched.meta.nodes))
+    assert two.valid.shape == three.valid.shape == (1024,)
+    assert int(two.valid.sum()) == 500 and int(three.valid.sum()) == 750
+    queue = list(sched.pending.values())
+    rest, _ = sched._build_batch(queue[:26], len(sched.meta.nodes))
+    whole, _ = sched._build_batch(queue[:250], len(sched.meta.nodes))
+    assert rest.valid.shape == whole.valid.shape == (256,)

@@ -197,7 +197,9 @@ def _scheduler(solver: str):
     sched = JobScheduler(meta, SchedulerConfig(backfill=False,
                                                solver=solver))
     rng = np.random.default_rng(3)
-    for i in range(18):
+    # 64 jobs a partition: under a 256-row batch's padding class the
+    # stream planner would turn a smaller queue down as too skewed
+    for i in range(192):
         sched.submit(JobSpec(
             res=ResourceSpec(cpu=float(rng.integers(1, 6)),
                              mem_bytes=int(rng.integers(1, 9)) << 30),

@@ -52,7 +52,10 @@ def build(overlap: bool, solver: str = "auto"):
     return meta, sched
 
 
-def submit_queue(sched, overlap: bool, n_jobs: int = 36):
+def submit_queue(sched, overlap: bool, n_jobs: int = 216):
+    # 72 jobs a partition: a cycle's candidates pad to 256 rows
+    # (JobScheduler._job_bucket), and the stream planner turns a batch
+    # down whose padding class outweighs its real ones
     rng = np.random.default_rng(7)
     parts = ["p0", "p1", "p2"] + (["all"] if overlap else [])
     for i in range(n_jobs):
