@@ -586,10 +586,13 @@ class JobScheduler:
         self._ptable = PendingTable(meta.layout.num_dims)
         # membership indexes maintained by the dict hooks so per-cycle
         # scans iterate exactly the rows they need, never O(pending) /
-        # O(running): array templates awaiting materialization, and
-        # alloc_only jobs whose time limit ctld itself enforces
+        # O(running): array templates awaiting materialization,
+        # alloc_only jobs whose time limit ctld itself enforces, and
+        # each user's live jobs (pending or running; no entry for a
+        # user with none), what a per-user query reads (user_jobs)
         self._array_templates: set[int] = set()
         self._alloc_only: set[int] = set()
+        self._user_jobs: dict[str, set[int]] = collections.defaultdict(set)
         # event-driven loop plumbing: the server points cycle_kick at
         # its wakeup event; mutations that can change the next cycle's
         # outcome call _kick() so a sleeping loop wakes immediately
@@ -851,10 +854,26 @@ class JobScheduler:
         if kick is not None:
             kick()
 
+    def _user_jobs_drop(self, job_id: int, job: Job) -> None:
+        """``job_id`` left pending or running and is in neither now.  A
+        move is a del THEN a set (a start, a requeue), so a user's only
+        job drops the entry here and the set hook opens it again within
+        the same lock hold: nobody reads the index in between."""
+        ids = self._user_jobs.get(job.spec.user)
+        if ids is not None:
+            ids.discard(job_id)
+            if not ids:
+                del self._user_jobs[job.spec.user]
+
+    def user_jobs(self, user: str) -> Iterable[int]:
+        """Ids of ``user``'s pending and running jobs, in no order."""
+        return self._user_jobs.get(user, ())
+
     def _on_pending_set(self, job_id: int, job: Job) -> None:
         self._table_upsert(job)
         if job.spec.array is not None:
             self._array_templates.add(job_id)
+        self._user_jobs[job.spec.user].add(job_id)
         _MET_PENDING.set(len(self.pending))
         self._kick()
 
@@ -864,12 +883,15 @@ class JobScheduler:
             # the table held it while the job had a row (job_priority)
             job.priority = priority
         self._array_templates.discard(job_id)
+        if job_id not in self.running:
+            self._user_jobs_drop(job_id, job)
         _MET_PENDING.set(len(self.pending))
         self._kick()
 
     def _on_running_set(self, job_id: int, job: Job) -> None:
         if job.spec.alloc_only:
             self._alloc_only.add(job_id)
+        self._user_jobs[job.spec.user].add(job_id)
         self._run_epoch += 1
         _MET_RUNNING.set(len(self.running))
         if self.global_usage is not None:
@@ -882,6 +904,8 @@ class JobScheduler:
 
     def _on_running_del(self, job_id: int, job: Job) -> None:
         self._alloc_only.discard(job_id)
+        if job_id not in self.pending:
+            self._user_jobs_drop(job_id, job)
         self._run_epoch += 1
         _MET_RUNNING.set(len(self.running))
         if self.global_usage is not None:

@@ -170,9 +170,17 @@ of periods in which it took the lock.
                          of the WAL's fsync_seconds over each hold)
     rpc_submit_batch_door_ms  OUTSIDE any hold: batches waiting out a
                          cycle that compiles (wait_out_compiling_cycle)
-    rpc_query_snapshot_ms    _job_snapshot: name map, queue copy,
-                         filters, sort
-    rpc_query_convert_ms     job_to_pb over the rows
+    rpc_query_snapshot_ms    _job_snapshot: the live candidates from the
+                         narrowest source the request names (job_ids,
+                         the user's index, else the whole queue) in
+                         ascending id, the filters, the cut at limit + 1
+    rpc_query_scanned    a COUNT, not a time: Job objects _job_snapshot
+                         touched in Python in the period's query holds
+                         (lookups on the way to the cut; every job of
+                         the queue, history or an archive page where the
+                         request names no narrower source)
+    rpc_query_convert_ms     the name map of the rows' own nodes and
+                         job_to_pb over the rows
     lock_held_rpc_ms     sum of rpc_<c>_held_ms over the classes
     lock_unaccounted_ms  period_ms - lock_held_work_ms -
                          lock_held_rpc_ms: the lock free, or held at a
@@ -287,9 +295,12 @@ class LockLedger:
     PARTS = ((SUBMIT, "submit_wal"), (SUBMIT_BATCH, "submit_batch_wal"),
              (SUBMIT_BATCH, "submit_batch_door"),
              (QUERY, "query_snapshot"), (QUERY, "query_convert"))
-    _SIZE = _SLOT * len(HOLDERS) + len(PARTS)
+    #: counts booked within a class's holds, in the slots after the
+    #: parts': (the class, <class>_<what>), written without a unit
+    COUNTS = ((QUERY, "query_scanned"),)
+    _SIZE = _SLOT * len(HOLDERS) + len(PARTS) + len(COUNTS)
     (SUBMIT_WAL, SUBMIT_BATCH_WAL, SUBMIT_BATCH_DOOR, QUERY_SNAPSHOT,
-     QUERY_CONVERT) = range(_SIZE - len(PARTS), _SIZE)
+     QUERY_CONVERT, QUERY_SCANNED) = range(_SLOT * len(HOLDERS), _SIZE)
 
     def __init__(self):
         self.annotate = False
@@ -323,9 +334,10 @@ class LockLedger:
             self._span.__enter__()
         return t1
 
-    def add(self, part: int, seconds: float) -> None:
-        """Book ``seconds`` to a part; call it inside the hold."""
-        self._acc[part] += seconds
+    def add(self, part: int, amount: float) -> None:
+        """Book seconds to a part, or a number to a count; call it
+        inside the hold."""
+        self._acc[part] += amount
 
     def leave(self) -> None:
         """Last act inside the hold."""
@@ -374,6 +386,9 @@ class LockLedger:
         for k, (holder, name) in enumerate(self.PARTS, self.SUBMIT_WAL):
             if acc[holder * self._SLOT]:
                 fields["rpc_" + name + "_ms"] = round(acc[k] * 1e3, 3)
+        for k, (holder, name) in enumerate(self.COUNTS, self.QUERY_SCANNED):
+            if acc[holder * self._SLOT]:
+                fields["rpc_" + name] = int(acc[k])
         wait_cell, held_cell = self._cells[-1]
         wait_cell.inc(clock_fields["lock_wait_ms"] / 1e3)
         held_cell.inc(clock_fields["lock_held_work_ms"] / 1e3)

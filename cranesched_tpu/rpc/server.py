@@ -18,7 +18,10 @@ Two clock modes:
 
 from __future__ import annotations
 
+import bisect
 import contextlib
+import itertools
+import operator
 import threading
 import time
 from concurrent import futures
@@ -38,6 +41,8 @@ from cranesched_tpu.rpc.convert import (
     step_spec_from_pb,
     step_to_pb,
 )
+
+_JOB_ID = operator.attrgetter("job_id")
 
 _MET_FWD = _OBS.counter(
     "crane_fed_forwards_total",
@@ -567,62 +572,113 @@ class CtldServer:
     # the bare-read archive cap
     DEFAULT_PAGE = 10_000
 
-    def _job_snapshot(self, request) -> tuple[list, dict]:
-        """Filtered job list + node-name map, under the lock.  Returns
-        refs (cheap); pb conversion happens in bounded chunks so large
-        queues never pin the scheduler for the whole result set."""
+    def _job_snapshot(self, request) -> list:
+        """The jobs a query names, ascending by id, under the lock: refs
+        (cheap); pb conversion happens in bounded chunks so large queues
+        never pin the scheduler for the whole result set.  The live
+        candidates come from the narrowest source the request itself
+        names (``job_ids``: a lookup each; ``user``: the scheduler's
+        index of that user's live jobs; neither: the whole queue), taken
+        in ascending id so that the walk stops at the caller's
+        ``limit + 1`` matches: one more than asked is what the handlers'
+        ``truncated`` reads.  A ``Job`` is looked up only on the way to
+        that cut; how many were is booked as ``rpc_query_scanned``."""
+        sched = self.scheduler
+        cut = request.limit + 1 if request.limit else None
         if request.after_job_id and not request.limit:
             # a cursor without a limit gets the default page size — so
             # the handlers' truncation math (limit-based) marks the
             # reply truncated instead of silently dropping the tail
             request.limit = self.DEFAULT_PAGE
-        names = {i: n.name
-                 for i, n in self.scheduler.meta.nodes.items()}
-        jobs = list(self.scheduler.queue())
+        after = request.after_job_id
+        wanted = sorted(set(request.job_ids))
+        scanned = 0
+
+        def looked_up(ids, *dicts):
+            nonlocal scanned
+            for i in ids[bisect.bisect_right(ids, after):]:
+                for jobs in dicts:
+                    job = jobs.get(i)
+                    if job is not None:
+                        scanned += 1
+                        yield job
+                        break
+
+        def matching(jobs):
+            if request.user:
+                jobs = (j for j in jobs if j.spec.user == request.user)
+            if request.partition:
+                jobs = (j for j in jobs
+                        if j.spec.partition == request.partition)
+            if after:
+                # keyset pagination: results ascend by job id, so
+                # resume strictly after the cursor
+                jobs = (j for j in jobs if j.job_id > after)
+            return jobs
+
+        indexed = bool(wanted or request.user)
+        if indexed:
+            # ascending already: the walk stops at the cut
+            ids = wanted or sorted(sched.user_jobs(request.user))
+            jobs = list(itertools.islice(
+                matching(looked_up(ids, sched.pending, sched.running)),
+                cut))
+        else:
+            live = sched.queue()
+            scanned += len(live)
+            jobs = list(matching(live))
         if request.include_history:
-            jobs += list(self.scheduler.history.values())
-            if self.scheduler.archive is not None:
+            if wanted:
+                jobs += matching(looked_up(wanted, sched.history))
+            else:
+                scanned += len(sched.history)
+                jobs += matching(sched.history.values())
+            if sched.archive is not None:
                 # durable rows not in RAM (pre-restart /
                 # post-compaction history); RAM wins on overlap.
                 # Capped: a bare cacct on a long-lived cluster must
                 # not deserialize the whole archive under the
-                # server lock (newest rows are returned first)
-                seen = {j.job_id for j in jobs}
-                # a paginated read (after_job_id set) pages the archive
-                # by keyset so every archived row is reachable; the
-                # bare read keeps the newest-10k cap
-                # paginated reads (limit set) page the archive by
+                # server lock (newest rows are returned first).
+                # Paginated reads (limit set; a cursor always carries
+                # one here, normalized above) page the archive by
                 # keyset from the cursor (0 = start) so every row is
                 # reachable; +1 row lets the truncated flag tell a
                 # full final page from a continued one.  Bare reads
                 # keep the newest-10k cap.
-                paged = bool(request.limit or request.after_job_id)
-                # cursor reads always carry a limit here (normalized
-                # above): limit+1 rows let the truncated flag tell a
-                # full final page from a continued one
-                jobs += [j for j in self.scheduler.archive.query(
-                             job_ids=list(request.job_ids),
-                             user=request.user,
-                             partition=request.partition,
-                             limit=(request.limit + 1 if paged
-                                    else self.DEFAULT_PAGE),
-                             after_job_id=request.after_job_id,
-                             keyset=paged)
-                         if j.job_id not in seen]
-        if request.job_ids:
-            wanted = set(request.job_ids)
-            jobs = [j for j in jobs if j.job_id in wanted]
-        if request.user:
-            jobs = [j for j in jobs if j.spec.user == request.user]
-        if request.partition:
-            jobs = [j for j in jobs
-                    if j.spec.partition == request.partition]
-        if request.after_job_id:
-            # keyset pagination: results ascend by job id, so resume
-            # strictly after the cursor
-            jobs = [j for j in jobs if j.job_id > request.after_job_id]
-        jobs.sort(key=lambda j: j.job_id)
-        return jobs, names
+                paged = bool(request.limit or after)
+                archived = sched.archive.query(
+                    job_ids=list(request.job_ids),
+                    user=request.user,
+                    partition=request.partition,
+                    limit=(request.limit + 1 if paged
+                           else self.DEFAULT_PAGE),
+                    after_job_id=after,
+                    keyset=paged)
+                scanned += len(archived)
+                jobs += [j for j in archived
+                         if j.job_id not in sched.pending
+                         and j.job_id not in sched.running
+                         and j.job_id not in sched.history]
+        if request.include_history or not indexed:
+            jobs.sort(key=_JOB_ID)
+            if cut:
+                del jobs[cut:]
+        ledger = sched.lock_ledger
+        ledger.add(ledger.QUERY_SCANNED, scanned)
+        return jobs
+
+    def _node_names(self, jobs) -> dict:
+        """Node-name map for ``job_to_pb`` over ``jobs``, under the lock
+        and in the same hold as the conversion (a job of a streamed
+        reply may start between two chunks): the nodes the rows
+        themselves name, or every node where the rows are no fewer than
+        the nodes.  An id the topology lacks stays out, and
+        ``convert._node_name`` renders its placeholder."""
+        nodes = self.scheduler.meta.nodes
+        if len(jobs) >= len(nodes):
+            return {i: n.name for i, n in nodes.items()}
+        return {i: nodes[i].name
+                for j in jobs for i in j.node_ids if i in nodes}
 
     # conversion batch: bounds both the message size of one streamed
     # chunk and the lock hold per chunk
@@ -664,12 +720,13 @@ class CtldServer:
         with self._lock:
             t1 = ledger.enter(ledger.QUERY, t0)
             try:
-                jobs, names = self._job_snapshot(request)
+                jobs = self._job_snapshot(request)
                 t2 = time.perf_counter()
                 ledger.add(ledger.QUERY_SNAPSHOT, t2 - t1)
                 truncated = bool(limit) and len(jobs) > limit
                 if truncated:
                     jobs = jobs[:limit]
+                names = self._node_names(jobs)
                 rows = [job_to_pb(j, names, priority_of(j)) for j in jobs]
                 ledger.add(ledger.QUERY_CONVERT, time.perf_counter() - t2)
                 return pb.QueryJobsReply(
@@ -691,7 +748,7 @@ class CtldServer:
         with self._lock:
             t1 = ledger.enter(ledger.QUERY, t0)
             try:
-                jobs, names = self._job_snapshot(request)
+                jobs = self._job_snapshot(request)
                 ledger.add(ledger.QUERY_SNAPSHOT, time.perf_counter() - t1)
             finally:
                 ledger.leave()
@@ -707,6 +764,7 @@ class CtldServer:
             with self._lock:
                 t1 = ledger.enter(ledger.QUERY, t0)
                 try:
+                    names = self._node_names(batch)
                     chunk = [job_to_pb(j, names, priority_of(j))
                              for j in batch]
                     ledger.add(ledger.QUERY_CONVERT,

@@ -179,7 +179,8 @@ def test_ledger_slots_are_a_preallocated_flat_list_of_floats():
     ledger = LockLedger()
     acc = ledger._acc
     assert type(acc) is list and all(type(v) is float for v in acc)
-    assert len(acc) == 5 * len(ledger.HOLDERS) + len(ledger.PARTS)
+    assert len(acc) == 5 * len(ledger.HOLDERS) + len(ledger.PARTS) \
+        + len(ledger.COUNTS)
     for k in range(len(ledger.HOLDERS)):
         ledger.enter(k, time.perf_counter())
         ledger.leave()
@@ -606,6 +607,44 @@ def test_query_info_splits_its_hold_into_snapshot_and_convert():
     assert snapshot >= 20.0 and 0.0 < convert < 20.0
     assert snapshot + convert <= held + 0.01
     assert held - snapshot - convert < 5.0
+
+
+def test_a_user_query_with_a_limit_scans_no_more_than_it_returns():
+    """``rpc_query_scanned``: the ``Job`` objects ``_job_snapshot``
+    touched.  Over a 2,000-job queue a ``user`` + ``limit`` query looks up
+    ``limit + 1`` of them; the read that names no source walks them all."""
+    meta, sched, cluster = _cluster(backfill=False)
+    server, port = serve(sched, sim=cluster, address="127.0.0.1:0",
+                         tick_mode=True)        # no cycle: nobody drains
+    client = CtldClient(f"127.0.0.1:{port}")
+    try:
+        for user, n in (("alice", 1200), ("bob", 800)):
+            reply = client.submit_many([_pbspec(user=user)] * n)
+            assert all(r.job_id for r in reply.replies)
+        ledger = sched.lock_ledger
+        ledger.drain(_clock_fields())
+        reply = client.query_jobs(user="alice", limit=500)
+        assert len(reply.jobs) == 500 and reply.truncated
+        fields = ledger.drain(_clock_fields())
+        assert fields["rpc_query_n"] == 1
+        assert fields["rpc_query_scanned"] == 501
+        assert type(fields["rpc_query_scanned"]) is int
+        # a page further on starts at its cursor, not at the user's first
+        last = reply.jobs[-1].job_id
+        assert len(client.query_jobs(user="alice", limit=500,
+                                     after_job_id=last).jobs) == 500
+        assert ledger.drain(_clock_fields())["rpc_query_scanned"] == 501
+        assert len(client.query_jobs(user="alice").jobs) == 1200
+        assert ledger.drain(_clock_fields())["rpc_query_scanned"] == 1200
+        assert len(client.query_jobs(job_ids=[5, 7, 99999]).jobs) == 2
+        assert ledger.drain(_clock_fields())["rpc_query_scanned"] == 2
+        assert len(client.query_jobs(limit=500).jobs) == 500
+        assert ledger.drain(_clock_fields())["rpc_query_scanned"] == 2000
+        # a period with no query writes no count
+        assert "rpc_query_scanned" not in ledger.drain(_clock_fields())
+    finally:
+        client.close()
+        server.stop()
 
 
 def _slow_fsync(monkeypatch, seconds):
