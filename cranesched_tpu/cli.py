@@ -989,7 +989,12 @@ def cmd_cstats(args) -> int:
                  # single process over 8 chips); "-" for host solvers
                  t.get("mesh", "-"),
                  t.get("queue_depth"),
+                 # RANKED: the candidates the priority sort ranked (the
+                 # whole queue's); CAND: the batch of them the solve was
+                 # given; CUT: the rest, past ScheduledBatchSize
+                 t.get("ranked", "-"),
                  t.get("candidates"),
+                 t.get("cut", "-"),
                  # TOUCHED: Job objects the prelude looked up (about 0
                  # on the default route: the cycle carries table rows)
                  t.get("prelude_jobs_touched", "-"),
@@ -1026,7 +1031,8 @@ def cmd_cstats(args) -> int:
                  t.get("wal_fsyncs"), t.get("topo_frag", "-"))
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
-            "NOW", "SOLVER", "MESH", "QUEUE", "CAND", "TOUCHED", "K",
+            "NOW", "SOLVER", "MESH", "QUEUE", "RANKED", "CAND", "CUT",
+            "TOUCHED", "K",
             "PASS%", "PLACED", "NODES", "BACKFILL", "PREEMPT", "SKIP",
             "DIRTY", "PRELUDE_MS", "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS",
             "LOCK_MS",

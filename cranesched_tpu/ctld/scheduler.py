@@ -2605,18 +2605,26 @@ class JobScheduler:
             self._arm_noop(now)
             self._in_cycle = False
             return []
-        limit = self.config.schedule_batch_size
-        if len(candidates) > limit:
-            self._cut_batch(candidates[limit:])
-            candidates = candidates[:limit]
 
         # snapshot + event capture window (cpp:1437)
         clock.mark("snapshot")
         self.meta.start_logging()
         avail, total, alive = self.meta.snapshot()
 
+        # rank EVERY candidate (factor bounds over the whole queue), then
+        # cut: the first schedule_batch_size of the order are the cycle's
+        # batch, the rest wait on "Priority" with their priority written
+        # (GetOrderedJobPtrVec(limit), cpp:6734, :7606-7629).  A queue
+        # that fits is its own batch: the slice is the whole order
         clock.mark("priority")
-        ordered = self._priority_sort(candidates, now)
+        ranked = self._priority_sort(candidates, now)
+        clock.mark("cut")
+        limit = self.config.schedule_batch_size
+        ordered = ranked[:limit]
+        if len(ranked) > limit:
+            self._cut_batch(ranked[limit:])
+        self._cur_trace.update(ranked=len(ranked),
+                               cut=len(ranked) - len(ordered))
         clock.mark("build")
         # the table epoch of the lock-free solve window: every writer of
         # a pending job's spec, hold flag or dependencies re-upserts its
@@ -2653,7 +2661,7 @@ class JobScheduler:
             started += self._try_preemption(ordered, now)
             clock.mark("wal")
             self._wal_flush()
-            self._record_cycle_stats(t0, t_prelude, candidates, started,
+            self._record_cycle_stats(t0, t_prelude, ordered, started,
                                      _time.perf_counter(), "packed")
             if self._dispatch_ring:
                 self._note_dispatch((yield self._dispatch_phase()))
@@ -2688,7 +2696,7 @@ class JobScheduler:
             started += self._try_preemption(ordered, now)
             clock.mark("wal")
             self._wal_flush()
-            self._record_cycle_stats(t0, t_prelude, candidates, started,
+            self._record_cycle_stats(t0, t_prelude, ordered, started,
                                      _time.perf_counter(), "topo")
             if self._dispatch_ring:
                 self._note_dispatch((yield self._dispatch_phase()))
@@ -2703,7 +2711,7 @@ class JobScheduler:
                 started += self._try_preemption(ordered, now)
                 clock.mark("wal")
                 self._wal_flush()
-                self._record_cycle_stats(t0, t_prelude, candidates,
+                self._record_cycle_stats(t0, t_prelude, ordered,
                                          started,
                                          _time.perf_counter(),
                                          "backfill-split")
@@ -2735,7 +2743,7 @@ class JobScheduler:
         clock.mark("commit_apply")
         self._resident.stage()
         self._record_cycle_stats(
-            t0, t_prelude, candidates, started, _time.perf_counter(),
+            t0, t_prelude, ordered, started, _time.perf_counter(),
             "backfill" if self.config.backfill else solver_name)
         if self._dispatch_ring:
             self._note_dispatch((yield self._dispatch_phase()))
@@ -3858,9 +3866,10 @@ class JobScheduler:
                 [(jid, pending[jid].requeue_count) for jid in fresh], now)
 
     def _cut_batch(self, cut: _CycleJobs) -> None:
-        """The candidates past ``schedule_batch_size`` wait on
-        "Priority".  With rows, the reason is written only where the
-        stamp says the job carries another (as ``_commit`` does)."""
+        """The ranked candidates past ``schedule_batch_size``, the
+        lowest of the order, wait on "Priority".  With rows, the reason
+        is written only where the stamp says the job carries another
+        (as ``_commit`` does)."""
         if cut.rows is None:
             for job in cut.jobs:
                 job.pending_reason = PendingReason.PRIORITY

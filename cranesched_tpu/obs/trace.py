@@ -35,7 +35,14 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
                              any group)
     wal_groups       int     WAL groups flushed in the same span (the
                              cycle's own <= 3)
-    candidates       int     jobs considered this cycle
+    candidates       int     jobs considered this cycle: the batch the
+                             solve was given (<= ScheduledBatchSize)
+    ranked           int     rows _priority_sort ranked: every candidate
+                             the gate pass returned, the whole queue's
+    cut              int     ranked - candidates: the rows past the
+                             batch cut, waiting on "Priority" with
+                             their priority written (0 where the queue
+                             fits one batch)
     gang_bound       int     the static gang bound K the cycle's solves
                              ran with: the bucket of its widest
                              candidate, capped at MaxNodesPerJob; the
@@ -125,6 +132,10 @@ period: with dispatch_ms and unnamed_ms they sum to period_ms.
     candidates_ms    _pending_candidates + the "eligible" stamps
     snapshot_ms      meta.start_logging + meta.snapshot
     priority_ms      _priority_sort (the device priority and its wait)
+                     over EVERY candidate
+    cut_ms           the batch cut: the order's first ScheduledBatchSize
+                     rows sliced off as the batch, _cut_batch's stamps
+                     on the rest
     build_ms         _build_batch, cost0, route set-up, up to the first
                      WAL flush before a solve
     solve_enqueue_ms inside the closures: fn() until it returns
@@ -255,7 +266,8 @@ GC_PAUSES = GcPauses()
 
 #: the phases that run under the server lock (lock_held_work_ms)
 LOCKED_PARTS = ("sim", "record", "drain", "candidates", "snapshot",
-                "priority", "build", "commit_apply", "wal", "preempt")
+                "priority", "cut", "build", "commit_apply", "wal",
+                "preempt")
 #: every named phase; with "dispatch" and the glue they tile a period
 PARTS = ("sleep", "lock_wait") + LOCKED_PARTS + (
     "solve_enqueue", "solve_device_wait", "solve_host")

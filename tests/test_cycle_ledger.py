@@ -118,36 +118,73 @@ def _served(sched, cluster):
 # the clock alone
 # ---------------------------------------------------------------------------
 
-def test_clock_partitions_exactly():
+class _FakeTime:
+    """``time`` as obs/trace.py sees it, with a ``perf_counter`` the test
+    moves: the clock's arithmetic is held to exact numbers, not to what
+    a host under six xdist workers makes of a 1 ms sleep."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def perf_counter(self):
+        return self.t
+
+    def sleep(self, seconds):
+        self.t += seconds
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_clock_partitions_exactly(monkeypatch):
+    fake = _FakeTime()
+    monkeypatch.setattr("cranesched_tpu.obs.trace.time", fake)
     clock = CycleClock()
     clock.close()                      # whatever construction cost
     clock.mark("sleep")
-    time.sleep(0.004)
+    fake.sleep(0.004)
     for wait in (0.002, 0.008, 0.001):  # a phase that recurs accumulates
         clock.mark("lock_wait")
-        time.sleep(wait)
+        fake.sleep(wait)
         clock.mark("drain")
+        fake.sleep(0.0005)
+    clock.mark("priority")
+    fake.sleep(0.003)
+    clock.mark("cut")
+    fake.sleep(0.00025)
     clock.mark("dispatch")
-    time.sleep(0.001)
+    fake.sleep(0.001)
+    clock.mark(CycleClock.GLUE)
+    fake.sleep(0.0002)
     fields = clock.close()
     assert set(fields) == ({p + "_ms" for p in PARTS} | {
         "period_ms", "lock_wait_max_ms", "lock_held_work_ms",
         "unnamed_ms", "gc_ms", "cpu_ms", "lock_wait_max_behind"})
-    assert fields["sleep_ms"] >= 4.0
-    assert fields["lock_wait_ms"] >= 11.0
-    # the longest SINGLE wait, not the sum
-    assert 8.0 <= fields["lock_wait_max_ms"] < fields["lock_wait_ms"]
-    # "dispatch" has no field of its own (dispatch_ms stays the
-    # closure's reading) and is no part of unnamed_ms either
-    assert fields["unnamed_ms"] < 0.5
+    assert "cut" in LOCKED_PARTS and "cut_ms" in fields
+    want = dict(sleep_ms=4.0, lock_wait_ms=11.0, drain_ms=1.5,
+                priority_ms=3.0, cut_ms=0.25,
+                # the longest SINGLE wait, not the sum
+                lock_wait_max_ms=8.0,
+                # what ran under the lock: drain + priority + cut
+                lock_held_work_ms=4.75,
+                # "dispatch" has no field of its own (dispatch_ms stays
+                # the closure's reading) and is no part of unnamed_ms,
+                # which is the glue alone
+                unnamed_ms=0.2, period_ms=20.95)
+    for name, value in want.items():
+        assert fields[name] == pytest.approx(value, abs=1e-6), name
     named = sum(fields[p + "_ms"] for p in PARTS)
-    assert fields["period_ms"] - named >= 1.0
+    # the partition: every instant belongs to exactly one phase
+    assert named + 1.0 + fields["unnamed_ms"] == pytest.approx(
+        fields["period_ms"], abs=1e-6)
     assert fields["lock_held_work_ms"] == pytest.approx(
-        sum(fields[p + "_ms"] for p in LOCKED_PARTS), abs=0.01)
+        sum(fields[p + "_ms"] for p in LOCKED_PARTS), abs=1e-6)
     # the ledger starts afresh
+    fake.sleep(0.0003)
     again = clock.close()
     assert again["lock_wait_ms"] == 0.0 and again["lock_wait_max_ms"] == 0.0
-    assert again["period_ms"] < 5.0   # not the 17 ms before; a loaded host
+    assert again["period_ms"] == pytest.approx(0.3, abs=1e-6)
+    assert again["unnamed_ms"] == pytest.approx(0.3, abs=1e-6)
 
 
 def test_a_phase_no_field_names_lands_in_unnamed():
