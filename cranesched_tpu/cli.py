@@ -998,6 +998,11 @@ def cmd_cstats(args) -> int:
                  # TOUCHED: Job objects the prelude looked up (about 0
                  # on the default route: the cycle carries table rows)
                  t.get("prelude_jobs_touched", "-"),
+                 # RUN_WALKED: running jobs whose priority row the
+                 # prelude derived in Python (0 on a steady cycle: the
+                 # running dict's hooks keep the rows); RUN_COLS_MS:
+                 # the part of the priority phase on their columns
+                 t.get("run_walked", "-"), t.get("run_cols_ms", "-"),
                  # K: the static gang bound of the cycle's solves;
                  # PASS%: the share of its slots x K selection passes
                  # the Pallas kernel ran
@@ -1032,7 +1037,7 @@ def cmd_cstats(args) -> int:
                 for t in doc.get("cycle_trace", [])]
         print(_fmt_table(rows, (
             "NOW", "SOLVER", "MESH", "QUEUE", "RANKED", "CAND", "CUT",
-            "TOUCHED", "K",
+            "TOUCHED", "RUN_WALKED", "RUN_COLS_MS", "K",
             "PASS%", "PLACED", "NODES", "BACKFILL", "PREEMPT", "SKIP",
             "DIRTY", "PRELUDE_MS", "SOLVE_MS", "COMMIT_MS", "DISPATCH_MS",
             "LOCK_MS",

@@ -56,6 +56,7 @@ from cranesched_tpu.ctld.pending_table import (
 )
 from cranesched_tpu.ctld.resident import ResidentClusterState
 from cranesched_tpu.ctld.runledger import RunLedger
+from cranesched_tpu.ctld.running_table import RunningTable
 from cranesched_tpu.models.priority import (
     PendingPriorityAttrs,
     PriorityWeights,
@@ -610,12 +611,16 @@ class JobScheduler:
         # since is void at the commit)
         self._rows_gen = -1
         self._plan_epoch = 0
-        # running-set priority attrs: rebuilt only when running-set
-        # MEMBERSHIP changes (the dict hooks bump _run_epoch on
-        # start/finish/requeue) — per cycle only run_time is recomputed
-        # from the cached start times
-        self._run_attrs: tuple | None = None
-        self._run_epoch = 0
+        # the running jobs' priority columns (ctld/running_table.py):
+        # made by the first _priority_sort that needs them (so never
+        # under ``Priority: Type: basic``, whose hooks then derive no
+        # row) from one walk over ``running``, and kept from then on by
+        # the ``running`` dict's hooks, one row a start, one move a
+        # finish; rebuild_device_state drops them.  _run_dev is (the
+        # table epoch they were put at, the padded device copies of the
+        # attribute columns): stale once the membership has moved
+        self._rtable: RunningTable | None = None
+        self._run_dev: tuple | None = None
         meta.delta_snapshot = self.config.incremental
         # job_id -> Job; insertion = id order (the hooks mirror
         # membership into the table/indexes/gauges at mutation time)
@@ -892,7 +897,8 @@ class JobScheduler:
         if job.spec.alloc_only:
             self._alloc_only.add(job_id)
         self._user_jobs[job.spec.user].add(job_id)
-        self._run_epoch += 1
+        if self._rtable is not None:
+            self._running_put(job)
         _MET_RUNNING.set(len(self.running))
         if self.global_usage is not None:
             self.global_usage.note_run(job.spec.user, job.spec.account, 1)
@@ -906,7 +912,8 @@ class JobScheduler:
         self._alloc_only.discard(job_id)
         if job_id not in self.pending:
             self._user_jobs_drop(job_id, job)
-        self._run_epoch += 1
+        if self._rtable is not None:
+            self._rtable.remove(job_id)
         _MET_RUNNING.set(len(self.running))
         if self.global_usage is not None:
             self.global_usage.note_run(job.spec.user, job.spec.account, -1)
@@ -942,8 +949,8 @@ class JobScheduler:
         event that can change it."""
         spec = job.spec
         dep, dep_never = self._dep_cols(job)
-        req, node_num, time_limit = self._job_row(job)
-        part = self.meta.partitions.get(spec.partition)
+        req, _, time_limit = self._job_row(job)
+        qos, part, node_num, cpus, mem, acct = self._priority_row(job)
         packed = bool(spec.exclusive or spec.task_res is not None
                       or (spec.ntasks is not None
                           and spec.ntasks != spec.node_num)
@@ -957,12 +964,8 @@ class JobScheduler:
             dep=dep, dep_never=dep_never,
             lic=self._ptable.lic_key(spec.licenses),
             submit=job.submit_time,
-            qos=job.qos_priority,
-            part=part.priority if part is not None else 0,
-            nnum=node_num,
-            cpus=float(req[DIM_CPU]) / 256.0 * spec.node_num,
-            mem=float(req[DIM_MEM]) * spec.node_num,
-            acct=self._account_id(spec.account),
+            qos=qos, part=part, nnum=node_num, cpus=cpus, mem=mem,
+            acct=acct,
             tlimit=time_limit,
             packed=packed,
             req=req,
@@ -2555,6 +2558,7 @@ class JobScheduler:
             "solver": "", "solve_ms": 0.0,
             "preempted": 0, "backfilled": 0, "num_streams": 1,
             "prelude_jobs_touched": 0,
+            "run_walked": 0, "run_cols_ms": 0.0,
         }
         _MET_PENDING.set(len(self.pending))
         self._cycle_now = now
@@ -3916,10 +3920,34 @@ class JobScheduler:
             self._account_index[account] = len(self._account_index)
         return self._account_index[account]
 
+    def _priority_row(self, job: Job) -> tuple:
+        """``(qos, partition priority, node_num, total cpus, total mem,
+        account index)``: what the priority model reads of one job,
+        derived from the Job as ``_table_upsert`` derives a pending
+        row's columns."""
+        spec = job.spec
+        req = self._job_row(job)[0]   # spec-cached encode
+        part = self.meta.partitions.get(spec.partition)
+        return (job.qos_priority,
+                part.priority if part is not None else 0,
+                spec.node_num,
+                float(req[DIM_CPU]) / 256.0 * spec.node_num,
+                float(req[DIM_MEM]) * spec.node_num,
+                self._account_id(spec.account))
+
+    def _running_put(self, job: Job) -> None:
+        """Write a running job's RunningTable row: once a start (the
+        ``running`` dict's set hook), since nothing in it changes while
+        the job runs."""
+        self._rtable.put(
+            job.job_id, *self._priority_row(job),
+            job.start_time if job.start_time is not None else np.inf)
+
     def _priority_sort(self, candidates: _CycleJobs, now: float
                        ) -> _CycleJobs:
         if self.config.priority_type == "basic" or not candidates:
             return candidates  # FIFO: id order (JobScheduler.h:183-201)
+        import time as _time
 
         # vectorized path: gather priority attrs straight from the
         # PendingTable columns (O(1) numpy gathers) instead of touching
@@ -3932,43 +3960,51 @@ class JobScheduler:
             for job in candidates.jobs:
                 self._account_id(job.spec.account)
 
-        def job_row(job: Job):
-            req = self._job_row(job)[0]   # spec-cached encode
-            total_cpu = float(req[DIM_CPU]) / 256.0 * job.spec.node_num
-            total_mem = float(req[DIM_MEM]) * job.spec.node_num
-            return (job.qos_priority,
-                    self.meta.partitions[job.spec.partition].priority,
-                    job.spec.node_num, total_cpu, total_mem,
-                    self._account_id(job.spec.account))
-
-        def col(rows, k, dt, size):
-            arr = np.zeros(size, dt)
-            arr[: len(rows)] = [r[k] for r in rows]
-            return jnp.asarray(arr)
-
         # running-set attrs: none of them change while a job RUNS (qos,
         # partition, shape and account are modify-refused for running
-        # jobs; only run_time ages), so the padded device arrays are
-        # cached until the running-set epoch moves — membership churn
-        # rebuilds them, and job_row re-registers every running account
-        # then, which is why this block precedes num_accounts
-        ra = self._run_attrs
-        if ra is None or ra[0] != self._run_epoch:
-            r_jobs = list(self.running.values())
-            nR = len(r_jobs)
-            RP = self._bucket(nR) if r_jobs else 16
-            r_rows = [job_row(j) for j in r_jobs]
-            start = np.full(RP, np.inf)
-            start[:nR] = [j.start_time if j.start_time is not None
-                          else np.inf for j in r_jobs]
-            r_valid = np.zeros(RP, bool)
-            r_valid[:nR] = True
-            ra = (self._run_epoch, nR, RP, start,
-                  tuple(col(r_rows, k, dt, RP) for k, dt in (
-                      (0, np.int32), (1, np.int32), (2, np.int32),
-                      (3, np.float32), (4, np.float32), (5, np.int32))),
-                  jnp.asarray(r_valid))
-            self._run_attrs = ra
+        # jobs; only run_time ages), so the cycle reads the columns the
+        # running dict's hooks keep (RunningTable) and never walks the
+        # jobs: Python derives rows here only to MAKE the table, on the
+        # first cycle and on the first after rebuild_device_state
+        # dropped it (run_walked).  Both routes read the same table.
+        # The padded device copies stand until the membership moves.
+        # A row's account registered as the row was written, which is
+        # why this block precedes num_accounts
+        t_run = _time.perf_counter()
+        walked = 0
+        rt = self._rtable
+        if rt is None:
+            rt = self._rtable = RunningTable(len(self.running))
+            self._run_dev = None
+            for job in self.running.values():
+                self._running_put(job)
+            walked = len(rt)
+        nR = len(rt)
+        RP = self._bucket(nR)
+        if self._run_dev is None or self._run_dev[0] != rt.epoch:
+
+            def rcol(name, dt):
+                arr = np.zeros(RP, dt)
+                arr[:nR] = rt.column(name)
+                return jnp.asarray(arr)
+
+            self._run_dev = (rt.epoch, dict(
+                qos_prio=rcol("qos", np.int32),
+                part_prio=rcol("part", np.int32),
+                node_num=rcol("nnum", np.int32),
+                cpus=rcol("cpus", np.float32),
+                mem=rcol("mem", np.float32),
+                account=rcol("acct", np.int32),
+                valid=jnp.asarray(np.arange(RP) < nR)))
+        # start == +inf encodes "not started yet": it clamps to 0, as
+        # the per-job `now - (start or now)` did
+        run_time = np.zeros(RP, np.int32)
+        run_time[:nR] = np.maximum(now - rt.column("start"), 0.0)
+        running = RunningPriorityAttrs(
+            run_time=jnp.asarray(run_time), **self._run_dev[1])
+        self._cur_trace.update(
+            run_walked=walked,
+            run_cols_ms=round((_time.perf_counter() - t_run) * 1e3, 3))
         # bucketed: num_accounts is a jit static arg, and the dense index
         # grows monotonically — pad so new accounts rarely recompile
         num_accounts = self._bucket(len(self._account_index))
@@ -3999,7 +4035,12 @@ class JobScheduler:
                 account=pcol(pt.acct, np.int32),
                 valid=jnp.asarray(p_valid))
         else:
-            p_rows = [job_row(j) for j in candidates.jobs]
+            def col(rows, k, dt, size):
+                arr = np.zeros(size, dt)
+                arr[: len(rows)] = [r[k] for r in rows]
+                return jnp.asarray(arr)
+
+            p_rows = [self._priority_row(j) for j in candidates.jobs]
             age = np.zeros(JP, np.int32)
             age[: len(candidates)] = [max(now - j.submit_time, 0.0)
                                       for j in candidates.jobs]
@@ -4012,18 +4053,6 @@ class JobScheduler:
                 mem=col(p_rows, 4, np.float32, JP),
                 account=col(p_rows, 5, np.int32, JP),
                 valid=jnp.asarray(p_valid))
-
-        _, nR, RP, r_start, r_cols, r_valid = ra
-        run_time = np.zeros(RP, np.int32)
-        if nR:
-            # start == +inf encodes "not started yet" → clamps to 0,
-            # matching the old per-job `now - (start or now)`
-            run_time[:nR] = np.maximum(now - r_start[:nR], 0.0)
-        running = RunningPriorityAttrs(
-            qos_prio=r_cols[0], part_prio=r_cols[1], node_num=r_cols[2],
-            cpus=r_cols[3], mem=r_cols[4], account=r_cols[5],
-            run_time=jnp.asarray(run_time),
-            valid=r_valid)
 
         extra_service = None
         if self.global_usage is not None:
@@ -4594,7 +4623,9 @@ class JobScheduler:
         self.meta._snap = None
         self._noop_fp = None
         self._rows_gen = -1
-        self._run_attrs = None
+        # rows encoded against the old layout: the next cycle's
+        # _priority_sort makes the table again from self.running
+        self._rtable = None
         # the resident ClusterState mirrors the OLD leader's ledger —
         # drop it; the first cycle pays one full rebuild
         self._resident.invalidate()

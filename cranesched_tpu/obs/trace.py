@@ -43,6 +43,19 @@ Cycle-trace schema (ARCHITECTURE.md "Observability"):
                              batch cut, waiting on "Priority" with
                              their priority written (0 where the queue
                              fits one batch)
+    run_walked       int     running jobs whose priority row Python
+                             derived inside this cycle's prelude: 0 on a
+                             steady cycle (the running dict's hooks keep
+                             the rows, ctld/running_table.py), the whole
+                             running set on the cycle that makes the
+                             table (the first, and the first after
+                             rebuild_device_state); 0 under basic
+                             priority, which reads no running column
+    run_cols_ms      float   the part of priority_ms on the running
+                             jobs' columns: that walk where there is
+                             one, the padded device copies when the
+                             membership has moved, run_time (one
+                             perf_counter pair, no clock phase)
     gang_bound       int     the static gang bound K the cycle's solves
                              ran with: the bucket of its widest
                              candidate, capped at MaxNodesPerJob; the
@@ -132,7 +145,8 @@ period: with dispatch_ms and unnamed_ms they sum to period_ms.
     candidates_ms    _pending_candidates + the "eligible" stamps
     snapshot_ms      meta.start_logging + meta.snapshot
     priority_ms      _priority_sort (the device priority and its wait)
-                     over EVERY candidate
+                     over EVERY candidate; run_cols_ms is its part on
+                     the running jobs' columns
     cut_ms           the batch cut: the order's first ScheduledBatchSize
                      rows sliced off as the batch, _cut_batch's stamps
                      on the rest
